@@ -64,7 +64,11 @@ def run():
         data = _analytic_halo_bytes()
     else:
         env = dict(os.environ)
+        # CPU only: this child splits the host CPU into 8 devices. The
+        # parent has already imported JAX (benchmarks.common), and on a TPU
+        # host it holds the chip, so the child must never reach for it.
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         env["PYTHONPATH"] = os.path.join(repo, "src")
         proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,

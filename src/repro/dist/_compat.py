@@ -1,19 +1,10 @@
-"""Version-portable imports for the distributed layer.
+"""The distributed layer's ``shard_map`` import, in one place.
 
-``shard_map`` graduated from ``jax.experimental`` (where the replication
-check is spelled ``check_rep``) to ``jax.shard_map`` (``check_vma``). Every
-distributed module imports the shim from here so the version dance lives in
-exactly one place.
+Every distributed module imports it from here, so a future move of the
+API touches one line.
 """
 from __future__ import annotations
 
-try:
-    from jax import shard_map  # jax >= 0.6
-except ImportError:  # jax < 0.6: experimental location, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+from jax import shard_map
 
 __all__ = ["shard_map"]

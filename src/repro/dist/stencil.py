@@ -53,7 +53,7 @@ from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.decomp import check_divisible, split_ringed_bands
 from repro.core.halo import exchange_cols, exchange_rows
@@ -359,12 +359,7 @@ def _obs_host_active(u) -> bool:
     host level (not inside a jit trace) — the only situation where
     phase spans measure real wall-clock rather than trace time."""
     from repro.obs.trace import get_tracer
-    if get_tracer() is None or isinstance(u, jax.core.Tracer):
-        return False
-    try:
-        return bool(jax.core.trace_state_clean())
-    except AttributeError:  # older/newer jax without the helper
-        return True
+    return get_tracer() is not None and not isinstance(u, jax.core.Tracer)
 
 
 def _run_sharded_traced(u, interior, bc, spec: StencilSpec, mesh,
@@ -451,7 +446,7 @@ def _run_sharded_traced(u, interior, bc, spec: StencilSpec, mesh,
             schedule.remainder, "remainder")
         interior = run_round(interior, steps_rem, schedule.remainder,
                              remainder_bill, schedule.fused_blocks)
-    return u.at[r:-r, r:-r].set(interior)
+    return _reattach_ring(u, interior, mesh, r)
 
 
 def _execute_rounds(u, spec: StencilSpec, mesh, block: Callable, *,
@@ -480,7 +475,18 @@ def _execute_rounds(u, spec: StencilSpec, mesh, block: Callable, *,
             row_axis=row_axis, col_axis=col_axis, t=schedule.remainder,
             overlap=schedule.overlap)
         interior = step_rem(interior, bc)
-    return u.at[r:-r, r:-r].set(interior)
+    return _reattach_ring(u, interior, mesh, r)
+
+
+def _reattach_ring(u, interior, mesh, r: int):
+    """The full grid: ``u``'s ring around the mesh-sharded interior.
+
+    The result is replicated over ``mesh``; naming that output sharding
+    is what lets the scatter take a sharded update into an unsharded
+    grid under explicit mesh axes.
+    """
+    return u.at[r:-r, r:-r].set(
+        interior, out_sharding=NamedSharding(mesh, P()))
 
 
 # Cached jitted single launches for the untraced serial path, and cached

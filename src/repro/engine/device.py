@@ -9,9 +9,10 @@ sets of magic constants (the old ``plan.VMEM_BUDGET_BYTES``, the
 
 A :class:`DeviceModel` is a frozen, hashable value object, so it can ride
 through ``functools.lru_cache`` keys and jit static arguments unchanged.
-Models are registered by name; ``detect()`` maps ``jax.default_backend()``
-to the closest registered model so ``device=None`` everywhere means "the
-hardware this process is actually on".
+Models are registered by name; ``detect()`` maps the running backend (and,
+on a TPU, the chip's ``device_kind``) to its registered model, so
+``device=None`` everywhere means "the hardware this process is actually
+on" -- and hardware with no model is an error, not a guess.
 
 All numbers are *modeling constants* (vendor peaks / paper-quoted
 figures), not measurements — the measured side lives in
@@ -73,6 +74,10 @@ class DeviceModel:
     # whose inter-device traffic must bounce through the host, so halo
     # exchange is billed at ``inter_node_bw`` instead.
     mesh_direct_links: bool = True
+    #: ``jax.Device.device_kind`` strings this model describes. A TPU
+    #: process is matched on these, never on the backend alone: TPU
+    #: generations differ in VMEM, bandwidth and peaks.
+    device_kinds: tuple[str, ...] = ()
 
     @property
     def preferred_jax_dtype(self):
@@ -160,18 +165,30 @@ def get_device(device: str | DeviceModel | None = None) -> DeviceModel:
 
 
 def detect() -> DeviceModel:
-    """The registered model closest to ``jax.default_backend()``.
+    """The registered model for the hardware this process runs on.
 
-    The match is by the model's ``backend`` tag (first registered wins), so
-    a TPU process plans against VMEM, a GPU process against shared memory,
-    and a CPU process against the reference Xeon's cache budget. Unmatched
-    backends fall back to ``cpu_ref`` — the conservative choice.
+    A TPU is matched on ``device_kind`` (``"TPU v5 lite"`` is a v5e); a
+    TPU kind with no model raises rather than borrowing another chip's
+    VMEM and bandwidth. Other backends match the model's ``backend`` tag
+    (first registered wins), so a GPU process plans against shared
+    memory and a CPU process against the reference Xeon's cache budget.
+    A backend with no model at all raises too.
     """
     backend = jax.default_backend()
+    if backend == "tpu":
+        kind = jax.devices()[0].device_kind
+        for model in _REGISTRY.values():
+            if model.backend == "tpu" and kind in model.device_kinds:
+                return model
+        tpus = [m.name for m in _REGISTRY.values() if m.backend == "tpu"]
+        raise ValueError(
+            f"no device model for TPU kind {kind!r}; registered TPU "
+            f"models: {tpus} (register one, or pass device= explicitly)")
     for model in _REGISTRY.values():
         if model.backend == backend:
             return model
-    return _REGISTRY["cpu_ref"]
+    raise ValueError(f"no device model for backend {backend!r}; "
+                     f"registered: {available_devices()}")
 
 
 # ---------------------------------------------------------------------------
@@ -202,6 +219,7 @@ TPU_V5E = register_device(DeviceModel(
     noc_bw=0.0,                # monolithic chip: DRAM bw is the constraint
     txn_overhead_s=1e-6,       # the legacy benchmarks TXN_OVERHEAD_S value
     core_grid=(1, 1),
+    device_kinds=("TPU v5 lite", "TPU v5e"),
 ))
 
 GRAYSKULL_E150 = register_device(DeviceModel(

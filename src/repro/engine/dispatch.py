@@ -15,6 +15,7 @@ import functools
 from typing import Callable
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.stencil import StencilSpec, jacobi_2d_5pt
 from repro.engine import policies as P
@@ -126,6 +127,9 @@ def resolve_auto(shape, dtype, spec: StencilSpec, *, iters: int = 1,
     ``masked`` probes the temporal candidate in its masked
     (distributed-shard) form, whose pin-mask stream costs extra fast
     memory — the form the distributed executor will actually launch.
+    Every candidate is planned before it is returned, so the answer is
+    always a policy whose plan validates; when none does, ``PlanError``
+    lists why each was refused.
     """
     t_eff = t if t is not None else min(DEFAULT_T, max(iters, 1))
     if iters >= 2 and t_eff >= 2:
@@ -135,11 +139,18 @@ def resolve_auto(shape, dtype, spec: StencilSpec, *, iters: int = 1,
             return "temporal"
         except PlanError:
             pass
-    try:
-        plan = plan_for(shape, dtype, spec, "rowchunk", device=device)
-    except PlanError:
-        return "shifted"  # window never fits; stream per-tap blocks instead
-    return "dbuf" if plan.nblocks >= 2 else "rowchunk"
+    tried = []
+    for name in ("dbuf", "rowchunk", "shifted"):
+        try:
+            plan = plan_for(shape, dtype, spec, name, device=device)
+        except PlanError as e:
+            tried.append(f"{name}: {e}")
+            continue
+        if name == "dbuf" and plan.nblocks < 2:
+            continue  # one resident block leaves nothing to prefetch
+        return name
+    raise PlanError(f"no policy plans for grid {tuple(shape)} "
+                    f"({jnp.dtype(dtype).name}): " + "; ".join(tried))
 
 
 def _resolve_device_name(device: str | DeviceModel | None
@@ -285,8 +296,6 @@ def _converged_launch_for(sched, spec: StencilSpec, bm, interpret, device,
     ``tol < 0`` never triggers, so the sentinel ``-1.0`` means "run the
     whole budget" (fixed-iteration semantics, residual still reported).
     """
-    import jax.numpy as jnp
-
     res_fn = residual_for(spec)
 
     def block(v):

@@ -28,7 +28,7 @@ from repro.engine.device import DeviceModel, get_device
 from repro.obs import metrics as _metrics
 
 # Knob defaults shared by every policy.
-DEFAULT_BM = 256   # interior rows per block
+DEFAULT_BM = 256   # largest interior-row block bm=None plans
 DEFAULT_T = 8      # temporal fusion depth (sweeps per HBM round-trip)
 
 
@@ -37,17 +37,44 @@ class PlanError(ValueError):
     planned."""
 
 
-def pick_bm(h_int: int, bm: int) -> int:
-    """Largest divisor of ``h_int`` that is <= ``bm`` (keeps the grid exact).
+def sublane_tile(dtype) -> int:
+    """Rows in one TPU sublane tile for ``dtype``: 8 for 4-byte elements,
+    16 for 2-byte (two rows pack per sublane), 32 for 1-byte.
 
-    Warns when the request degrades all the way to ``bm=1`` (e.g. a prime
-    interior height like 1021 rows turns into 1021 one-row grid steps) —
+    Mosaic refuses a row block or window whose height is not a multiple
+    of this (``Slice shape along dimension 0 must be aligned to tiling``),
+    so every Pallas policy plans its row blocks in these units.
+    """
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def bm_candidates(h_int: int, align: int) -> list[int]:
+    """Exact row-block heights for an ``h_int``-row interior, largest
+    first: every divisor that is a multiple of ``align``, plus the full
+    interior height (a single block, always legal)."""
+    out = {h_int}
+    out.update(d for d in range(align, h_int, align) if h_int % d == 0)
+    return sorted(out, reverse=True)
+
+
+def pick_bm(h_int: int, bm: int, align: int = 1) -> int:
+    """Largest legal block height <= ``bm``.
+
+    Legal heights are the divisors of ``h_int`` that are multiples of
+    ``align`` (the dtype's sublane tile on a TPU), or ``h_int`` itself;
+    a request below all of them gets the smallest. Warns when the request
+    degrades all the way to ``bm=1`` (e.g. a prime interior height like
+    1021 rows with ``align=1`` turns into 1021 one-row grid steps) --
     that is always a performance bug the caller should hear about.
     """
     req = min(bm, h_int)
-    bm = req
-    while h_int % bm:
-        bm -= 1
+    cands = bm_candidates(h_int, align)
+    fit = [c for c in cands if c <= req]
+    bm = fit[0] if fit else cands[-1]
     if bm == 1 and req > 1:
         warnings.warn(
             f"pick_bm: interior height {h_int} has no divisor <= {req}; "
@@ -95,7 +122,16 @@ class ExecutionPlan:
 
     @property
     def nblocks(self) -> int:
-        return self.interior_shape[0] // self.bm
+        """Grid steps; the last block is ragged when ``bm`` does not
+        divide the interior height."""
+        return -(-self.interior_shape[0] // self.bm)
+
+    @property
+    def halo_rows(self) -> tuple[int, int]:
+        """Tile-aligned rows the kernel streams above and below each
+        ``bm``-row main block (``(0, 0)`` for one whole-grid block)."""
+        return _halo_rows(self.policy, self.radius, self.t, self.dtype,
+                          self.nblocks == 1)
 
     @property
     def dtype_bytes(self) -> int:
@@ -109,40 +145,70 @@ class ExecutionPlan:
                 f"device={self.device.name}")
 
 
-def _window_and_vmem(policy: str, shape, dtype_bytes: int, spec: StencilSpec,
+def _row_align(policy: str, r: int, t: int, dtype) -> int:
+    """Rows a block height must be a multiple of: the sublane tile, or
+    the policy's halo block when that is taller (the halo rides as a
+    block of its own, indexed in units of its height)."""
+    tile = sublane_tile(dtype)
+    if policy == "temporal":
+        # The output rows sit r below the main block's first row; t sweeps
+        # need t*r valid rows past them on each side.
+        return _round_up((t + 1) * r, tile)
+    if policy in ("rowchunk", "dbuf"):
+        return _round_up(2 * r, tile)
+    return tile
+
+
+def _halo_rows(policy: str, r: int, t: int, dtype,
+               single: bool) -> tuple[int, int]:
+    """Rows above/below a main block that a policy's kernel streams,
+    tile-aligned so every block DMA is."""
+    if single or policy == "shifted":
+        return (0, 0)
+    hb = _row_align(policy, r, t, dtype)
+    return (hb, hb) if policy == "temporal" else (0, hb)
+
+
+def _window_and_vmem(policy: str, shape, dtype, spec: StencilSpec,
                      bm: int, t: int, masked: bool = False) -> tuple[int, int]:
-    """Fast-memory window height and total scratch/operand footprint."""
+    """Stencil window height and the kernel's fast-memory footprint.
+
+    The footprint counts what the Pallas kernel holds per grid step: the
+    streamed blocks times their buffering depth (the Pallas pipeline
+    double-buffers a block unless the policy asks for one buffer), plus
+    the f32 working copies the tap arithmetic runs on.
+    """
     h, w = shape
     r = spec.radius
-    wi = w - 2 * r
+    hi, wi = h - 2 * r, w - 2 * r
+    db = jnp.dtype(dtype).itemsize
+    if policy not in ("shifted", "rowchunk", "dbuf", "temporal"):
+        raise PlanError(f"unknown policy {policy!r}")
+    top, bot = _halo_rows(policy, r, t, dtype, bm == hi)
+    kwin = h if bm == hi else top + bm + bot     # kernel window rows
+    out = 2 * bm * wi * db                       # double-buffered output
     if policy == "shifted":
         # One streamed (bm, wi) block per tap plus the output block; the
         # Pallas pipeline double-buffers them (x2).
-        win = bm
-        vmem = 2 * (spec.taps + 1) * bm * wi * dtype_bytes
-    elif policy == "rowchunk":
-        win = min(bm + 2 * r, h)
-        vmem = win * w * dtype_bytes + 2 * bm * wi * dtype_bytes
-    elif policy == "dbuf":
-        win = min(bm + 2 * r, h)
-        vmem = 2 * win * w * dtype_bytes + 2 * bm * wi * dtype_bytes
-    elif policy == "temporal":
-        win = min(bm + 2 * t * r, h)
-        # The t in-flight sweeps run on an f32 copy of the window (4B/elt,
-        # two live buffers under fori_loop), plus the stored window and the
-        # write-back staging block. A masked run streams the pin mask
-        # through a second window-sized scratch buffer.
-        vmem = win * w * (dtype_bytes + 8) + bm * w * dtype_bytes
-        if masked:
-            vmem += win * w * dtype_bytes
-    else:
-        raise PlanError(f"unknown policy {policy!r}")
-    return win, vmem
+        return bm, 2 * spec.taps * bm * wi * db + out
+    if policy in ("rowchunk", "dbuf"):
+        # rowchunk streams its window through one buffer (load, compute,
+        # store in turn); dbuf double-buffers it so the next window loads
+        # while this one computes.
+        bufs = 1 if policy == "rowchunk" else 2
+        f32 = kwin * w * 4 + 2 * bm * wi * 4     # window + accumulator
+        return min(bm + 2 * r, h), bufs * kwin * w * db + out + f32
+    # temporal: grid (and pin-mask) windows double-buffered; the t sweeps
+    # run on f32 copies of the window (the carried iterate, the pinned
+    # originals, a rolled tap and the accumulator).
+    streams = 2 if masked else 1
+    f32 = 4 * kwin * w * 4
+    return min(bm + 2 * t * r, h), 2 * streams * kwin * w * db + out + f32
 
 
 @functools.lru_cache(maxsize=1024)
 def _plan_cached(shape: tuple[int, int], dtype: str, spec: StencilSpec,
-                 policy: str, bm_req: int, t: int,
+                 policy: str, bm_req: int | None, t: int,
                  device: DeviceModel, masked: bool) -> ExecutionPlan:
     # Executed only on a cache miss (lru_cache body), so this counter plus
     # the request counter in plan_for gives the hit/miss split.
@@ -160,10 +226,25 @@ def _plan_cached(shape: tuple[int, int], dtype: str, spec: StencilSpec,
         raise PlanError(f"policy {policy!r} takes no pin mask; only the "
                         f"temporal kernel streams one")
     hi = h - 2 * r
-    bm = pick_bm(hi, bm_req)
-    win, vmem = _window_and_vmem(policy, shape, jnp.dtype(dtype).itemsize,
-                                 spec, bm, t, masked)
-    if vmem > device.fast_memory_bytes:
+    align = _row_align(policy, r, t, dtype)
+    if bm_req is None:
+        # The largest block up to DEFAULT_BM (so the grid keeps several
+        # steps for the pipeline to overlap) that fits the budget: exact
+        # tilings first, then tile multiples with a ragged last block
+        # (the kernels drop its rows past the interior).
+        cap = min(DEFAULT_BM, hi)
+        cands = [c for c in bm_candidates(hi, align) if c <= cap]
+        cands += [c for c in range(cap // align * align, 0, -align)
+                  if c not in cands]
+        cands = cands or [pick_bm(hi, cap, align)]
+    else:
+        cands = [pick_bm(hi, bm_req, align)]
+    for bm in cands:
+        win, vmem = _window_and_vmem(policy, shape, dtype, spec, bm, t,
+                                     masked)
+        if vmem <= device.fast_memory_bytes:
+            break
+    else:
         # Lazy import: diagnostics is stdlib-only, but keep the planner's
         # import graph free of repro.analysis on the happy path.
         from repro.analysis.diagnostics import budget_message
@@ -182,9 +263,11 @@ def plan_for(shape, dtype, spec: StencilSpec, policy: str, *,
              masked: bool = False) -> ExecutionPlan:
     """Resolve (and cache) an :class:`ExecutionPlan` for static arguments.
 
-    ``bm``/``t`` are requests; the plan holds the realized values (``bm`` is
-    snapped to the largest interior-row divisor, ``t`` is forced to 1 for
-    non-temporal policies). ``device`` is a registry name or model; None
+    ``bm``/``t`` are requests; the plan holds the realized values (``bm``
+    is snapped by :func:`pick_bm` to the policy's row alignment, ``t`` is
+    forced to 1 for non-temporal policies). ``bm=None`` takes the largest
+    height up to ``DEFAULT_BM`` whose footprint fits the device, exact
+    tilings first, then a ragged last block. ``device`` is a registry name or model; None
     plans against the detected host backend (``device.detect()``).
     ``masked`` plans the temporal kernel's explicit pin-mask stream (the
     distributed shard form).
@@ -192,7 +275,7 @@ def plan_for(shape, dtype, spec: StencilSpec, policy: str, *,
     t_eff = (t if t is not None else DEFAULT_T) if policy == "temporal" else 1
     misses0 = _metrics.counter("engine.plan.miss").value
     plan = _plan_cached(tuple(int(s) for s in shape), jnp.dtype(dtype).name,
-                        spec, policy, int(bm if bm is not None else DEFAULT_BM),
+                        spec, policy, None if bm is None else int(bm),
                         int(t_eff), get_device(device), bool(masked))
     if _metrics.counter("engine.plan.miss").value == misses0:
         _metrics.counter("engine.plan.hit").inc()

@@ -10,23 +10,32 @@ any 2-D :class:`~repro.core.stencil.StencilSpec` (any radius, any tap set):
       domain per sweep. Kept as the faithful baseline.
 
   ``rowchunk``  — paper §VI *optimized* design: one contiguous full-width
-      row-chunk (+r halo rows each side) is DMA'd from HBM into a VMEM
-      scratch window per grid step; every tap is served by an in-VMEM
-      shifted view of the same buffer (the paper's CB read-pointer
-      aliasing). Traffic ≈ 1x + 2r halo rows per block, independent of tap
-      count — the whole point of the §VI design.
+      row window (the block plus its halo rows) is streamed from HBM into
+      VMEM per grid step; every tap is served by an in-VMEM shifted view
+      of the same window (the paper's CB read-pointer aliasing). Traffic
+      ≈ 1x + the halo rows per block, independent of tap count — the
+      whole point of the §VI design. The window has one buffer: each
+      step loads, computes and stores in turn.
 
-  ``dbuf``      — rowchunk with an explicitly double-buffered data mover: a
-      single kernel instance loops over row blocks, prefetching block i+1
-      into the alternate VMEM slot while computing block i (the paper's
-      Table I "double buffering" row, done TPU-style).
+  ``dbuf``      — rowchunk with the window double-buffered, so the next
+      window loads while this one computes (the paper's Table I "double
+      buffering" row, done by the TPU's block pipeline).
 
   ``temporal``  — beyond-paper: T sweeps fused per HBM round-trip. Each
-      block DMAs a window with T*r halo rows per side, advances it T sweeps
-      locally (valid region shrinking by r rows per sweep) and writes back
-      the central rows. HBM traffic per sweep drops ~Tx at the cost of
-      O(T²r²) redundant halo compute — the right trade when the
-      compute:bandwidth ratio dwarfs the stencil's arithmetic intensity.
+      block streams a window with tile-rounded halos of at least
+      (T+1)*r rows per side, advances it T sweeps locally (valid region
+      shrinking by r rows per sweep) and writes back the central rows.
+      HBM traffic per sweep drops ~Tx at the cost of O(T²r²) redundant
+      halo compute — the right trade when the compute:bandwidth ratio
+      dwarfs the stencil's arithmetic intensity.
+
+Windows are built from Pallas blocks whose heights are multiples of the
+dtype's sublane tile (8 rows for f32, 16 for bf16): the TPU's compiler
+refuses a DMA row window of any other height, and refuses any manual row
+slice of an HBM grid whose width is not a multiple of 128 lanes (the
+paper's ringed width is 9218). So the grid operand stays in HBM and only
+blocks enter VMEM; the planner (``engine.plan``) picks block heights that
+tile the interior, or lets the last block run ragged.
 
 All grids are "ringed": shape (H, W) with a fixed Dirichlet boundary ring of
 width ``spec.radius``; only the interior is updated. Kernels accumulate in
@@ -48,6 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.stencil import StencilSpec
 from repro.engine.device import DeviceModel  # noqa: F401  (annotations)
 from repro.engine.plan import plan_for
+from repro.obs import metrics as _metrics
 
 
 def _tap_sum(c, bm: int, r: int, w: int, offsets, weights):
@@ -63,6 +73,59 @@ def _tap_sum(c, bm: int, r: int, w: int, offsets, weights):
 
 def _interior_index(shape, r: int):
     return tuple(slice(r, s - r) for s in shape)
+
+
+def _compiler_params(plan, interpret: bool):
+    """Count the trace as compiled or interpreted (the
+    ``engine.kernel.*`` counters a chip run checks), and hold Mosaic to
+    the fast-memory budget the plan was validated against, so planner
+    and compiler agree on what fits."""
+    _metrics.counter("engine.kernel.interpret" if interpret
+                     else "engine.kernel.compiled").inc()
+    if interpret:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(plan.device.fast_memory_bytes))
+
+
+def _window_specs(plan, buffers: int = 2) -> list:
+    """BlockSpecs streaming one row window of the ringed grid per step.
+
+    Step ``i`` gets its ``bm``-row main block (rows ``[i*bm, (i+1)*bm)``)
+    plus the plan's tile-aligned halo blocks above and below it (``bm``
+    is a multiple of their height, so they index as blocks of their
+    own). The kernel concatenates them in order. A halo that would fall
+    outside the grid is clamped onto its first or last block, and blocks
+    may run past the last row: window rows outside the grid stand for
+    cells beyond the ring, which the kernels pin or crop, so no kept cell
+    ever reads them. One whole-grid block stands in for all of this when
+    the plan has a single block. The grid operand stays in HBM; only
+    these blocks enter VMEM.
+    """
+    h, w = plan.shape
+    bm = plan.bm
+    mode = {} if buffers == 2 else {"pipeline_mode": pl.Buffered(buffers)}
+    if plan.nblocks == 1:
+        return [pl.BlockSpec((h, w), lambda i: (0, 0), **mode)]
+    top, bot = plan.halo_rows
+    specs = []
+    if top:
+        specs.append(pl.BlockSpec(
+            (top, w), lambda i: (jnp.maximum(i * (bm // top) - 1, 0), 0),
+            **mode))
+    specs.append(pl.BlockSpec((bm, w), lambda i: (i, 0), **mode))
+    last = -(-h // bot) - 1
+    specs.append(pl.BlockSpec(
+        (bot, w), lambda i: (jnp.minimum((i + 1) * (bm // bot), last), 0),
+        **mode))
+    return specs
+
+
+def _load_window(refs):
+    """The streamed window as one f32 value (blocks are tile-aligned, so
+    the concatenation is too)."""
+    parts = [ref[...].astype(jnp.float32) for ref in refs]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -100,26 +163,40 @@ def stencil_shifted(u: jax.Array, spec: StencilSpec, *, bm: int | None = None,
         in_specs=[blk] * spec.taps,
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((hi, wi), u.dtype),
+        compiler_params=_compiler_params(plan, interpret),
         interpret=interpret,
     )(*views)
     return u.at[_interior_index(u.shape, r)].set(out)
 
 
 # ---------------------------------------------------------------------------
-# rowchunk — contiguous row-chunk single load + in-VMEM tap views (paper §VI)
+# rowchunk / dbuf — contiguous row-window load + in-VMEM tap views (§VI)
 # ---------------------------------------------------------------------------
 
-def _rowchunk_kernel(u_hbm, o_ref, scratch, sem, *, r: int, offsets, weights):
-    i = pl.program_id(0)
+def _window_kernel(*refs, r: int, offsets, weights):
+    *in_refs, o_ref = refs
     bm = o_ref.shape[0]  # derived from the block, not passed redundantly
-    # Data-mover: one contiguous DMA of (bm + 2r) full-width rows.
-    cp = pltpu.make_async_copy(u_hbm.at[pl.ds(i * bm, bm + 2 * r), :],
-                               scratch, sem)
-    cp.start()
-    cp.wait()
-    c = scratch[...].astype(jnp.float32)
-    o_ref[...] = _tap_sum(c, bm, r, scratch.shape[1], offsets,
+    c = _load_window(in_refs)
+    o_ref[...] = _tap_sum(c, bm, r, c.shape[1], offsets,
                           weights).astype(o_ref.dtype)
+
+
+def _window_sweep(u, spec, policy: str, bm, interpret, device, buffers: int):
+    plan = plan_for(u.shape, u.dtype, spec, policy, bm=bm, device=device)
+    r = plan.radius
+    hi, wi = plan.interior_shape
+    in_specs = _window_specs(plan, buffers)
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, r=r, offsets=spec.offsets,
+                          weights=spec.weights),
+        grid=(plan.nblocks,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((plan.bm, wi), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((hi, wi), u.dtype),
+        compiler_params=_compiler_params(plan, interpret),
+        interpret=interpret,
+    )(*[u] * len(in_specs))
+    return u.at[_interior_index(u.shape, r)].set(out)
 
 
 @functools.partial(jax.jit,
@@ -127,75 +204,12 @@ def _rowchunk_kernel(u_hbm, o_ref, scratch, sem, *, r: int, offsets, weights):
 def stencil_rowchunk(u: jax.Array, spec: StencilSpec, *, bm: int | None = None,
                      interpret: bool = False,
                      device: "str | DeviceModel | None" = None) -> jax.Array:
-    """One sweep via contiguous row-chunk loads + in-VMEM shifts."""
-    plan = plan_for(u.shape, u.dtype, spec, "rowchunk", bm=bm, device=device)
-    r = plan.radius
-    w = u.shape[1]
-    hi, wi = plan.interior_shape
-    out = pl.pallas_call(
-        functools.partial(_rowchunk_kernel, r=r, offsets=spec.offsets,
-                          weights=spec.weights),
-        grid=(plan.nblocks,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((plan.bm, wi), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((hi, wi), u.dtype),
-        scratch_shapes=[pltpu.VMEM((plan.bm + 2 * r, w), u.dtype),
-                        pltpu.SemaphoreType.DMA],
-        interpret=interpret,
-    )(u)
-    return u.at[_interior_index(u.shape, r)].set(out)
+    """One sweep via contiguous row-chunk loads + in-VMEM shifts.
 
-
-# ---------------------------------------------------------------------------
-# dbuf — rowchunk with an explicit double-buffered data mover (Table I row)
-# ---------------------------------------------------------------------------
-
-def _dbuf_kernel(u_hbm, o_hbm, in_scr, out_scr, in_sem, out_sem,
-                 *, r: int, nblocks: int, offsets, weights):
-    bm = out_scr.shape[1]
-    w = in_scr.shape[2]
-
-    def in_copy(slot, blk):
-        return pltpu.make_async_copy(
-            u_hbm.at[pl.ds(blk * bm, bm + 2 * r), :], in_scr.at[slot],
-            in_sem.at[slot])
-
-    in_copy(0, 0).start()
-
-    def body(blk, _):
-        slot = jax.lax.rem(blk, 2)
-        nxt = jax.lax.rem(blk + 1, 2)
-
-        @pl.when(blk + 1 < nblocks)
-        def _():
-            # Prefetch the next row-chunk while this one computes.
-            in_copy(nxt, blk + 1).start()
-
-        in_copy(slot, blk).wait()
-        c = in_scr[slot].astype(jnp.float32)
-        res = _tap_sum(c, bm, r, w, offsets, weights).astype(out_scr.dtype)
-
-        @pl.when(blk > 1)
-        def _():
-            # This slot's previous write was issued at blk-2; drain it
-            # before overwriting the buffer.
-            pltpu.make_async_copy(
-                out_scr.at[slot], o_hbm.at[pl.ds((blk - 2) * bm, bm), :],
-                out_sem.at[slot]).wait()
-
-        out_scr[slot] = res
-        pltpu.make_async_copy(
-            out_scr.at[slot], o_hbm.at[pl.ds(blk * bm, bm), :],
-            out_sem.at[slot]).start()
-        return 0
-
-    jax.lax.fori_loop(0, nblocks, body, 0)
-    # Drain the (up to two) writes still in flight.
-    for blk in range(max(0, nblocks - 2), nblocks):
-        slot = blk % 2
-        pltpu.make_async_copy(
-            out_scr.at[slot], o_hbm.at[pl.ds(blk * bm, bm), :],
-            out_sem.at[slot]).wait()
+    The window streams through a single buffer: each step loads, computes
+    and stores in turn, the paper's §VI design before double buffering.
+    """
+    return _window_sweep(u, spec, "rowchunk", bm, interpret, device, 1)
 
 
 @functools.partial(jax.jit,
@@ -203,60 +217,30 @@ def _dbuf_kernel(u_hbm, o_hbm, in_scr, out_scr, in_sem, out_sem,
 def stencil_dbuf(u: jax.Array, spec: StencilSpec, *, bm: int | None = None,
                  interpret: bool = False,
                  device: "str | DeviceModel | None" = None) -> jax.Array:
-    """One sweep with an explicit double-buffered load/compute/store loop."""
-    plan = plan_for(u.shape, u.dtype, spec, "dbuf", bm=bm, device=device)
-    r = plan.radius
-    w = u.shape[1]
-    hi, wi = plan.interior_shape
-    out = pl.pallas_call(
-        functools.partial(_dbuf_kernel, r=r, nblocks=plan.nblocks,
-                          offsets=spec.offsets, weights=spec.weights),
-        grid=(),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct((hi, wi), u.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, plan.bm + 2 * r, w), u.dtype),
-            pltpu.VMEM((2, plan.bm, wi), u.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(u)
-    return u.at[_interior_index(u.shape, r)].set(out)
+    """One sweep with the row window double-buffered: the Pallas pipeline
+    loads window ``i+1`` while window ``i`` computes (Table I's double
+    buffering, done by the TPU's block pipeline)."""
+    return _window_sweep(u, spec, "dbuf", bm, interpret, device, 2)
 
 
 # ---------------------------------------------------------------------------
 # temporal — T sweeps fused per HBM round-trip (beyond paper)
 # ---------------------------------------------------------------------------
 
-def _temporal_kernel(*refs, bm: int, t: int, r: int, h: int, w: int,
-                     offsets, weights, masked: bool):
-    if masked:
-        (u_hbm, m_hbm, o_hbm, scratch, m_scr, out_scr,
-         in_sem, m_sem, out_sem) = refs
-    else:
-        u_hbm, o_hbm, scratch, out_scr, in_sem, out_sem = refs
-    i = pl.program_id(0)
-    win = scratch.shape[0]  # loaded rows (whole grid if the halo overflows)
-    # Clamp the window inside the array; remember where it starts globally.
-    ws = jnp.clip(i * bm + r - t * r, 0, h - win)
-    cp = pltpu.make_async_copy(u_hbm.at[pl.ds(ws, win), :], scratch, in_sem)
-    cp.start()
-    if masked:
-        mcp = pltpu.make_async_copy(m_hbm.at[pl.ds(ws, win), :], m_scr, m_sem)
-        mcp.start()
-    cp.wait()
-
-    c0 = scratch[...].astype(jnp.float32)
+def _temporal_kernel(*refs, nin: int, bm: int, t: int, r: int, h: int,
+                     top: int, offsets, weights, masked: bool):
+    o_ref = refs[-1]
+    c0 = _load_window(refs[:nin])
+    win, w = c0.shape
     if masked:
         # Explicit pin mask (nonzero = Dirichlet): on a distributed shard
         # only the *global* ring is pinned — exchanged halo cells must
         # evolve with the fused sweeps or the fusion is fake.
-        mcp.wait()
-        fixed = m_scr[...] != 0
+        fixed = _load_window(refs[nin:2 * nin]) != 0
     else:
-        # Mask pinning global Dirichlet cells: the r-deep ring of the grid.
+        # Mask pinning global Dirichlet cells: the r-deep ring of the grid
+        # (and any window rows that stand for cells above or below it).
+        ws = pl.program_id(0) * bm - top  # grid row of window row 0
         grow = ws + jax.lax.broadcasted_iota(jnp.int32, (win, w), 0)
         gcol = jax.lax.broadcasted_iota(jnp.int32, (win, w), 1)
         fixed = (grow < r) | (grow >= h - r) | (gcol < r) | (gcol >= w - r)
@@ -265,20 +249,21 @@ def _temporal_kernel(*refs, bm: int, t: int, r: int, h: int, w: int,
         acc = None
         for (dy, dx), wt in zip(offsets, weights):
             # value at p + (dy, dx): roll by the negated offset
-            term = jnp.roll(c, (-dy, -dx), axis=(0, 1)) * jnp.float32(wt)
+            term = c
+            if dy:
+                term = pltpu.roll(term, (-dy) % win, 0)
+            if dx:
+                term = pltpu.roll(term, (-dx) % w, 1)
+            term = term * jnp.float32(wt)
             acc = term if acc is None else acc + term
         # Dirichlet cells keep their original value; roll wrap garbage only
         # ever lands in the t*r-deep halo that is discarded below.
         return jnp.where(fixed, c0, acc)
 
     c = jax.lax.fori_loop(0, t, sweep, c0)
-    # Central bm rows are exact after t sweeps; write them back.
-    lo = i * bm + r - ws  # local offset of the first output row
-    out_scr[...] = jax.lax.dynamic_slice(c, (lo, 0), (bm, w)).astype(out_scr.dtype)
-    wcp = pltpu.make_async_copy(out_scr, o_hbm.at[pl.ds(i * bm + r, bm), :],
-                                out_sem)
-    wcp.start()
-    wcp.wait()
+    # The bm interior rows of this block sit r below the main block's
+    # first row; they are exact after t sweeps.
+    o_ref[...] = c[top + r:top + r + bm, r:w - r].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -303,29 +288,23 @@ def stencil_temporal(u: jax.Array, spec: StencilSpec, *, t: int | None = None,
                     device=device, masked=masked)
     r = plan.radius
     h, w = u.shape
-    operands = [u]
-    scratch = [pltpu.VMEM((plan.window_rows, w), u.dtype)]
-    sems = [pltpu.SemaphoreType.DMA]
+    hi, wi = plan.interior_shape
+    specs = _window_specs(plan)
+    operands = [u] * len(specs)
     if masked:
-        # The mask rides the same DMA machinery as the grid (its own
-        # window scratch + semaphore), cast to the grid dtype so 0/1
-        # survive any registry dtype exactly.
-        operands.append(mask.astype(u.dtype))
-        scratch.append(pltpu.VMEM((plan.window_rows, w), u.dtype))
-        sems.append(pltpu.SemaphoreType.DMA)
+        # The mask rides the same block pipeline as the grid, cast to the
+        # grid dtype so 0/1 survive any registry dtype exactly.
+        operands += [mask.astype(u.dtype)] * len(specs)
     out = pl.pallas_call(
-        functools.partial(_temporal_kernel, bm=plan.bm, t=plan.t, r=r, h=h,
-                          w=w, offsets=spec.offsets, weights=spec.weights,
+        functools.partial(_temporal_kernel, nin=len(specs), bm=plan.bm,
+                          t=plan.t, r=r, h=h, top=plan.halo_rows[0],
+                          offsets=spec.offsets, weights=spec.weights,
                           masked=masked),
         grid=(plan.nblocks,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(operands),
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct((h, w), u.dtype),
-        scratch_shapes=scratch + [pltpu.VMEM((plan.bm, w), u.dtype)]
-        + sems + [pltpu.SemaphoreType.DMA],
+        in_specs=specs * (2 if masked else 1),
+        out_specs=pl.BlockSpec((plan.bm, wi), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((hi, wi), u.dtype),
+        compiler_params=_compiler_params(plan, interpret),
         interpret=interpret,
     )(*operands)
-    # The top/bottom r boundary rows are never written by the kernel;
-    # restore them (columns are pinned by the fixed-cell mask).
-    out = out.at[:r, :].set(u[:r, :]).at[h - r:, :].set(u[h - r:, :])
-    return out
+    return u.at[_interior_index(u.shape, r)].set(out)

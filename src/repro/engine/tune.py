@@ -34,7 +34,7 @@ import numpy as np
 
 from repro.core.stencil import StencilSpec
 from repro.engine.device import DeviceModel, get_device
-from repro.engine.dispatch import get_policy, registry
+from repro.engine.dispatch import _on_tpu, get_policy, registry
 from repro.engine.plan import DEFAULT_T, PlanError, plan_for
 from repro.engine.schedule import effective_depth
 from repro.obs import metrics as _metrics
@@ -148,7 +148,7 @@ def _time_policy(u, spec, name: str, *, bm, t, interpret: bool,
 
 
 def measure(shape, dtype, spec: StencilSpec, *, t: int | None = None,
-            bm: int | None = None, interpret: bool = True,
+            bm: int | None = None, interpret: bool | None = None,
             device: str | DeviceModel | None = None,
             masked: bool = False) -> dict:
     """Time every policy that plans on ``device``; return the record.
@@ -163,6 +163,8 @@ def measure(shape, dtype, spec: StencilSpec, *, t: int | None = None,
     """
     global measure_count
     measure_count += 1
+    if interpret is None:
+        interpret = not _on_tpu()
     dev = get_device(device)
     t_eff = t if t is not None else DEFAULT_T
     u = jnp.zeros(tuple(int(s) for s in shape), jnp.dtype(dtype))
@@ -199,7 +201,7 @@ def measure(shape, dtype, spec: StencilSpec, *, t: int | None = None,
 
 def best_policy(shape, dtype, spec: StencilSpec, *, iters: int = 1,
                 t: int | None = None, bm: int | None = None,
-                interpret: bool = True,
+                interpret: bool | None = None,
                 device: str | DeviceModel | None = None,
                 mesh: tuple | None = None, masked: bool = False,
                 overlap: bool = False,
@@ -215,7 +217,11 @@ def best_policy(shape, dtype, spec: StencilSpec, *, iters: int = 1,
     gates fused candidates by their masked-plan footprint and always
     rides with ``mesh`` in the distributed path, so the mesh bucket
     already separates the two candidate worlds in the key.
+    ``interpret=None`` times compiled kernels on a TPU and the Pallas
+    interpreter elsewhere, the same rule ``engine.run`` applies.
     """
+    if interpret is None:
+        interpret = not _on_tpu()
     dev = get_device(device)
     t_eff = effective_depth(iters, t)
     key = tune_key(shape, dtype, spec, dev, t=t_eff, bm=bm,
@@ -237,7 +243,7 @@ def best_policy(shape, dtype, spec: StencilSpec, *, iters: int = 1,
 
 def warm(shapes, dtype, spec: StencilSpec, *, iters: int = 1,
          t: int | None = None, bm: int | None = None,
-         interpret: bool = True,
+         interpret: bool | None = None,
          device: str | DeviceModel | None = None,
          mesh: tuple | None = None, masked: bool = False,
          overlap: bool = False,

@@ -5,6 +5,8 @@ Each kernel in kernels/ is validated against these references with
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -21,6 +23,31 @@ def jacobi_multi(u: jax.Array, t: int) -> jax.Array:
     for _ in range(t):
         u = jacobi_step(u)
     return u
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "fuse"))
+def sweeps(u: jax.Array, n, spec: StencilSpec | None = None, *,
+           fuse: int = 1) -> jax.Array:
+    """``n`` oracle sweeps in one jitted loop (``n`` may be traced).
+
+    Each group of ``fuse`` sweeps runs in f32 and rounds to ``u.dtype``
+    once at its end, which is where a fused policy (``temporal`` with
+    ``t=fuse``) rounds; leftover ``n % fuse`` sweeps round every sweep,
+    like the engine's non-fused remainder. ``fuse=1`` is the plain
+    per-sweep oracle. For f32 grids the rounding points change nothing.
+    """
+    spec = spec if spec is not None else jacobi_2d_5pt()
+
+    def group(k):
+        def body(_, v):
+            w = v.astype(jnp.float32)
+            for _ in range(k):
+                w = apply_stencil(w, spec)
+            return w.astype(v.dtype)
+        return body
+
+    u = jax.lax.fori_loop(0, n // fuse, group(fuse), u)
+    return jax.lax.fori_loop(0, n % fuse, group(1), u)
 
 
 def stencil_step(u: jax.Array, spec: StencilSpec) -> jax.Array:

@@ -73,28 +73,35 @@ def test_roofline_hw_comes_from_registry():
 # ---------------------------------------------------------------------------
 
 def test_e150_budget_rejects_plan_v5e_accepts():
-    plan = engine.plan_for(BIG, jnp.float32, SPEC, "rowchunk",
+    plan = engine.plan_for(BIG, jnp.float32, SPEC, "rowchunk", bm=128,
                            device="tpu_v5e")
     assert plan.vmem_bytes < get_device("tpu_v5e").fast_memory_bytes
     assert plan.device.name == "tpu_v5e"
     with pytest.raises(PlanError, match="grayskull_e150"):
-        engine.plan_for(BIG, jnp.float32, SPEC, "rowchunk",
+        engine.plan_for(BIG, jnp.float32, SPEC, "rowchunk", bm=128,
                         device="grayskull_e150")
+    # Left to choose (bm=None), the planner shrinks the block until the
+    # window fits the device it plans for.
+    fit = engine.plan_for(BIG, jnp.float32, SPEC, "rowchunk",
+                          device="grayskull_e150")
+    assert fit.bm < plan.bm
+    assert fit.vmem_bytes <= get_device("grayskull_e150").fast_memory_bytes
     # shifted streams (bm, wi) tap blocks with a small bm, so the e150 can
     # still run the problem — just not with the resident-window policies
-    small = engine.plan_for(BIG, jnp.float32, SPEC, "shifted", bm=8,
+    small = engine.plan_for(BIG, jnp.float32, SPEC, "shifted",
                             device="grayskull_e150")
+    assert small.bm <= 16
     assert small.vmem_bytes < get_device("grayskull_e150").fast_memory_bytes
 
 
 def test_engine_run_enforces_device_budget():
     u = _problem(130, 4098)
-    out = engine.run(u, SPEC, policy="rowchunk", iters=1, interpret=True,
-                     device="tpu_v5e")
+    out = engine.run(u, SPEC, policy="rowchunk", iters=1, bm=128,
+                     interpret=True, device="tpu_v5e")
     assert out.shape == u.shape
     with pytest.raises(PlanError, match="1.50 MiB"):
-        engine.run(u, SPEC, policy="rowchunk", iters=1, interpret=True,
-                   device="grayskull_e150")
+        engine.run(u, SPEC, policy="rowchunk", iters=1, bm=128,
+                   interpret=True, device="grayskull_e150")
 
 
 def test_plan_cache_keys_differ_per_device():
@@ -115,13 +122,14 @@ def test_plan_cache_keys_differ_per_device():
 
 
 def test_resolve_auto_crossover_shifts_on_e150():
-    # v5e: the t=8 temporal window fits VMEM -> fuse; e150: neither the
-    # temporal nor the rowchunk window fits 1.5 MiB SRAM -> stream per-tap
-    # blocks (shifted). Same problem, different hardware, different policy.
+    # v5e: the t=8 temporal window fits VMEM -> fuse; e150: even the
+    # smallest temporal window (bm plus two t*r-deep halos) overflows
+    # 1.5 MiB SRAM, while a small double-buffered row window fits -> dbuf.
+    # Same problem, different hardware, different policy.
     assert engine.resolve_auto(BIG, jnp.float32, SPEC, iters=100,
                                device="tpu_v5e") == "temporal"
     assert engine.resolve_auto(BIG, jnp.float32, SPEC, iters=100,
-                               device="grayskull_e150") == "shifted"
+                               device="grayskull_e150") == "dbuf"
     # narrow problem: every window fits both; both fuse
     assert engine.resolve_auto((130, 130), jnp.float32, SPEC, iters=100,
                                device="grayskull_e150") == "temporal"
@@ -132,9 +140,9 @@ def test_distributed_plan_validates_against_device():
     u = _problem(130, 4098)
     with pytest.raises(PlanError, match="grayskull_e150"):
         engine.run_distributed(u, SPEC, mesh=mesh, policy="rowchunk",
-                               iters=1, device="grayskull_e150")
+                               iters=1, bm=128, device="grayskull_e150")
     out = engine.run_distributed(u, SPEC, mesh=mesh, policy="rowchunk",
-                                 iters=1, device="tpu_v5e")
+                                 iters=1, bm=128, device="tpu_v5e")
     assert out.shape == u.shape
 
 
@@ -145,10 +153,14 @@ def test_distributed_plan_validates_against_device():
 def test_pick_bm_warns_on_prime_interior():
     with pytest.warns(UserWarning, match="realized bm=1"):
         assert pick_bm(1021, 256) == 1  # 1021 is prime: 1021 grid steps
+    # The planner aligns blocks to the sublane tile instead: a prime
+    # interior height gets tile-sized blocks with a ragged last one.
     engine.plan_cache_clear()
-    with pytest.warns(UserWarning, match="1021"):
+    import warnings
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
         plan = engine.plan_for((1023, 130), jnp.float32, SPEC, "rowchunk")
-    assert plan.bm == 1 and plan.nblocks == 1021
+    assert plan.bm == 256 and plan.nblocks == 4
 
 
 def test_pick_bm_quiet_cases():
@@ -287,12 +299,13 @@ def test_bench_dry_env_falsy_values(monkeypatch):
 def test_tuned_respects_device_budget(tmp_path):
     cache = str(tmp_path / "tune.json")
     tune.clear()
-    # With the default bm request, no policy's window fits the e150's
-    # 1.5 MiB SRAM for BIG: the tuner must refuse with every candidate's
+    # With a 128-row block, no policy's window fits the e150's 1.5 MiB
+    # SRAM for BIG: the tuner must refuse with every candidate's
     # rejection in the message, not silently pick an unplannable winner.
     with pytest.raises(PlanError, match="no policy plans"):
-        tune.best_policy(BIG, jnp.float32, SPEC, iters=1, interpret=True,
-                         device="grayskull_e150", cache_path=cache)
+        tune.best_policy(BIG, jnp.float32, SPEC, iters=1, bm=128,
+                         interpret=True, device="grayskull_e150",
+                         cache_path=cache)
     # With a small streamed block everything fits; the measured winner is
     # a real, plannable policy and the skip list is empty.
     best = tune.best_policy((34, 130), jnp.float32, SPEC, iters=1, bm=8,
@@ -301,4 +314,85 @@ def test_tuned_respects_device_budget(tmp_path):
     assert best in engine.available_policies()
     [rec] = json.load(open(cache)).values()
     assert rec["skipped"] == [] and rec["device"] == "grayskull_e150"
+    tune.clear()
+
+
+# ---------------------------------------------------------------------------
+# Planning the paper's domain for the chip's tiling
+# ---------------------------------------------------------------------------
+
+PAPER = (1026, 9218)  # the paper's 1024x9216 interior, ringed
+
+
+@pytest.mark.parametrize("align", [8, 16])
+def test_pick_bm_returns_only_tile_aligned_heights(align):
+    for h in (1024, 270, 526, 1021, 64, 30, 9):
+        for req in (1, 8, 16, 64, 100, 256, 5000):
+            bm = pick_bm(h, req, align)
+            assert bm == h or bm % align == 0, (h, req, bm)
+            assert bm <= max(req, align) or bm == h, (h, req, bm)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("policy,masked", [
+    ("shifted", False), ("rowchunk", False), ("dbuf", False),
+    ("temporal", False), ("temporal", True)])
+def test_plan_without_bm_fits_the_paper_domain(policy, masked, dtype):
+    from repro.engine.plan import sublane_tile
+    plan = engine.plan_for(PAPER, dtype, SPEC, policy, device="tpu_v5e",
+                           t=8 if policy == "temporal" else None,
+                           masked=masked)
+    assert plan.vmem_bytes <= get_device("tpu_v5e").fast_memory_bytes
+    assert plan.bm % sublane_tile(dtype) == 0
+    assert plan.nblocks * plan.bm >= plan.interior_shape[0]
+    top, bot = plan.halo_rows
+    assert top % sublane_tile(dtype) == 0 and bot % sublane_tile(dtype) == 0
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("iters,masked", [(1, False), (5000, False),
+                                          (5000, True)])
+def test_resolve_auto_returns_a_plannable_policy(iters, masked, dtype):
+    policy = engine.resolve_auto(PAPER, dtype, SPEC, iters=iters,
+                                 device="tpu_v5e", masked=masked)
+    fused = engine.get_policy(policy).fused
+    engine.plan_for(PAPER, dtype, SPEC, policy, device="tpu_v5e",
+                    t=8 if fused else None, masked=masked and fused)
+    assert policy == ("temporal" if iters > 1 else "dbuf")
+
+
+def test_resolve_auto_raises_when_no_policy_plans():
+    import dataclasses
+    tiny = dataclasses.replace(get_device("tpu_v5e"), name="tiny",
+                               fast_memory_bytes=1024)
+    with pytest.raises(PlanError, match="no policy plans"):
+        engine.resolve_auto(PAPER, jnp.float32, SPEC, iters=100,
+                            device=tiny)
+
+
+def test_detect_matches_tpu_kind_and_refuses_unknown(monkeypatch):
+    import types
+    from repro.engine import device as dev_mod
+    monkeypatch.setattr(dev_mod.jax, "default_backend", lambda: "tpu")
+    chip = types.SimpleNamespace(device_kind="TPU v5 lite")
+    monkeypatch.setattr(dev_mod.jax, "devices", lambda *a: [chip])
+    assert detect().name == "tpu_v5e"
+    chip.device_kind = "TPU v9 imaginary"
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        detect()
+    monkeypatch.setattr(dev_mod.jax, "default_backend", lambda: "warp")
+    with pytest.raises(ValueError, match="warp"):
+        detect()
+
+
+def test_tune_interpret_follows_the_backend(tmp_path):
+    cache = str(tmp_path / "tune.json")
+    tune.clear()
+    tune.best_policy((34, 130), jnp.float32, SPEC, iters=1, bm=8,
+                     device="tpu_v5e", cache_path=cache)
+    [key] = json.load(open(cache))
+    want = jax.default_backend() != "tpu"
+    assert f"interpret={want}" in key
     tune.clear()

@@ -122,9 +122,11 @@ def test_auto_demotes_when_only_the_masked_plan_overflows():
     letting local_sweep_for crash on the masked plan."""
     import dataclasses
 
-    plain = engine.plan_for(SHAPE, DTYPE, SPEC, "temporal", t=4)
+    # bm=1 plans the smallest legal block: the planner shrinks blocks to
+    # fit, so the budget sits between the two smallest footprints.
+    plain = engine.plan_for(SHAPE, DTYPE, SPEC, "temporal", t=4, bm=1)
     masked = engine.plan_for(SHAPE, DTYPE, SPEC, "temporal", t=4,
-                             masked=True)
+                             masked=True, bm=1)
     budget = (plain.vmem_bytes + masked.vmem_bytes) // 2
     tight = dataclasses.replace(engine.get_device("tpu_v5e"),
                                 name="tight", fast_memory_bytes=budget)

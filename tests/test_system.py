@@ -64,3 +64,26 @@ def test_serve_driver():
               "--max-new", "6"])
     assert p.returncode == 0, p.stderr
     assert "tok/s=" in p.stdout
+
+
+def test_compile_cache_dir_comes_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache sits
+    at one fixed, git-ignored path inside the checkout."""
+    import jax
+
+    from repro import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.use_compile_cache()
+        assert path == os.path.join(compile_cache.REPO_ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        ignored = open(os.path.join(compile_cache.REPO_ROOT,
+                                    ".gitignore")).read().split()
+        assert ".jax_cache/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
