@@ -20,10 +20,12 @@ every exchange round — is ONE cached jitted ``lax.scan`` launch with the
 ``BASELINE_PR9`` pins the per-round-dispatch numbers this launch
 replaced; ``serial_speedup``/``overlapped_speedup`` report the measured
 improvement per row. A traced pass per case re-runs the serial solve
-through the span-per-phase executor and reports ``dispatch_overhead_us``
-(wall minus the sum of per-round span durations — the host dispatch the
-scan launch eliminates), reconciled via ``obs.reconcile``: it is why
-rows whose *model* says overlap wins used to *measure* overlap losing.
+with a tracer installed — the same one launch, as one ``dist.run`` span
+carrying the launch's modeled bill — and reports ``dispatch_overhead_us``
+(wall minus the span: host work outside the launch) and the
+``obs.reconcile`` line of the launch. The committed file's
+``dispatch_overhead_us`` predates this: it was measured against a
+per-phase executor with one span per round, since removed.
 
 Run: ``PYTHONPATH=src:. python -m benchmarks.bench_dist [--out PATH]``.
 With ``REPRO_BENCH_DRY=1`` measurement is skipped (measured_us = 0.0) but
@@ -103,10 +105,9 @@ for mesh_shape, t, policy in cases:
         # Best-of-N: forced host devices share the host's cores, so the
         # floor — not the scheduler-noise median — is the launch cost.
         rec[tag + "_us"] = float(min(ts)) * 1e6
-    # Traced pass: the span-per-phase executor (what the scan launch
-    # replaced on the hot path). First call warms the per-phase steps;
-    # the second measures steady state. Host dispatch between rounds =
-    # wall minus the sum of per-round span durations.
+    # Traced pass: the same cached launch with a tracer installed; its
+    # dist.run span waits for the launch. Host work outside the launch =
+    # wall minus the span. The second call measures steady state.
     for _ in range(2):
         tracer = Tracer()
         with use_tracer(tracer):
@@ -115,13 +116,13 @@ for mesh_shape, t, policy in cases:
                 u, mesh=mesh, policy=policy, iters=%(iters)d, t=t,
                 row_axis="x", col_axis=col, overlap=False))
             wall_us = (time.perf_counter() - t0) * 1e6
-    rounds_us = sum(ev.dur_us for ev in tracer.events
-                    if ev.name == "dist.round")
+    launch_us = sum(ev.dur_us for ev in tracer.events
+                    if ev.name == "dist.run")
     rec["traced_serial_us"] = wall_us
-    rec["dispatch_overhead_us"] = max(0.0, wall_us - rounds_us)
-    # Per-phase measured-vs-modeled lines: the reconciliation evidence
-    # that interpret-mode host cost, not the exchange model, carries
-    # the measured gap (the model prices another chip's links).
+    rec["dispatch_overhead_us"] = max(0.0, wall_us - launch_us)
+    # Measured-vs-modeled launch line: the reconciliation evidence that
+    # interpret-mode host cost, not the exchange model, carries the
+    # measured gap (the model prices another chip's links).
     rec["reconcile"] = [ln.strip()
                         for ln in reconcile(tracer).describe().splitlines()
                         if "spans=" in ln]

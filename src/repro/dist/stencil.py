@@ -59,6 +59,7 @@ from repro.core.decomp import check_divisible, split_ringed_bands
 from repro.core.halo import exchange_cols, exchange_rows
 from repro.core.stencil import StencilSpec
 from repro.dist._compat import shard_map
+from repro.engine.dispatch import cached_launch
 from repro.engine.schedule import overlap_feasible
 
 
@@ -111,8 +112,8 @@ def _assemble_ext(u, top, bottom, left, right, tl, tr, bl, br, *,
     block so shard-corner halos ride along), Dirichlet bands substituted
     on physical domain edges, and the four ``r x r`` physical ring
     corners patched onto the corner shards. Pure function of the local
-    shard + bands, shared between the fused serial/overlap rounds in
-    :func:`_local_sweeps` and the per-phase traced executor.
+    shard + bands, shared by the serial and overlap rounds of
+    :func:`_local_sweeps`.
     """
     hl, wl = u.shape
     ix, iy = _shard_index(row_axis, col_axis, px, py)
@@ -228,10 +229,10 @@ def _local_sweeps(u, top, bottom, left, right, tl, tr, bl, br, *,
     ``wl <= 2d``) silently runs the serial round — same numbers, nothing
     left to hide the exchange behind.
 
-    The phases themselves (:func:`_assemble_ext`, :func:`_interior_keep`,
-    :func:`_pin_mask`, :func:`_rind_stitch`) are shared with the traced
-    per-phase executor (:func:`make_phase_steps`), so the one-launch and
-    span-per-phase formulations execute the same local ops.
+    The phases run under ``jax.named_scope`` names that reach every device
+    operation's ``op_name``: ``exchange.halo`` (the ``ppermute`` calls and
+    the extended block's assembly), ``exchange.interior`` and
+    ``exchange.rind`` (the overlap split).
     """
     hl, wl = u.shape
     d = t * r
@@ -244,14 +245,18 @@ def _local_sweeps(u, top, bottom, left, right, tl, tr, bl, br, *,
         # Interior launch, issued before the exchange: after t sweeps the
         # cells >= d from the shard edge are exact (the near-edge cells
         # would need halo data and are covered by the rind strips below).
-        inner_keep = _interior_keep(u, block, t, d)
-    ext = _assemble_ext(u, top, bottom, left, right, tl, tr, bl, br,
-                        row_axis=row_axis, col_axis=col_axis, px=px, py=py,
-                        r=r, d=d)
-    ix, iy = _shard_index(row_axis, col_axis, px, py)
-    fixed = _pin_mask(hl, wl, d, ix, iy, px, py)
+        with jax.named_scope("exchange.interior"):
+            inner_keep = _interior_keep(u, block, t, d)
+    with jax.named_scope("exchange.halo"):
+        ext = _assemble_ext(u, top, bottom, left, right, tl, tr, bl, br,
+                            row_axis=row_axis, col_axis=col_axis, px=px,
+                            py=py, r=r, d=d)
+        ix, iy = _shard_index(row_axis, col_axis, px, py)
+        fixed = _pin_mask(hl, wl, d, ix, iy, px, py)
     if overlap:
-        return _rind_stitch(ext, fixed, inner_keep, block=block, t=t, d=d)
+        with jax.named_scope("exchange.rind"):
+            return _rind_stitch(ext, fixed, inner_keep, block=block, t=t,
+                                d=d)
     ext = block(ext, fixed, t)
     return ext[d:-d, d:-d]
 
@@ -298,166 +303,17 @@ def make_sharded_step(mesh, spec: StencilSpec, block: Callable, *,
     return step
 
 
-def make_phase_steps(mesh, spec: StencilSpec, block: Callable, *,
-                     row_axis: str | None, col_axis: str | None,
-                     t: int = 1) -> dict:
-    """Per-phase jitted shard_map callables for the traced executor.
-
-    Returns ``{"exchange", "compute", "interior", "rind"}``: the same
-    local ops :func:`_local_sweeps` runs in one launch, split so the
-    traced executor can ``block_until_ready`` between phases and put a
-    span around each. ``exchange(interior, *bands)`` returns the stacked
-    extended blocks; ``compute(ext)`` the serial full-block round;
-    ``interior(interior)`` the halo-independent keeps; ``rind(ext,
-    inner_keep)`` the stitched overlap round.
-    """
-    px = mesh.shape[row_axis] if row_axis else 1
-    py = mesh.shape[col_axis] if col_axis else 1
-    row_axis = row_axis or "_row_unused"
-    col_axis = col_axis or "_col_unused"
-    r = spec.radius
-    d = t * r
-    row = row_axis if px > 1 else None
-    col = col_axis if py > 1 else None
-    grid_spec = P(row, col)
-    band_specs = (grid_spec, P(None, col), P(None, col),
-                  P(row, None), P(row, None)) + (P(None, None),) * 4
-
-    def exchange_fn(u, top, bottom, left, right, tl, tr, bl, br):
-        return _assemble_ext(u, top, bottom, left, right, tl, tr, bl, br,
-                             row_axis=row_axis, col_axis=col_axis,
-                             px=px, py=py, r=r, d=d)
-
-    def compute_fn(ext):
-        hl, wl = ext.shape[0] - 2 * d, ext.shape[1] - 2 * d
-        ix, iy = _shard_index(row_axis, col_axis, px, py)
-        fixed = _pin_mask(hl, wl, d, ix, iy, px, py)
-        return block(ext, fixed, t)[d:-d, d:-d]
-
-    def interior_fn(u):
-        return _interior_keep(u, block, t, d)
-
-    def rind_fn(ext, inner_keep):
-        hl, wl = ext.shape[0] - 2 * d, ext.shape[1] - 2 * d
-        ix, iy = _shard_index(row_axis, col_axis, px, py)
-        fixed = _pin_mask(hl, wl, d, ix, iy, px, py)
-        return _rind_stitch(ext, fixed, inner_keep, block=block, t=t, d=d)
-
-    def sm(fn, in_specs):
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=grid_spec, check_vma=False))
-
-    return {"exchange": sm(exchange_fn, band_specs),
-            "compute": sm(compute_fn, (grid_spec,)),
-            "interior": sm(interior_fn, (grid_spec,)),
-            "rind": sm(rind_fn, (grid_spec, grid_spec))}
-
-
-def _obs_host_active(u) -> bool:
-    """Whether the per-phase traced executor should run: a
-    :mod:`repro.obs` tracer is installed and we are executing eagerly at
-    host level (not inside a jit trace) — the only situation where
-    phase spans measure real wall-clock rather than trace time."""
-    from repro.obs.trace import get_tracer
-    return get_tracer() is not None and not isinstance(u, jax.core.Tracer)
-
-
-def _run_sharded_traced(u, interior, bc, spec: StencilSpec, mesh,
-                        block: Callable, *, schedule, row_axis, col_axis,
-                        remainder_block, bill, remainder_bill,
-                        cache_key=None):
-    """Span-per-phase twin of the serial body of :func:`run_sharded`.
-
-    Each round runs as separate jitted phase launches with
-    ``block_until_ready`` between them, wrapped in ``dist.round`` >
-    ``exchange``/``interior``/``rind`` (or ``compute``) spans. Every
-    phase span carries the round's :class:`~repro.engine.schedule.
-    ExchangeBill` attrs plus its own ``model_s``, the join key
-    ``obs.reconcile`` prices drift from. The local ops are the exact
-    helpers the one-launch path uses, so the result is bit-identical —
-    what changes is that the phases are serialized to be measurable (the
-    overlap win itself is *not* realized here; the spans price what it
-    would hide). The first round of each depth also pays phase
-    compilation inside its spans.
-    """
-    from repro.obs.trace import span as _obs_span
-
-    r = spec.radius
-    px = mesh.shape[row_axis] if row_axis else 1
-    py = mesh.shape[col_axis] if col_axis else 1
-    bands = (bc["top"], bc["bottom"], bc["left"], bc["right"],
-             bc["tl"], bc["tr"], bc["bl"], bc["br"])
-
-    def attrs(b, model_s):
-        return dict(b.as_attrs(), model_s=model_s) if b is not None else {}
-
-    def run_round(interior, steps, t, b, idx):
-        d = t * r
-        hl, wl = interior.shape[0] // px, interior.shape[1] // py
-        ov = schedule.overlap and overlap_feasible(hl, wl, d)
-        with _obs_span("dist.round", round=idx, t=t, halo_depth=d,
-                       overlap=ov):
-            if ov:
-                with _obs_span("interior",
-                               **attrs(b, b.interior_s if b else None)):
-                    inner = jax.block_until_ready(
-                        steps["interior"](interior))
-                with _obs_span("exchange",
-                               **attrs(b, b.exchange_s if b else None)):
-                    ext = jax.block_until_ready(
-                        steps["exchange"](interior, *bands))
-                with _obs_span("rind",
-                               **attrs(b, b.rind_s if b else None)):
-                    interior = jax.block_until_ready(
-                        steps["rind"](ext, inner))
-            else:
-                with _obs_span("exchange",
-                               **attrs(b, b.exchange_s if b else None)):
-                    ext = jax.block_until_ready(
-                        steps["exchange"](interior, *bands))
-                with _obs_span("compute",
-                               **attrs(b, b.compute_s if b else None)):
-                    interior = jax.block_until_ready(steps["compute"](ext))
-        return interior
-
-    def steps_for(blk, t, tag):
-        # Reuse jitted phase callables across calls when the caller pinned
-        # how `blk` was built — otherwise every traced run would recompile
-        # all four phases and the spans would price compilation forever.
-        if cache_key is None:
-            return make_phase_steps(mesh, spec, blk, row_axis=row_axis,
-                                    col_axis=col_axis, t=t)
-        key = (cache_key, mesh, spec, row_axis, col_axis, t, tag,
-               tuple(interior.shape), str(interior.dtype))
-        steps = _PHASE_STEPS.get(key)
-        if steps is None:
-            steps = make_phase_steps(mesh, spec, blk, row_axis=row_axis,
-                                     col_axis=col_axis, t=t)
-            _PHASE_STEPS[key] = steps
-        return steps
-
-    if schedule.fused_blocks:
-        steps = steps_for(block, schedule.t, "fused")
-        for i in range(schedule.fused_blocks):
-            interior = run_round(interior, steps, schedule.t, bill, i)
-    if schedule.remainder:
-        steps_rem = steps_for(
-            remainder_block if remainder_block is not None else block,
-            schedule.remainder, "remainder")
-        interior = run_round(interior, steps_rem, schedule.remainder,
-                             remainder_bill, schedule.fused_blocks)
-    return _reattach_ring(u, interior, mesh, r)
-
-
 def _execute_rounds(u, spec: StencilSpec, mesh, block: Callable, *,
                     schedule, row_axis, col_axis, remainder_block):
-    """The untraced executor body: band split, ``lax.scan`` over fused
-    exchange rounds, remainder round, ring re-attach. Shared verbatim by
-    the eager fallback and the cached jitted single launch, so the two
+    """The executor body: band split, ``lax.scan`` over fused exchange
+    rounds, remainder round, ring re-attach. Shared verbatim by the
+    inline (traced) call and the cached jitted single launch, so the two
     are the same program by construction."""
     r = spec.radius
-    interior, bc = split_ringed_bands(u, r)
-    bc = dict(bc, tl=u[:r, :r], tr=u[:r, -r:], bl=u[-r:, :r], br=u[-r:, -r:])
+    with jax.named_scope("exchange.halo"):
+        interior, bc = split_ringed_bands(u, r)
+        bc = dict(bc, tl=u[:r, :r], tr=u[:r, -r:], bl=u[-r:, :r],
+                  br=u[-r:, -r:])
     if schedule.fused_blocks:
         step = make_sharded_step(mesh, spec, block, row_axis=row_axis,
                                  col_axis=col_axis, t=schedule.t,
@@ -485,22 +341,20 @@ def _reattach_ring(u, interior, mesh, r: int):
     is what lets the scatter take a sharded update into an unsharded
     grid under explicit mesh axes.
     """
-    return u.at[r:-r, r:-r].set(
-        interior, out_sharding=NamedSharding(mesh, P()))
+    with jax.named_scope("stencil.writeback"):
+        return u.at[r:-r, r:-r].set(
+            interior, out_sharding=NamedSharding(mesh, P()))
 
 
-# Cached jitted single launches for the untraced serial path, and cached
-# per-phase jitted callables for the traced executor — keyed by everything
-# that shaped the program (the caller's ``cache_key`` must pin whatever
-# produced ``block``). Bounded in practice by the handful of
-# (mesh, schedule) combinations a process runs.
+# Cached jitted single launches, keyed by everything that shaped the
+# program (the caller's ``cache_key`` must pin whatever produced
+# ``block``). Bounded in practice by the handful of (mesh, schedule)
+# combinations a process runs.
 _SCAN_LAUNCHES: dict = {}
-_PHASE_STEPS: dict = {}
 
 
 def run_sharded_cache_clear() -> None:
     _SCAN_LAUNCHES.clear()
-    _PHASE_STEPS.clear()
 
 
 def resolve_axes(mesh, row_axis: str | None, col_axis: str | None):
@@ -536,7 +390,6 @@ def run_sharded(u: jax.Array, spec: StencilSpec, mesh, block: Callable, *,
                 schedule, row_axis: str | None = None,
                 col_axis: str | None = None,
                 remainder_block: Callable | None = None,
-                bill=None, remainder_bill=None,
                 cache_key=None, donate: bool = False) -> jax.Array:
     """Execute a :class:`~repro.engine.schedule.SweepSchedule` over ``mesh``.
 
@@ -548,13 +401,6 @@ def run_sharded(u: jax.Array, spec: StencilSpec, mesh, block: Callable, *,
     The iters/t/remainder arithmetic lives in the schedule — this function
     only spends exchanges; ``schedule.overlap`` selects the interior/rind
     split that hides each exchange behind the halo-independent compute.
-
-    With a :mod:`repro.obs` tracer installed (and an eager host-level
-    call), rounds run through the span-per-phase executor instead —
-    bit-identical result, one ``exchange``/``interior``/``rind`` (or
-    ``compute``) span per phase. ``bill``/``remainder_bill`` are the
-    per-round :class:`~repro.engine.schedule.ExchangeBill`\\ s those spans
-    attach for ``obs.reconcile`` (None = spans carry no model attrs).
 
     Called untraced with a hashable ``cache_key`` (anything that pins how
     ``block``/``remainder_block`` were built — ``run_distributed`` passes
@@ -572,28 +418,15 @@ def run_sharded(u: jax.Array, spec: StencilSpec, mesh, block: Callable, *,
     py = mesh.shape[col_axis] if col_axis else 1
     check_divisible(hi, wi, px, py)
 
-    if _obs_host_active(u):
-        interior, bc = split_ringed_bands(u, r)
-        bc = dict(bc, tl=u[:r, :r], tr=u[:r, -r:], bl=u[-r:, :r],
-                  br=u[-r:, -r:])
-        return _run_sharded_traced(
-            u, interior, bc, spec, mesh, block, schedule=schedule,
-            row_axis=row_axis, col_axis=col_axis,
-            remainder_block=remainder_block, bill=bill,
-            remainder_bill=remainder_bill, cache_key=cache_key)
-
     if cache_key is not None and not isinstance(u, jax.core.Tracer):
-        key = (cache_key, mesh, spec, schedule, row_axis, col_axis,
-               tuple(u.shape), str(u.dtype), bool(donate))
-        fn = _SCAN_LAUNCHES.get(key)
-        if fn is None:
-            fn = jax.jit(functools.partial(
+        key = ("run_sharded", cache_key, mesh, spec, schedule, row_axis,
+               col_axis, tuple(u.shape), str(u.dtype), bool(donate))
+        return cached_launch(_SCAN_LAUNCHES, key, lambda: jax.jit(
+            functools.partial(
                 _execute_rounds, spec=spec, mesh=mesh, block=block,
                 schedule=schedule, row_axis=row_axis, col_axis=col_axis,
                 remainder_block=remainder_block),
-                donate_argnums=(0,) if donate else ())
-            _SCAN_LAUNCHES[key] = fn
-        return fn(u)
+            donate_argnums=(0,) if donate else ()), u)
 
     return _execute_rounds(u, spec, mesh, block, schedule=schedule,
                            row_axis=row_axis, col_axis=col_axis,

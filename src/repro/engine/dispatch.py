@@ -23,6 +23,7 @@ from repro.engine.device import DeviceModel, get_device
 from repro.engine.plan import DEFAULT_T, PlanError, plan_for
 from repro.engine.schedule import DEFAULT_REMAINDER_POLICY  # noqa: F401
 from repro.engine.schedule import build_schedule
+from repro.obs import metrics as _metrics
 from repro.obs.trace import span as _obs_span
 
 
@@ -209,7 +210,11 @@ def residual_for(spec: StencilSpec | None = None) -> Callable:
     """
     from repro.core.stencil import residual
     spec = spec if spec is not None else jacobi_2d_5pt()
-    return functools.partial(residual, spec=spec)
+
+    def res(u):
+        with jax.named_scope("solver.residual"):
+            return residual(u, spec=spec)
+    return res
 
 
 def _is_traced(u) -> bool:
@@ -261,10 +266,33 @@ def _execute_schedule(u: jax.Array, sched, spec: StencilSpec, bm,
         sched.iters)
 
 
-@functools.lru_cache(maxsize=256)
-def _launch_for(sched, spec: StencilSpec, bm, interpret, device,
-                donate: bool) -> Callable:
-    """Cached jitted whole-schedule launch: one dispatch per solve.
+# Jitted launches, keyed by everything that shapes their program: a
+# launch tag, the schedule and the static arguments. Bounded in practice
+# by the handful of schedules a process runs.
+_LAUNCHES: dict = {}
+
+
+def cached_launch(cache: dict, key: tuple, build: Callable, *args):
+    """Call the launch cached under ``key``, building it on a miss.
+
+    A miss counts ``engine.launch.miss`` and makes its first call — the
+    trace and the compile, or the load from the persistent compile cache
+    — inside an ``engine.compile`` span, so that a recompile shows at its
+    place on the timeline. ``key[0]`` names the launch."""
+    fn = cache.get(key)
+    if fn is not None:
+        return fn(*args)
+    _metrics.counter("engine.launch.miss").inc()
+    with _obs_span("engine.compile", launch=key[0]):
+        fn = build()
+        out = fn(*args)
+    cache[key] = fn
+    return out
+
+
+def _run_program(sched, spec: StencilSpec, bm, interpret, device,
+                 donate: bool) -> Callable:
+    """The jitted whole-schedule launch: one dispatch per solve.
 
     With ``donate=True`` the input grid's buffer is donated to XLA
     (``donate_argnums``) so the sweep updates in place — the caller's
@@ -274,19 +302,17 @@ def _launch_for(sched, spec: StencilSpec, bm, interpret, device,
     return jax.jit(go, donate_argnums=(0,) if donate else ())
 
 
-@functools.lru_cache(maxsize=256)
-def _batched_launch_for(sched, spec: StencilSpec, bm, interpret, device,
-                        donate: bool) -> Callable:
+def _batched_program(sched, spec: StencilSpec, bm, interpret, device,
+                     donate: bool) -> Callable:
     def go(us):
         return jax.vmap(lambda u: _execute_schedule(
             u, sched, spec, bm, interpret, device))(us)
     return jax.jit(go, donate_argnums=(0,) if donate else ())
 
 
-@functools.lru_cache(maxsize=256)
-def _converged_launch_for(sched, spec: StencilSpec, bm, interpret, device,
-                          max_blocks: int, donate: bool) -> Callable:
-    """Cached jitted tolerance-driven launch: ``lax.while_loop`` over
+def _converged_program(sched, spec: StencilSpec, bm, interpret, device,
+                       max_blocks: int, donate: bool) -> Callable:
+    """The jitted tolerance-driven launch: ``lax.while_loop`` over
     ``t``-sweep blocks with the in-launch residual as exit test.
 
     ``sched`` is the one-block (cadence-``t``) schedule; the loop body
@@ -304,7 +330,8 @@ def _converged_launch_for(sched, spec: StencilSpec, bm, interpret, device,
     def go(u, tol):
         def cond(carry):
             _, n, r = carry
-            return (n < max_blocks) & (r > tol)
+            with jax.named_scope("solver.residual"):
+                return (n < max_blocks) & (r > tol)
 
         def body(carry):
             v, n, _ = carry
@@ -361,10 +388,11 @@ def run_converged(u: jax.Array, spec: StencilSpec | None = None, *,
                                bm=bm, interpret=interpret, device=device,
                                remainder_policy=remainder_policy)
         max_blocks = max_iters // cadence
-        fn = _converged_launch_for(sched, spec, bm, interpret, device,
-                                   max_blocks, donate)
+        args = (sched, spec, bm, interpret, device, max_blocks, donate)
         tol_arr = jnp.float32(-1.0 if tol is None else tol)
-        u, n, r = fn(u, tol_arr)
+        u, n, r = cached_launch(
+            _LAUNCHES, ("run_converged",) + args,
+            lambda: _converged_program(*args), u, tol_arr)
         iters_done = int(n) * cadence
         sp.set(policy=sched.policy, t=cadence, iters_done=iters_done,
                residual=float(r), launch="while_loop")
@@ -417,8 +445,9 @@ def run_batched(us: jax.Array, spec: StencilSpec | None = None, *,
                            dtype=us.dtype, policy=policy, t=t, bm=bm,
                            interpret=interpret, device=device,
                            remainder_policy=remainder_policy)
-    return _batched_launch_for(sched, spec, bm, interpret, device,
-                               donate)(us)
+    args = (sched, spec, bm, interpret, device, donate)
+    return cached_launch(_LAUNCHES, ("run_batched",) + args,
+                         lambda: _batched_program(*args), us)
 
 
 def run(u: jax.Array, spec: StencilSpec | None = None, *,
@@ -468,4 +497,6 @@ def run(u: jax.Array, spec: StencilSpec | None = None, *,
                                 "buffers")
             return _execute_schedule(u, sched, spec, bm, interpret, device)
         sp.set(launch="scan")
-        return _launch_for(sched, spec, bm, interpret, device, donate)(u)
+        args = (sched, spec, bm, interpret, device, donate)
+        return cached_launch(_LAUNCHES, ("run",) + args,
+                             lambda: _run_program(*args), u)

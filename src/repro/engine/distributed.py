@@ -31,8 +31,6 @@ degenerate.
 """
 from __future__ import annotations
 
-import dataclasses
-
 import jax
 
 from repro.core.stencil import StencilSpec, apply_stencil, jacobi_2d_5pt
@@ -43,7 +41,7 @@ from repro.engine.plan import plan_for
 from repro.engine.schedule import (DEFAULT_REMAINDER_POLICY, SweepSchedule,
                                    build_schedule, effective_depth,
                                    price_exchange)
-from repro.obs.trace import get_tracer
+from repro.obs.trace import NULL_SPAN, get_tracer, span as _obs_span
 
 
 def _mesh_shape(mesh, row_axis: str | None, col_axis: str | None) -> tuple:
@@ -180,9 +178,15 @@ def run_distributed(u: jax.Array, spec: StencilSpec | None = None, *,
     scan body, remainder, ring re-attach — runs as ONE cached jitted
     launch instead of one Python dispatch per round; ``donate=True``
     additionally donates ``u``'s buffer so the solve updates in place
-    (the caller's array is invalid afterwards). With an obs tracer
-    installed, rounds run through the span-per-phase traced executor
-    instead (measurable, at per-phase dispatch cost).
+    (the caller's array is invalid afterwards).
+
+    The call is one ``dist.run`` span whose ``model_s`` is the schedule's
+    whole-launch :class:`~repro.engine.schedule.ExchangeBill` total, the
+    component ``obs.reconcile`` prices. With a :class:`~repro.obs.Tracer`
+    installed the span waits for the launch, so its duration is the
+    launch's wall-clock; the program is the same one launch either way.
+    The split into exchange, interior and rind lives in the device trace,
+    under the ``exchange.*`` scopes of :mod:`repro.dist.stencil`.
     """
     from repro.dist import stencil as dstencil
 
@@ -190,48 +194,45 @@ def run_distributed(u: jax.Array, spec: StencilSpec | None = None, *,
     if interpret is None:
         interpret = not _on_tpu()
     device = _resolve_device_name(device)
-    sched, shard_shape, (row_axis, col_axis) = plan_distributed(
-        u.shape, u.dtype, spec, mesh=mesh, policy=policy, iters=iters, t=t,
-        bm=bm, row_axis=row_axis, col_axis=col_axis, interpret=interpret,
-        device=device, remainder_policy=remainder_policy, overlap=overlap)
-    mesh_shape = _mesh_shape(mesh, row_axis, col_axis)
-    block = local_sweep_for(sched.policy, spec, shard_shape=shard_shape,
-                            dtype=u.dtype, iters=iters, t=sched.t, bm=bm,
-                            interpret=interpret, device=device,
-                            mesh_shape=mesh_shape, overlap=sched.overlap)
-    remainder_block = None
-    if sched.remainder and sched.remainder_policy != sched.policy:
-        # Fused main policy with leftovers: the shallower remainder
-        # exchange runs the non-fused remainder policy per shard.
-        remainder_block = local_sweep_for(
-            sched.remainder_policy, spec, shard_shape=shard_shape,
-            dtype=u.dtype, iters=sched.remainder, t=sched.remainder, bm=bm,
-            interpret=interpret, device=device, mesh_shape=mesh_shape,
-            overlap=sched.overlap)
-    bill = remainder_bill = None
-    if get_tracer() is not None:
-        # Per-round bills for the traced executor's phase spans: one
-        # fused round, and the (shallower) remainder round, priced by
-        # the same price_exchange the overlap decision came from.
-        if sched.fused_blocks:
-            bill = price_exchange(
-                dataclasses.replace(sched, iters=sched.t, fused_blocks=1,
-                                    remainder=0),
-                shard_shape=shard_shape, dtype=u.dtype, spec=spec,
-                device=device, mesh_shape=mesh_shape)
-        if sched.remainder:
-            remainder_bill = price_exchange(
-                dataclasses.replace(sched, iters=sched.remainder,
-                                    fused_blocks=0),
-                shard_shape=shard_shape, dtype=u.dtype, spec=spec,
-                device=device, mesh_shape=mesh_shape)
-    # Everything that shaped `block`/`remainder_block` beyond what the
-    # schedule already pins — so the jitted single launch can be reused
-    # across calls (a fresh closure is built per call, its program isn't).
-    cache_key = ("run_distributed", bm, interpret, device,
-                 remainder_policy)
-    return dstencil.run_sharded(u, spec, mesh, block, schedule=sched,
-                                row_axis=row_axis, col_axis=col_axis,
-                                remainder_block=remainder_block,
-                                bill=bill, remainder_bill=remainder_bill,
-                                cache_key=cache_key, donate=donate)
+    with _obs_span("dist.run", iters=iters, shape=tuple(u.shape),
+                   requested_policy=policy) as sp:
+        sched, shard_shape, (row_axis, col_axis) = plan_distributed(
+            u.shape, u.dtype, spec, mesh=mesh, policy=policy, iters=iters,
+            t=t, bm=bm, row_axis=row_axis, col_axis=col_axis,
+            interpret=interpret, device=device,
+            remainder_policy=remainder_policy, overlap=overlap)
+        mesh_shape = _mesh_shape(mesh, row_axis, col_axis)
+        if sp is not NULL_SPAN:
+            bill = price_exchange(sched, shard_shape=shard_shape,
+                                  dtype=u.dtype, spec=spec, device=device,
+                                  mesh_shape=mesh_shape)
+            sp.set(policy=sched.policy, t=sched.t, overlap=sched.overlap,
+                   exchanges=sched.exchanges, model_s=(
+                       bill.overlapped_s if sched.overlap
+                       else bill.serial_s), **bill.as_attrs())
+        block = local_sweep_for(sched.policy, spec, shard_shape=shard_shape,
+                                dtype=u.dtype, iters=iters, t=sched.t,
+                                bm=bm, interpret=interpret, device=device,
+                                mesh_shape=mesh_shape, overlap=sched.overlap)
+        remainder_block = None
+        if sched.remainder and sched.remainder_policy != sched.policy:
+            # Fused main policy with leftovers: the shallower remainder
+            # exchange runs the non-fused remainder policy per shard.
+            remainder_block = local_sweep_for(
+                sched.remainder_policy, spec, shard_shape=shard_shape,
+                dtype=u.dtype, iters=sched.remainder, t=sched.remainder,
+                bm=bm, interpret=interpret, device=device,
+                mesh_shape=mesh_shape, overlap=sched.overlap)
+        # Everything that shaped `block`/`remainder_block` beyond what the
+        # schedule already pins — so the jitted single launch can be
+        # reused across calls (a fresh closure is built per call, its
+        # program isn't).
+        cache_key = ("run_distributed", bm, interpret, device,
+                     remainder_policy)
+        out = dstencil.run_sharded(u, spec, mesh, block, schedule=sched,
+                                   row_axis=row_axis, col_axis=col_axis,
+                                   remainder_block=remainder_block,
+                                   cache_key=cache_key, donate=donate)
+        if get_tracer() is not None and not isinstance(out, jax.core.Tracer):
+            out = jax.block_until_ready(out)
+    return out

@@ -44,6 +44,13 @@ f32 and store in the input dtype. Launch parameters come from
 static ``device`` (registry name or frozen DeviceModel) so the plan is
 validated against the fast-memory budget of the hardware being planned
 for, not a constant.
+
+Names on the device trace: every kernel's ``pallas_call`` is named
+:data:`KERNEL_PREFIX` + its policy (``stencil_shifted``,
+``stencil_rowchunk``, ``stencil_dbuf``, ``stencil_temporal``), which XLA
+keeps as the device operation's name whatever Python function wraps the
+call; the scatter of the kernel's interior back into the ringed grid runs
+under the ``jax.named_scope`` ``stencil.writeback``.
 """
 from __future__ import annotations
 
@@ -59,6 +66,9 @@ from repro.engine.device import DeviceModel  # noqa: F401  (annotations)
 from repro.engine.plan import plan_for
 from repro.obs import metrics as _metrics
 
+#: Prefix of every kernel's name on the device trace.
+KERNEL_PREFIX = "stencil_"
+
 
 def _tap_sum(c, bm: int, r: int, w: int, offsets, weights):
     """Weighted sum of in-VMEM shifted views of a resident (bm+2r, w) window."""
@@ -71,8 +81,10 @@ def _tap_sum(c, bm: int, r: int, w: int, offsets, weights):
     return acc
 
 
-def _interior_index(shape, r: int):
-    return tuple(slice(r, s - r) for s in shape)
+def _writeback(u, out, r: int):
+    """The kernel's interior written back into the ringed grid."""
+    with jax.named_scope("stencil.writeback"):
+        return u.at[tuple(slice(r, s - r) for s in u.shape)].set(out)
 
 
 def _compiler_params(plan, interpret: bool):
@@ -165,8 +177,9 @@ def stencil_shifted(u: jax.Array, spec: StencilSpec, *, bm: int | None = None,
         out_shape=jax.ShapeDtypeStruct((hi, wi), u.dtype),
         compiler_params=_compiler_params(plan, interpret),
         interpret=interpret,
+        name=KERNEL_PREFIX + "shifted",
     )(*views)
-    return u.at[_interior_index(u.shape, r)].set(out)
+    return _writeback(u, out, r)
 
 
 # ---------------------------------------------------------------------------
@@ -195,8 +208,9 @@ def _window_sweep(u, spec, policy: str, bm, interpret, device, buffers: int):
         out_shape=jax.ShapeDtypeStruct((hi, wi), u.dtype),
         compiler_params=_compiler_params(plan, interpret),
         interpret=interpret,
+        name=KERNEL_PREFIX + policy,
     )(*[u] * len(in_specs))
-    return u.at[_interior_index(u.shape, r)].set(out)
+    return _writeback(u, out, r)
 
 
 @functools.partial(jax.jit,
@@ -306,5 +320,6 @@ def stencil_temporal(u: jax.Array, spec: StencilSpec, *, t: int | None = None,
         out_shape=jax.ShapeDtypeStruct((hi, wi), u.dtype),
         compiler_params=_compiler_params(plan, interpret),
         interpret=interpret,
+        name=KERNEL_PREFIX + "temporal",
     )(*operands)
-    return u.at[_interior_index(u.shape, r)].set(out)
+    return _writeback(u, out, r)

@@ -170,9 +170,8 @@ class ExchangeBill:
 
     def as_attrs(self) -> dict:
         """The bill as flat span attrs (``model_``-prefixed seconds), the
-        form the traced distributed executor attaches to each round's
-        ``exchange``/``interior``/``rind`` spans so ``obs.reconcile`` can
-        join measured durations against this pricing."""
+        form ``run_distributed`` attaches to its ``dist.run`` span beside
+        the ``model_s`` total ``obs.reconcile`` joins against."""
         return {"model_exchange_s": self.exchange_s,
                 "model_compute_s": self.compute_s,
                 "model_interior_s": self.interior_s,

@@ -85,10 +85,10 @@ def main(argv=None):
                          "as Chrome-trace JSON (open in Perfetto / "
                          "chrome://tracing, or inspect with "
                          "'python -m repro.obs summarize PATH'). With "
-                         "--devices N the distributed executor runs its "
-                         "span-per-phase form: one exchange/interior/rind "
-                         "span per halo round, each carrying the round's "
-                         "modeled ExchangeBill")
+                         "--devices N the solve is one dist.run span "
+                         "carrying the launch's modeled ExchangeBill; the "
+                         "device's exchange/interior/rind phases are in a "
+                         "jax.profiler capture, under their named scopes")
     args = ap.parse_args(argv)
 
     from repro.compile_cache import use_compile_cache
@@ -129,7 +129,6 @@ def _dispatch(args):
     from repro import engine
     from repro.core.stencil import jacobi_2d_5pt, make_laplace_problem
     from repro.kernels.ops import VERSION_TO_POLICY
-    from repro.obs.trace import get_tracer
 
     device = engine.get_device(args.device_model).name \
         if args.device_model else None
@@ -272,26 +271,12 @@ def _dispatch(args):
                                      device=device,
                                      mesh_shape=(args.devices,))
         print(f"exchange bill: {bill.describe()}")
-        if get_tracer() is not None:
-            # Traced: run eagerly so the executor's span-per-phase form
-            # engages (an outer jit would fold the spans into trace time
-            # and hide the per-round exchange/interior/rind splits).
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(engine.run_distributed(
-                u0, mesh=mesh, policy=policy, iters=args.iters, t=t_fuse,
-                row_axis="x", device=device, overlap=overlap))
-            dt = time.perf_counter() - t0
-        else:
-            run = jax.jit(lambda u: engine.run_distributed(
-                u, mesh=mesh, policy=policy, iters=args.iters, t=t_fuse,
-                row_axis="x", device=device, overlap=overlap))
-            run(u0).block_until_ready()  # compile
-            t0 = time.perf_counter()
-            out = run(u0)
-            out.block_until_ready()
-            dt = time.perf_counter() - t0
-        result = np.asarray(out, np.float32)[1:-1, 1:-1]
         fuse = _fuse(sched.policy, sched.t)
+
+        def run(u):  # one cached jitted launch per call
+            return engine.run_distributed(
+                u, mesh=mesh, policy=policy, iters=args.iters, t=t_fuse,
+                row_axis="x", device=device, overlap=overlap)
     else:
         policy = VERSION_TO_POLICY.get(args.kernel, args.kernel)
         if policy == "ref":
@@ -341,27 +326,16 @@ def _dispatch(args):
             fuse = _fuse(sched.policy, sched.t)
             if args.verify:
                 _verify(policy, t_fuse)
-            if get_tracer() is not None:
-                # Traced: eager call so engine.run's span measures real
-                # wall-clock (the policy kernels are jitted inside).
-                t0 = time.perf_counter()
-                out = jax.block_until_ready(engine.run(
-                    u0, policy=policy, iters=args.iters, t=t_fuse,
-                    device=device))
-                dt = time.perf_counter() - t0
-                result = np.asarray(out, np.float32)[1:-1, 1:-1]
-                _report(args, out, result, dt, fuse)
-                return
-            run = jax.jit(lambda u: engine.run(
-                u, policy=policy, iters=args.iters, t=t_fuse,
-                device=device))
-        run(u0).block_until_ready()
-        t0 = time.perf_counter()
-        out = run(u0)
-        out.block_until_ready()
-        dt = time.perf_counter() - t0
-        result = np.asarray(out, np.float32)[1:-1, 1:-1]
 
+            def run(u):  # one cached jitted launch per call
+                return engine.run(u, policy=policy, iters=args.iters,
+                                  t=t_fuse, device=device)
+    run(u0).block_until_ready()  # compile
+    t0 = time.perf_counter()
+    out = run(u0)
+    out.block_until_ready()
+    dt = time.perf_counter() - t0
+    result = np.asarray(out, np.float32)[1:-1, 1:-1]
     _report(args, out, result, dt, fuse)
 
 

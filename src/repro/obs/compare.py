@@ -6,7 +6,7 @@ time, and the schedule pricing ``build_schedule(overlap=None)`` decides
 from — but until now nothing checked those predictions against what
 actually ran. :func:`reconcile` closes the loop: instrumented spans
 attach their own prediction as a ``model_s`` attr (seconds the pricing
-layer expected; the distributed executor attaches each round's full
+layer expected; ``run_distributed`` attaches its launch's whole
 :class:`ExchangeBill`, the simulator its ``model_time_s``), and this
 module joins measured span durations against them per component name.
 
@@ -94,7 +94,7 @@ def reconcile(trace, *, tolerance: float = 2.0) -> DriftReport:
     event list, or a path to a trace file — reconciling a reloaded file
     gives the same report as the in-memory tracer. Spans participate by
     carrying a ``model_s`` attr; totals group by span name (so every
-    ``exchange`` span across every round folds into one ``exchange``
+    ``dist.run`` span across every launch folds into one ``dist.run``
     component). A component whose measured/modeled ratio leaves
     ``[1/tolerance, tolerance]`` fires a warning-severity ``OBS-DRIFT``
     diagnostic; zero-model components and empty traces fire

@@ -1,26 +1,30 @@
-"""Contextvar-scoped tracing: nested spans -> Chrome-trace JSON.
+"""Contextvar-scoped tracing: nested spans on the profiler's clock.
 
-Zero-dependency (stdlib only) and **disabled by default**: until a
-:class:`Tracer` is installed (``set_tracer`` / ``use_tracer``), the
+Stdlib only at import, and **disabled by default**: with no
+:class:`Tracer` installed and no JAX profiler session capturing, the
 module-level :func:`span` returns one shared no-op singleton — no
-allocation, no clock read, no branch beyond the contextvar lookup — so
-instrumented hot paths (``engine.run``, the distributed exchange rounds,
-``serve.step``) cost nothing when nobody is watching. Tests pin both
-properties: ``obs.span("a") is obs.span("b")`` with no tracer, and
-bit-identical engine output with obs on vs off.
+allocation, no clock read; its only cost beyond the contextvar lookup is
+one ``TraceAnnotation.is_enabled()`` check — so instrumented hot paths
+(``engine.run``, ``run_distributed``, ``serve.step``) cost nothing when
+nobody is watching. Tests pin both properties: ``obs.span("a") is
+obs.span("b")`` with nothing watching, and bit-identical engine output
+with obs on vs off.
 
-With a tracer installed, ``with span(name, **attrs) as sp`` records a
-frozen :class:`SpanEvent` on exit (start/duration in microseconds since
-the tracer's epoch, the nesting path, and the attrs — ``sp.set(...)``
-adds more mid-span, e.g. a resolved policy or a modeled bill). Counter
-*tracks* (:meth:`Tracer.counter`) record time series like per-core busy
-seconds. Export surfaces:
+A span has two observers, either or both of which may be live:
 
-* :meth:`Tracer.write_trace` — Chrome-trace/Perfetto JSON (open in
-  ``chrome://tracing`` or https://ui.perfetto.dev); spans are ``ph: "X"``
-  complete events, counters ``ph: "C"`` tracks, attrs ride in ``args``.
-* :meth:`Tracer.summary` / :meth:`Tracer.describe` — a structured tree
-  aggregated by span path (count, total, mean), for terminal output.
+* **The JAX profiler** (``jax.profiler.trace`` / ``start_trace``): the
+  span opens a ``jax.profiler.TraceAnnotation`` of the same name carrying
+  its attrs as metadata, so it lands in the capture's host plane on the
+  same clock as the device operations it launched — the view to open in
+  xprof or Perfetto. ``jax`` is imported only when the first span asks.
+* **A Tracer** (``set_tracer`` / ``use_tracer``): ``with span(name,
+  **attrs) as sp`` records a frozen :class:`SpanEvent` on exit (start and
+  duration in microseconds since the tracer's epoch, the nesting path,
+  and the attrs — ``sp.set(...)`` adds more mid-span, e.g. a resolved
+  policy or a modeled bill). Counter *tracks* (:meth:`Tracer.counter`)
+  record time series like per-core busy seconds.
+  :meth:`Tracer.write_trace` exports Chrome-trace JSON and
+  :meth:`Tracer.describe` a summary tree aggregated by span path.
 
 Spans attach model predictions via the ``model_s`` attr (seconds the
 pricing layer expected the span to take); :func:`repro.obs.compare.
@@ -28,17 +32,18 @@ reconcile` joins those against the measured durations. A ``sink``
 callable receives every finished :class:`SpanEvent` as it closes —
 ``launch/solve.py --serve`` uses this for live per-block progress lines.
 
-One caveat worth knowing: a span entered *inside* a ``jax.jit`` trace
-measures trace time (schedule resolution, lowering), not run time — real
-host work, but not kernel wall-clock. The distributed executor therefore
-switches to per-phase launches with ``block_until_ready`` between spans
-when a tracer is installed (``repro.dist.stencil``).
+A span entered *inside* a ``jax.jit`` trace measures trace time (schedule
+resolution, lowering), and a span around an asynchronous launch measures
+its dispatch; what the device did in a phase of a launch is read from
+the profiler's device trace, under the ``jax.named_scope`` names the
+engine gives its phases.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
 import dataclasses
+import functools
 import json
 import os
 import threading
@@ -101,35 +106,63 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+@functools.cache
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    ``repro.obs`` stays importable from the engine's lowest layers."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+def _profiling() -> bool:
+    """Whether a JAX profiler session is capturing host events."""
+    return _annotation().is_enabled()
+
+
 class Span:
-    """A live span; records a frozen :class:`SpanEvent` on ``__exit__``."""
+    """A live span: a profiler annotation while ``annotate``, and a
+    :class:`SpanEvent` recorded on ``__exit__`` when ``tracer`` is set."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_annotate",
+                 "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+    def __init__(self, tracer: "Tracer | None", name: str, attrs: dict,
+                 annotate: bool = False):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
+        self._annotate = annotate
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "Span":
-        self._tracer._stack.append(self.name)
-        self._t0 = self._tracer._now_us()
+        if self._annotate:
+            self._annotation = _annotation()(self.name, **self.attrs)
+            self._annotation.__enter__()
+        if self._tracer is not None:
+            self._tracer._stack.append(self.name)
+            self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc) -> bool:
-        t1 = self._tracer._now_us()
-        stack = self._tracer._stack
-        path = tuple(stack)
-        stack.pop()
-        self._tracer._emit(SpanEvent(
-            name=self.name, path=path, ts_us=self._t0,
-            dur_us=t1 - self._t0, pid=self._tracer.pid,
-            tid=threading.get_ident() & 0x7FFFFFFF,
-            attrs={k: _jsonable(v) for k, v in self.attrs.items()}))
+        tracer = self._tracer
+        if tracer is not None:
+            t1 = tracer._now_us()
+            path = tuple(tracer._stack)
+            tracer._stack.pop()
+            tracer._emit(SpanEvent(
+                name=self.name, path=path, ts_us=self._t0,
+                dur_us=t1 - self._t0, pid=tracer.pid,
+                tid=threading.get_ident() & 0x7FFFFFFF,
+                attrs={k: _jsonable(v) for k, v in self.attrs.items()}))
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         return False
 
 
@@ -158,7 +191,7 @@ class Tracer:
             self.sink(event)
 
     def span(self, name: str, **attrs) -> Span:
-        return Span(self, name, attrs)
+        return Span(self, name, attrs, _profiling())
 
     def counter(self, name: str, values: dict, *,
                 ts_us: float | None = None) -> None:
@@ -223,12 +256,14 @@ def use_tracer(tracer: Tracer | None):
 
 
 def span(name: str, **attrs):
-    """A span against the installed tracer — or the shared no-op when
-    none is installed (the disabled path allocates nothing)."""
+    """A span against the installed tracer and any capturing profiler
+    session — or the shared no-op when neither is watching (the disabled
+    path allocates nothing)."""
     tracer = _TRACER.get()
-    if tracer is None:
+    annotate = _profiling()
+    if tracer is None and not annotate:
         return NULL_SPAN
-    return tracer.span(name, **attrs)
+    return Span(tracer, name, attrs, annotate)
 
 
 def counter(name: str, values: dict) -> None:

@@ -218,9 +218,10 @@ def _superblock_for(key: BucketKey, k: int):
     over the slot axis — bit-identical per lane to a solo ``engine.run``)
     and computes per-slot residuals inside the same launch; a lane that
     has converged (``residual <= tol``) or spent its block budget is
-    *frozen*: ``jnp.where`` carries its iterate through unchanged, so its
-    final value is bit-exactly the iterate at its stopping block — the
-    same array a one-block-per-launch server would have evicted. The
+    *frozen*: ``jnp.where`` (named scope ``serve.freeze``) carries its
+    iterate through unchanged, so its final value is bit-exactly the
+    iterate at its stopping block — the same array a one-block-per-launch
+    server would have evicted. The
     residual history ``(k, S)`` plus per-block liveness flags return with
     the launch so the host replays block-boundary events (streaming,
     eviction accounting) after ONE sync per superblock instead of one
@@ -245,7 +246,8 @@ def _superblock_for(key: BucketKey, k: int):
             live = (~conv) & (n < budgets)
             vs = jax.vmap(one_block)(us)
             res = jax.vmap(res_fn)(vs)
-            us = jnp.where(live[:, None, None], vs, us)
+            with jax.named_scope("serve.freeze"):
+                us = jnp.where(live[:, None, None], vs, us)
             n = n + live.astype(n.dtype)
             conv = conv | (live & (res <= tols))
             return (us, n, conv), (res, live)

@@ -77,7 +77,10 @@ def _compile(one_chip, shape, dtype, policy, masked):
                          ids=[p + ("_masked" if m else "") for p, m in CASES])
 def test_kernel_compiles_at_paper_width(one_chip, policy, masked, dtype):
     plan, compiled = _compile(one_chip, PAPER, dtype, policy, masked)
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # The device operation carries the kernel's own name.
+    assert f"%{P.KERNEL_PREFIX}{policy}." in hlo
     assert plan.vmem_bytes <= get_device("tpu_v5e").fast_memory_bytes
     assert plan.nblocks >= 2  # the paper's grid never fits one block
 

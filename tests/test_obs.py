@@ -1,20 +1,28 @@
 """repro.obs: disabled-by-default tracing, metrics, reconciliation.
 
-The two load-bearing properties of the observability layer:
+The load-bearing properties of the observability layer:
 
-* **Off is free and invisible** — with no tracer installed, ``obs.span``
-  returns one shared no-op singleton (no allocation), and every
-  instrumented path (``engine.run``, ``run_distributed``, the server)
-  produces bit-identical output with obs on vs off.
+* **Off is free and invisible** — with no tracer installed and no
+  profiler capturing, ``obs.span`` returns one shared no-op singleton (no
+  allocation), and every instrumented path (``engine.run``,
+  ``run_distributed``, the server) produces bit-identical output with obs
+  on vs off, from the same cached launch.
 * **On is honest** — spans carry their nesting path and attrs into a
-  well-formed Chrome trace, and ``reconcile`` joins measured durations
-  against attached ``model_s`` predictions, firing structured
-  ``OBS-DRIFT`` / ``OBS-UNMODELED`` diagnostics.
+  well-formed Chrome trace and into a JAX profiler capture, on the device
+  trace's clock; ``reconcile`` joins measured durations against attached
+  ``model_s`` predictions, firing structured ``OBS-DRIFT`` /
+  ``OBS-UNMODELED`` diagnostics.
+* **Device phases carry stable names** — every kernel is named with the
+  program's prefix, and the launches' phases are named scopes that reach
+  each operation's ``op_name``.
 """
+import glob
 import os
+import re
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -67,6 +75,108 @@ def test_engine_run_bit_identical_obs_on_vs_off():
     (sched_ev,) = [e for e in tracer.events
                    if e.name == "engine.build_schedule"]
     assert sched_ev.path == ("engine.run", "engine.build_schedule")
+
+
+def _host_events(log_dir: str, name: str) -> list:
+    """``(stats, start_ns, end_ns)`` of every host event called ``name``
+    in the one profiler capture under ``log_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(dict(ev.stats), ev.start_ns, ev.end_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name == name]
+
+
+def test_spans_land_in_a_profiler_capture_with_their_attrs(tmp_path):
+    """Under a profiler session a span is live even with no tracer: it
+    becomes a host annotation carrying its attrs, set-later ones too,
+    on the profiler's clock around what it launched."""
+    from repro import engine
+    u = make_laplace_problem(18, 34, dtype=np.float32, left=1.0)
+    assert obs.get_tracer() is None
+    with jax.profiler.trace(str(tmp_path)):
+        assert obs.span("engine.run") is not NULL_SPAN
+        out = engine.run(u, jacobi_2d_5pt(), policy="temporal", iters=8,
+                         t=4, interpret=True).block_until_ready()
+    assert obs.span("engine.run") is NULL_SPAN      # capture over: off
+    (run,) = _host_events(str(tmp_path), "engine.run")
+    stats, start, end = run
+    assert stats["iters"] == 8 and stats["requested_policy"] == "temporal"
+    assert stats["policy"] == "temporal" and stats["t"] == 4
+    (sched,) = _host_events(str(tmp_path), "engine.build_schedule")
+    assert start <= sched[1] <= sched[2] <= end      # nested, one clock
+    off = engine.run(u, jacobi_2d_5pt(), policy="temporal", iters=8, t=4,
+                     interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(off))
+
+
+def test_launch_cache_miss_compiles_in_a_span_once():
+    """A launch-cache miss counts ``engine.launch.miss`` and puts its
+    compile in an ``engine.compile`` span under the call; a hit adds
+    neither."""
+    from repro import engine
+    u = make_laplace_problem(18, 42, dtype=np.float32, left=1.0)
+    kw = dict(policy="temporal", iters=12, t=4, interpret=True)
+    tracer = Tracer()
+    before = metrics.counter("engine.launch.miss").value
+    with use_tracer(tracer):
+        engine.run(u, jacobi_2d_5pt(), **kw)
+        engine.run(u, jacobi_2d_5pt(), **kw)
+    assert metrics.counter("engine.launch.miss").value == before + 1
+    (comp,) = [e for e in tracer.events if e.name == "engine.compile"]
+    assert comp.path == ("engine.run", "engine.compile")
+    assert comp.attrs["launch"] == "run"
+
+
+@pytest.mark.parametrize("policy", ["shifted", "rowchunk", "dbuf",
+                                    "temporal"])
+def test_every_kernel_is_named_with_the_program_prefix(policy):
+    """The device operation's name is the ``pallas_call``'s: each one is
+    the documented prefix plus its policy, not the wrapping function's."""
+    from repro import engine
+    from repro.engine.policies import KERNEL_PREFIX
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    u = make_laplace_problem(18, 34, dtype=np.float32, left=1.0)
+    jaxpr = jax.make_jaxpr(lambda v: engine.run(
+        v, jacobi_2d_5pt(), policy=policy, iters=4, t=4,
+        interpret=True))(u)
+    names = {e.params["name"] for e in eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"}
+    assert KERNEL_PREFIX == "stencil_"
+    assert names == {KERNEL_PREFIX + policy}
+
+
+def _op_names(hlo: str) -> set:
+    return set(re.findall(r'op_name="([^"]*)"', hlo))
+
+
+def test_converged_launch_names_residual_and_writeback():
+    """The compiled ``run_converged`` program carries its phases in every
+    operation's ``op_name``: the residual and exit test, and the kernel's
+    write-back."""
+    from repro.engine import dispatch
+    from repro.engine.schedule import build_schedule
+    spec = jacobi_2d_5pt()
+    u = make_laplace_problem(18, 34, dtype=np.float32, left=1.0)
+    sched = build_schedule(4, spec=spec, shape=u.shape, dtype=u.dtype,
+                           policy="temporal", t=4, interpret=True)
+    hlo = dispatch._converged_program(
+        sched, spec, None, True, None, 3, False).lower(
+        u, jnp.float32(1e-3)).compile().as_text()
+    names = _op_names(hlo)
+    assert any("/solver.residual/reduce_max" in n for n in names), names
+    assert any("/solver.residual/" in n and "while/cond" in n
+               for n in names), names               # the exit test
+    assert any("/stencil.writeback/" in n for n in names), names
 
 
 # ---------------------------------------------------------------------------
@@ -269,7 +379,8 @@ DIST_SCRIPT = """
 import numpy as np, jax
 from repro import engine
 from repro.core.stencil import jacobi_2d_5pt, make_laplace_problem
-from repro.obs import reconcile
+from repro.dist import stencil as dstencil
+from repro.obs import metrics, reconcile
 from repro.obs.trace import Tracer, use_tracer
 
 u = make_laplace_problem(34, 130, dtype=np.float32, left=1.0)
@@ -279,38 +390,83 @@ kw = dict(mesh=mesh, policy="temporal", iters=10, t=4, interpret=True)
 
 for overlap in (False, True):
     off = np.asarray(engine.run_distributed(u, spec, overlap=overlap, **kw))
+    launches = dict(dstencil._SCAN_LAUNCHES)
+    misses = metrics.counter("engine.launch.miss").value
     tracer = Tracer()
     with use_tracer(tracer):
-        on = np.asarray(jax.block_until_ready(
-            engine.run_distributed(u, spec, overlap=overlap, **kw)))
+        on = np.asarray(engine.run_distributed(u, spec, overlap=overlap,
+                                               **kw))
     assert (on == off).all(), f"overlap={overlap}: traced run diverged"
+    # The one executor: the traced call reuses the untraced launch.
+    assert dstencil._SCAN_LAUNCHES == launches
+    assert metrics.counter("engine.launch.miss").value == misses
     names = [e.name for e in tracer.events]
-    assert names.count("dist.round") == 3, names  # 2 fused + remainder
-    want = {"interior", "rind"} if overlap else {"compute"}
-    assert want <= set(names), (overlap, names)
-    rounds = [e for e in tracer.events if e.name == "exchange"]
-    assert len(rounds) == 3
-    for ev in rounds:   # every exchange span carries its round's bill
-        assert ev.attrs["model_s"] > 0
-        assert ev.attrs["halo_bytes"] > 0
-        assert ev.attrs["model_exchange_s"] > 0
+    assert "engine.compile" not in names, names
+    (run,) = [e for e in tracer.events if e.name == "dist.run"]
+    assert run.attrs["overlap"] is overlap
+    assert run.attrs["exchanges"] == 3      # 2 fused rounds + remainder
+    assert run.attrs["model_s"] > 0 and run.attrs["halo_bytes"] > 0
+    assert run.attrs["model_s"] == (run.attrs["model_overlapped_s"]
+                                    if overlap else
+                                    run.attrs["model_serial_s"])
     rep = reconcile(tracer)
-    comps = {c.component for c in rep.components}
-    assert "exchange" in comps, comps
+    comps = {c.component: c for c in rep.components}
+    assert comps["dist.run"].spans == 1, comps
+    assert comps["dist.run"].modeled_s == run.attrs["model_s"]
     # Interpret-mode CPU vs a modeled chip: drift is the information.
     assert rep.report.ok
     print(f"overlap={overlap} ok: {sorted(comps)}")
 print("OBS DIST OK")
 """
 
+DIST_HLO_SCRIPT = """
+import re
+import numpy as np, jax
+from repro import engine
+from repro.core.stencil import make_laplace_problem
 
-@pytest.mark.slow
-def test_run_distributed_bit_identical_obs_on_vs_off():
+u = make_laplace_problem(34, 130, dtype=np.float32, left=1.0)
+mesh = jax.make_mesh((2,), ("x",))
+for overlap in (False, True):
+    hlo = jax.jit(lambda v: engine.run_distributed(
+        v, mesh=mesh, policy="temporal", iters=10, t=4, interpret=True,
+        overlap=overlap)).lower(u).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    scopes = {s for n in names for s in n.split("/")
+              if s.startswith(("exchange.", "stencil."))}
+    want = {"exchange.halo", "stencil.writeback"} | (
+        {"exchange.interior", "exchange.rind"} if overlap else set())
+    assert want <= scopes, (overlap, scopes)
+    permutes = [l for l in hlo.splitlines() if "collective-permute" in l
+                and "op_name=" in l]
+    assert permutes and all("/exchange.halo/" in l for l in permutes)
+    print(f"overlap={overlap} ok: {sorted(scopes)}")
+print("DIST HLO OK")
+"""
+
+
+def _run_on_two_devices(script: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    proc = subprocess.run([sys.executable, "-c", DIST_SCRIPT], env=env,
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, f"\n{proc.stdout}\n{proc.stderr}"
-    assert "OBS DIST OK" in proc.stdout
+    return proc.stdout
+
+
+@pytest.mark.slow
+def test_run_distributed_bit_identical_obs_on_vs_off():
+    """One executor: with a tracer installed ``run_distributed`` runs the
+    same cached one-launch program, bit-identical, as one ``dist.run``
+    span that carries the launch's modeled bill for ``reconcile``."""
+    assert "OBS DIST OK" in _run_on_two_devices(DIST_SCRIPT)
+
+
+@pytest.mark.slow
+def test_run_distributed_hlo_names_its_phases():
+    """The compiled distributed program carries the exchange, overlap and
+    write-back phases in its operations' ``op_name``; every
+    ``collective-permute`` sits under ``exchange.halo``."""
+    assert "DIST HLO OK" in _run_on_two_devices(DIST_HLO_SCRIPT)
