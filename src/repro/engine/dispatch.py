@@ -24,6 +24,7 @@ from repro.engine.plan import DEFAULT_T, PlanError, plan_for
 from repro.engine.schedule import DEFAULT_REMAINDER_POLICY  # noqa: F401
 from repro.engine.schedule import build_schedule
 from repro.obs import metrics as _metrics
+from repro.obs.trace import NULL_SPAN
 from repro.obs.trace import span as _obs_span
 
 
@@ -217,6 +218,19 @@ def residual_for(spec: StencilSpec | None = None) -> Callable:
     return res
 
 
+def kernel_attrs(sched, shape, dtype, spec: StencilSpec, bm,
+                 device, masked: bool = False) -> dict:
+    """The launched kernel's form as span attributes: its strip height
+    and the rows it sweeps per row it keeps; none for the pure-jnp
+    reference, which has no plan."""
+    if sched.policy == "reference":
+        return {}
+    plan = plan_for(shape, dtype, spec, sched.policy, bm=bm, t=sched.t,
+                    device=device, masked=masked)
+    return {"strip_rows": plan.strip_rows,
+            "recompute": round(plan.recompute, 4)}
+
+
 def _is_traced(u) -> bool:
     """True when ``u`` is an abstract tracer (we are inside jit/vmap/scan).
 
@@ -394,8 +408,10 @@ def run_converged(u: jax.Array, spec: StencilSpec | None = None, *,
             _LAUNCHES, ("run_converged",) + args,
             lambda: _converged_program(*args), u, tol_arr)
         iters_done = int(n) * cadence
-        sp.set(policy=sched.policy, t=cadence, iters_done=iters_done,
-               residual=float(r), launch="while_loop")
+        if sp is not NULL_SPAN:
+            sp.set(policy=sched.policy, t=cadence, iters_done=iters_done,
+                   residual=float(r), launch="while_loop",
+                   **kernel_attrs(sched, u.shape, u.dtype, spec, bm, device))
     return u, iters_done, float(r)
 
 
@@ -488,8 +504,11 @@ def run(u: jax.Array, spec: StencilSpec | None = None, *,
                                dtype=u.dtype, policy=policy, t=t, bm=bm,
                                interpret=interpret, device=device,
                                remainder_policy=remainder_policy)
-        sp.set(policy=sched.policy, t=sched.t,
-               fused_blocks=sched.fused_blocks, remainder=sched.remainder)
+        if sp is not NULL_SPAN:
+            sp.set(policy=sched.policy, t=sched.t,
+                   fused_blocks=sched.fused_blocks,
+                   remainder=sched.remainder,
+                   **kernel_attrs(sched, u.shape, u.dtype, spec, bm, device))
         if _is_traced(u):
             if donate:
                 raise PlanError("donate=True needs a concrete host array; "

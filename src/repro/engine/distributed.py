@@ -36,11 +36,11 @@ import jax
 from repro.core.stencil import StencilSpec, apply_stencil, jacobi_2d_5pt
 from repro.engine.device import DeviceModel
 from repro.engine.dispatch import (_on_tpu, _resolve_device_name, get_policy,
-                                   resolve_auto)
+                                   kernel_attrs, resolve_auto)
 from repro.engine.plan import plan_for
 from repro.engine.schedule import (DEFAULT_REMAINDER_POLICY, SweepSchedule,
                                    build_schedule, effective_depth,
-                                   price_exchange)
+                                   overlap_feasible, price_exchange)
 from repro.obs.trace import NULL_SPAN, get_tracer, span as _obs_span
 
 
@@ -101,6 +101,19 @@ def local_sweep_for(policy: str, spec: StencilSpec, *, shard_shape,
     plan_for(shard_shape, dtype, spec, policy, bm=bm, device=device)
     return masked_block(lambda ext: p.fn(ext, spec, bm=bm,
                                          interpret=interpret, device=device))
+
+
+def _bulk_launch_shape(sched: SweepSchedule, shard_shape) -> tuple:
+    """The block the launch that sweeps most of a shard sees: the extended
+    shard, or under overlap the raw shard of the interior launch. The
+    ``strip_rows`` and ``recompute`` of ``dist.run`` describe its plan; the
+    four rind launches of an overlapped round plan their own narrow
+    windows."""
+    d = sched.halo_depth
+    raw = (shard_shape[0] - 2 * d, shard_shape[1] - 2 * d)
+    if sched.overlap and overlap_feasible(*raw, d):
+        return raw
+    return shard_shape
 
 
 def plan_distributed(shape, dtype, spec: StencilSpec | None = None, *,
@@ -209,7 +222,11 @@ def run_distributed(u: jax.Array, spec: StencilSpec | None = None, *,
             sp.set(policy=sched.policy, t=sched.t, overlap=sched.overlap,
                    exchanges=sched.exchanges, model_s=(
                        bill.overlapped_s if sched.overlap
-                       else bill.serial_s), **bill.as_attrs())
+                       else bill.serial_s), **bill.as_attrs(),
+                   **kernel_attrs(sched, _bulk_launch_shape(sched,
+                                                            shard_shape),
+                                  u.dtype, spec, bm, device,
+                                  masked=sched.fused))
         block = local_sweep_for(sched.policy, spec, shard_shape=shard_shape,
                                 dtype=u.dtype, iters=iters, t=sched.t,
                                 bm=bm, interpret=interpret, device=device,

@@ -31,6 +31,13 @@ from repro.obs import metrics as _metrics
 DEFAULT_BM = 256   # largest interior-row block bm=None plans
 DEFAULT_T = 8      # temporal fusion depth (sweeps per HBM round-trip)
 
+#: A temporal window of at most this many f32 vregs (8x128 tiles) sweeps
+#: as one value, the register file's size: larger windows sweep strip by
+#: strip from VMEM scratch, where a carried value would spill. Measured on
+#: a TPU v5e: a 288x24 window takes 0.024 ms a launch whole and 0.094 ms
+#: in strips; a 24x9232 one (219 vregs) 0.023 whole and 0.019 in strips.
+WHOLE_WINDOW_VREGS = 64
+
 
 class PlanError(ValueError):
     """A (shape, dtype, spec, policy, device) combination that cannot be
@@ -137,12 +144,36 @@ class ExecutionPlan:
     def dtype_bytes(self) -> int:
         return jnp.dtype(self.dtype).itemsize
 
+    @property
+    def kernel_rows(self) -> int:
+        """Window rows the kernel holds per block (see
+        :func:`_kernel_rows`)."""
+        return _kernel_rows(self.policy, self.shape[0], self.dtype, self.bm,
+                            self.halo_rows, self.nblocks == 1)
+
+    @property
+    def strip_rows(self) -> int:
+        """Rows one strip of a temporal sweep computes at a time (see
+        :func:`_strip_rows`); 0 where the kernel has no strip loop."""
+        return _strip_rows(self.policy, self.kernel_rows, self.shape[1])
+
+    @property
+    def recompute(self) -> float:
+        """Rows a sweep computes per interior row it keeps: the temporal
+        kernel sweeps its whole window, halo included; the others compute
+        only their block."""
+        if self.policy != "temporal":
+            return 1.0
+        return self.kernel_rows / self.bm
+
     def describe(self) -> str:
+        strip = (f"strip={self.strip_rows} recompute={self.recompute:.3f} "
+                 if self.policy == "temporal" else "")
         return (f"{self.policy}: grid={self.shape} dtype={self.dtype} "
                 f"taps={self.spec.taps} r={self.radius} bm={self.bm} "
                 f"t={self.t} window={self.window_rows}x{self.shape[1]} "
                 f"vmem={self.vmem_bytes / 1024:.0f}KiB blocks={self.nblocks} "
-                f"device={self.device.name}")
+                f"{strip}device={self.device.name}")
 
 
 def _row_align(policy: str, r: int, t: int, dtype) -> int:
@@ -169,6 +200,28 @@ def _halo_rows(policy: str, r: int, t: int, dtype,
     return (hb, hb) if policy == "temporal" else (0, hb)
 
 
+def _kernel_rows(policy: str, h: int, dtype, bm: int, halo: tuple[int, int],
+                 single: bool) -> int:
+    """Window rows a kernel holds per block: its halo and main blocks, or
+    the whole grid for a single block, which the temporal kernel rounds
+    up to the sublane tile like every other block."""
+    if not single:
+        return halo[0] + bm + halo[1]
+    return _round_up(h, sublane_tile(dtype)) if policy == "temporal" else h
+
+
+def _strip_rows(policy: str, kwin: int, w: int) -> int:
+    """Rows one strip of a temporal sweep computes: the sublane tile of
+    the f32 scratch the sweeps run in; 0 where the window is at most
+    :data:`WHOLE_WINDOW_VREGS` and sweeps as one value, and for the
+    single-sweep policies."""
+    tile = sublane_tile(jnp.float32)
+    vregs = -(-kwin // tile) * -(-w // 128)
+    if policy != "temporal" or vregs <= WHOLE_WINDOW_VREGS:
+        return 0
+    return tile
+
+
 def _window_and_vmem(policy: str, shape, dtype, spec: StencilSpec,
                      bm: int, t: int, masked: bool = False) -> tuple[int, int]:
     """Stencil window height and the kernel's fast-memory footprint.
@@ -176,7 +229,7 @@ def _window_and_vmem(policy: str, shape, dtype, spec: StencilSpec,
     The footprint counts what the Pallas kernel holds per grid step: the
     streamed blocks times their buffering depth (the Pallas pipeline
     double-buffers a block unless the policy asks for one buffer), plus
-    the f32 working copies the tap arithmetic runs on.
+    the f32 working copies or scratch the tap arithmetic runs on.
     """
     h, w = shape
     r = spec.radius
@@ -184,8 +237,8 @@ def _window_and_vmem(policy: str, shape, dtype, spec: StencilSpec,
     db = jnp.dtype(dtype).itemsize
     if policy not in ("shifted", "rowchunk", "dbuf", "temporal"):
         raise PlanError(f"unknown policy {policy!r}")
-    top, bot = _halo_rows(policy, r, t, dtype, bm == hi)
-    kwin = h if bm == hi else top + bm + bot     # kernel window rows
+    halo = _halo_rows(policy, r, t, dtype, bm == hi)
+    kwin = _kernel_rows(policy, h, dtype, bm, halo, bm == hi)
     out = 2 * bm * wi * db                       # double-buffered output
     if policy == "shifted":
         # One streamed (bm, wi) block per tap plus the output block; the
@@ -198,12 +251,20 @@ def _window_and_vmem(policy: str, shape, dtype, spec: StencilSpec,
         bufs = 1 if policy == "rowchunk" else 2
         f32 = kwin * w * 4 + 2 * bm * wi * 4     # window + accumulator
         return min(bm + 2 * r, h), bufs * kwin * w * db + out + f32
-    # temporal: grid (and pin-mask) windows double-buffered; the t sweeps
-    # run on f32 copies of the window (the carried iterate, the pinned
-    # originals, a rolled tap and the accumulator).
+    # temporal: grid (and pin-mask) windows double-buffered, the output
+    # block, and the f32 copies the t sweeps run on: in strips, a scratch
+    # of two ping-pong windows, plus the pin mask's with a mask (an even
+    # number of lane tiles: a tile of each column parity); whole, the
+    # carried window, the pinned originals, a rolled tap and the sum.
+    # Every VMEM row holds whole lane tiles.
+    lw, lwi = _round_up(w, 128), _round_up(wi, 128)
     streams = 2 if masked else 1
-    f32 = 4 * kwin * w * 4
-    return min(bm + 2 * t * r, h), 2 * streams * kwin * w * db + out + f32
+    if _strip_rows(policy, kwin, w):
+        f32 = (streams + 1) * kwin * _round_up(w, 256) * 4
+    else:
+        f32 = 4 * kwin * lw * 4
+    return (min(bm + 2 * t * r, h),
+            2 * streams * kwin * lw * db + 2 * bm * lwi * db + f32)
 
 
 @functools.lru_cache(maxsize=1024)

@@ -91,3 +91,13 @@ def test_masked_temporal_compiles_on_a_ragged_shard(one_chip, dtype):
     plan, compiled = _compile(one_chip, SHARD, dtype, "temporal", True)
     assert plan.nblocks * plan.bm > plan.interior_shape[0]  # ragged
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["ring", "masked"])
+def test_temporal_compiles_as_one_block_of_untiled_height(one_chip, masked):
+    """A grid small enough for one block, 26 rows: the temporal kernel
+    streams it as one block rounded up to the sublane tile."""
+    plan, compiled = _compile(one_chip, (26, 300), jnp.float32, "temporal",
+                              masked)
+    assert plan.nblocks == 1 and plan.kernel_rows == 32
+    assert "tpu_custom_call" in compiled.as_text()

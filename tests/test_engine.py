@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import engine
 from repro.core import jacobi as J
@@ -98,13 +99,103 @@ def test_boundary_ring_is_preserved(policy):
         np.testing.assert_array_equal(np.asarray(got[idx]), np.asarray(u[idx]))
 
 
-def test_temporal_deep_fusion_matches_oracle():
-    u = _problem(32, 128, jnp.float32)
-    got = engine.run(u, jacobi_2d_5pt(), policy="temporal", iters=8, t=8,
-                     bm=16, interpret=True)
-    want = _oracle(u, jacobi_2d_5pt(), 8)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
+RADIUS2 = StencilSpec(offsets=((-2, 0), (-1, 0), (0, 0), (0, -2), (0, 1)),
+                     weights=(0.1, 0.3, 0.2, 0.15, 0.25))
+# (interior rows, cols, dtype, spec, t, bm, masked): the temporal
+# kernel's edge cases, at widths whose windows exceed WHOLE_WINDOW_VREGS
+# and so sweep in strips. A single block whose 26 ringed rows are not a
+# whole number of sublane tiles; a ragged last block (``("fit", 16)``:
+# bm=None on a device whose budget holds 16-row blocks and no more, over
+# 46 rows); the masked form a distributed shard runs, ragged too; bf16;
+# a radius-2 spec; one sweep. The ``whole`` cases' windows are small
+# enough to sweep as one value.
+STRIP_CASES = {
+    "deep": (32, 2046, jnp.float32, jacobi_2d_5pt(), 8, 16, False),
+    "single_ragged": (24, 2302, jnp.float32, jacobi_2d_5pt(), 3, None,
+                      False),
+    "ragged_blocks": (46, 2302, jnp.float32, laplace_2d_9pt(), 4,
+                      ("fit", 16), False),
+    "masked_shard": (46, 2046, jnp.float32, jacobi_2d_5pt(), 8, ("fit", 16),
+                     True),
+    "bf16": (48, 2046, jnp.bfloat16, jacobi_2d_5pt(), 8, 16, False),
+    "radius2": (34, 2814, jnp.float32, RADIUS2, 2, 8, False),
+    "t1": (32, 2814, jnp.float32, jacobi_2d_5pt(), 1, 8, False),
+    "whole": (32, 128, jnp.float32, jacobi_2d_5pt(), 8, 16, False),
+    "whole_masked": (46, 140, jnp.float32, jacobi_2d_5pt(), 8, ("fit", 16),
+                     True),
+}
+
+
+def _strip_case(case):
+    """The case's grid, pin mask, ``bm`` request and device."""
+    import dataclasses
+
+    from repro.engine.plan import _window_and_vmem
+    ny, nx, dtype, spec, t, bm, masked = STRIP_CASES[case]
+    u = _problem(ny, nx, dtype)
+    device = None
+    if isinstance(bm, tuple):
+        budget = _window_and_vmem("temporal", u.shape, dtype, spec, bm[1],
+                                  t, masked)[1]
+        device = dataclasses.replace(engine.get_device("cpu_ref"),
+                                     name=f"fits_bm{bm[1]}",
+                                     fast_memory_bytes=budget)
+        bm = None
+    mask = None
+    if masked:
+        r = spec.radius
+        ring = np.ones(u.shape, bool)
+        ring[r:-r, r:-r] = False
+        mask = jnp.asarray(ring)
+    return u, spec, t, bm, device, mask
+
+
+@pytest.mark.parametrize("case", list(STRIP_CASES))
+def test_temporal_deep_fusion_matches_oracle(case):
+    """One fused launch equals ``t`` reference sweeps rounded once, bit
+    for bit; with a mask equal to the grid's own ring, the masked form
+    does too."""
+    from repro.kernels import ref
+    u, spec, t, bm, device, mask = _strip_case(case)
+    plan = engine.plan_for(u.shape, u.dtype, spec, "temporal", t=t, bm=bm,
+                           device=device, masked=mask is not None)
+    assert plan.strip_rows == (0 if case.startswith("whole") else 8)
+    got = engine.stencil_temporal(u, spec, t=t, bm=bm, interpret=True,
+                                  device=device, mask=mask)
+    want = ref.sweeps(u, t, spec, fuse=t)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+# Mosaic leaves scratch rows and lanes past the window, and block rows
+# past the grid, holding whatever VMEM held, which is not what the
+# interpreter's zeros are. Pallas's TPU interpret mode fills uninitialized
+# memory with NaN, and lets reads past an array return it; it can fuse a
+# multiply into an add differently, so it is exact only for dyadic
+# weights, as these cases have.
+NAN_MEMORY = pltpu.InterpretParams(out_of_bounds_reads="uninitialized",
+                                   uninitialized_memory="nan")
+
+
+@pytest.mark.parametrize("case", ["deep", "single_ragged", "masked_shard",
+                                  "bf16", "t1"])
+def test_temporal_strips_ignore_uninitialized_memory(case):
+    """No kept cell of the strip kernel reads memory the kernel did not
+    write: with NaN in every uninitialized scratch and out-of-grid row,
+    the answer still equals the oracle bit for bit. The masked case pins
+    only the ring's columns, as a middle shard of a row mesh does, so its
+    first and last rows evolve like exchanged halo rows; they go stale
+    one row per sweep and only the rows ``t`` deep and more are kept."""
+    from repro.kernels import ref
+    u, spec, t, bm, device, mask = _strip_case(case)
+    if mask is not None:
+        mask = jnp.asarray(mask).at[:, spec.radius:-spec.radius].set(False)
+    got = engine.stencil_temporal(u, spec, t=t, bm=bm, interpret=NAN_MEMORY,
+                                  device=device, mask=mask)
+    want = ref.sweeps(u, t, spec, fuse=t)
+    keep = slice(t, u.shape[0] - t) if mask is not None else slice(None)
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[keep],
+                                  np.asarray(want, np.float32)[keep])
 
 
 def test_temporal_mask_defaults_to_ring_mask():
@@ -195,6 +286,90 @@ def test_plan_values():
     plan2 = engine.plan_for((34, 130), jnp.float32, jacobi_2d_5pt(),
                             "rowchunk", bm=15)
     assert 32 % plan2.bm == 0 and plan2.bm <= 15
+
+
+def test_temporal_footprint_is_the_strip_kernels_working_set():
+    """The planner prices what the strip-mined kernel holds: the streamed
+    window (and pin mask) double-buffered, the output block, and two f32
+    ping-pong copies of the window (three with a mask), in whole lane
+    tiles, an even number of them in the scratch. At the paper's grid on
+    a v5e that keeps f32 at bm=32 (bm=64 would need about 19 MB) and the
+    masked form at bm=16."""
+    from repro.engine.plan import _window_and_vmem
+    spec, shape = jacobi_2d_5pt(), (1026, 9218)
+    win = 16 + 32 + 16                    # halo, main, halo
+    stream, out = 2 * win * 9344 * 4, 2 * 32 * 9216 * 4
+    scratch = win * 9472 * 4              # 74 lane tiles
+    assert _window_and_vmem("temporal", shape, jnp.float32, spec, 32, 8) \
+        == (48, stream + out + 2 * scratch)
+    assert _window_and_vmem("temporal", shape, jnp.float32, spec, 32, 8,
+                            masked=True) == (48, 2 * stream + out + 3 * scratch)
+    plan = engine.plan_for(shape, jnp.float32, spec, "temporal", t=8,
+                           device="tpu_v5e")
+    assert (plan.bm, plan.kernel_rows, plan.strip_rows) == (32, 64, 8)
+    assert plan.recompute == 2.0
+    assert "strip=8 recompute=2.000" in plan.describe()
+    masked = engine.plan_for(shape, jnp.float32, spec, "temporal", t=8,
+                             device="tpu_v5e", masked=True)
+    assert (masked.bm, masked.recompute) == (16, 3.0)
+
+
+def _loop_carries(jaxpr):
+    """Avals carried by every while/scan loop in ``jaxpr`` and the jaxprs
+    nested in its equations (a Pallas kernel's among them)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            n = eqn.params["body_nconsts"]
+            yield from eqn.params["body_jaxpr"].in_avals[n:]
+        elif eqn.primitive.name == "scan":
+            n, k = eqn.params["num_consts"], eqn.params["num_carry"]
+            yield from eqn.params["jaxpr"].in_avals[n:n + k]
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _loop_carries(sub)
+
+
+def _kernel_jaxpr(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn.params["jaxpr"]
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if isinstance(sub, jax.extend.core.Jaxpr):
+                found = _kernel_jaxpr(sub)
+                if found is not None:
+                    return found
+    return None
+
+
+@pytest.mark.parametrize("shape", [(1026, 9218), (272, 9232)],
+                         ids=["paper", "shard"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("masked", [False, True], ids=["ring", "masked"])
+def test_temporal_sweep_loops_carry_no_window(shape, dtype, masked):
+    """Spill guard: no loop in the temporal kernel carries more than one
+    strip. A sweep loop that carries the whole window as one value (584
+    vregs at the paper's width) makes Mosaic spill nearly all of it every
+    sweep; the strip-mined kernel keeps its window in VMEM scratch and
+    its loops carry indices."""
+    spec = jacobi_2d_5pt()
+    plan = engine.plan_for(shape, dtype, spec, "temporal", t=8,
+                           device="tpu_v5e", masked=masked)
+    x = jax.ShapeDtypeStruct(shape, dtype)
+    m = jax.ShapeDtypeStruct(shape, jnp.bool_)
+    outer = jax.make_jaxpr(lambda u, mk: engine.stencil_temporal(
+        u, spec, t=8, device="tpu_v5e", interpret=False,
+        mask=mk if masked else None))(x, m)
+    kernel = _kernel_jaxpr(outer.jaxpr)
+    assert kernel is not None
+    carries = list(_loop_carries(kernel))
+    assert carries, "the sweeps should run as loops"
+    strip = plan.strip_rows * shape[1]
+    for aval in carries:
+        assert int(np.prod(aval.shape)) <= strip, aval
 
 
 def test_plan_validation_errors():

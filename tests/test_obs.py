@@ -71,6 +71,10 @@ def test_engine_run_bit_identical_obs_on_vs_off():
     (run_ev,) = [e for e in tracer.events if e.name == "engine.run"]
     assert run_ev.attrs["policy"] == "temporal"
     assert run_ev.attrs["t"] == 4
+    # The kernel's form: a window this small sweeps as one value (no
+    # strips), one block of 24 tile-rounded rows for the 18 it keeps.
+    assert run_ev.attrs["strip_rows"] == 0
+    assert run_ev.attrs["recompute"] == round(24 / 18, 4)
     # build_schedule nests under engine.run in the span tree.
     (sched_ev,) = [e for e in tracer.events
                    if e.name == "engine.build_schedule"]
@@ -405,6 +409,7 @@ for overlap in (False, True):
     (run,) = [e for e in tracer.events if e.name == "dist.run"]
     assert run.attrs["overlap"] is overlap
     assert run.attrs["exchanges"] == 3      # 2 fused rounds + remainder
+    assert run.attrs["strip_rows"] == 0 and run.attrs["recompute"] >= 1
     assert run.attrs["model_s"] > 0 and run.attrs["halo_bytes"] > 0
     assert run.attrs["model_s"] == (run.attrs["model_overlapped_s"]
                                     if overlap else

@@ -168,6 +168,31 @@ def test_server_warm_never_remeasures():
     assert req.key.policy == won[(18, 18)]
 
 
+def test_run_batched_strip_kernel_matches_reference():
+    """The vmapped launch the served path runs, at a width whose windows
+    sweep in strips (the batch becomes a grid axis of the kernel and its
+    scratch is reused lane after lane), over two 16-row blocks: each lane
+    equals the oracle bit for bit, with uninitialized memory read as NaN
+    (Pallas's TPU interpret mode)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import ref
+    spec = jacobi_2d_5pt()
+    us = jnp.stack([_problem(32, 2300, left=1.0),
+                    _problem(32, 2300, left=-2.0)])
+    us = us.at[:, 1:-1, 1:-1].set(jax.random.uniform(
+        jax.random.PRNGKey(3), (2, 32, 2300)))
+    plan = engine.plan_for(us.shape[1:], us.dtype, spec, "temporal", t=8,
+                           bm=16)
+    assert plan.strip_rows == 8 and plan.nblocks == 2, plan.describe()
+    got = engine.run_batched(us, spec, policy="temporal", iters=16, t=8,
+                             bm=16, interpret=pltpu.InterpretParams(
+                                 out_of_bounds_reads="uninitialized"))
+    for i in range(us.shape[0]):
+        want = ref.sweeps(us[i], 16, spec, fuse=8)
+        np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want))
+
+
 def test_run_batched_matches_per_lane_run():
     """The vmapped batch primitive is bit-exact per lane vs solo runs."""
     spec = jacobi_2d_5pt()
