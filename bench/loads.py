@@ -35,6 +35,49 @@ def _annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
+def grid_form(config: dict, r: int):
+    """(interior shape, ringed shape, ``ring``) of a configuration, where
+    ``ring(dtype)`` gives a ringed grid whose ring holds the
+    configuration's fixed (Dirichlet) values; its interior is overwritten.
+
+    A configuration takes one of two forms:
+
+    * 2-D: ``ny`` and ``nx`` interior points, and a ``ring`` of constant
+      ``left``, ``right``, ``top`` and ``bottom`` sides (the top and bottom
+      rows hold the corners);
+    * n-D: ``shape``, the interior points per axis (outermost first, the
+      lane axis last), and an affine ``ring`` ``{"const": c, "coef":
+      [c_0, ...]}``: ``c + sum_k c_k p_k`` at ringed index ``p``, computed
+      in float32. It holds PolyBench's initial boundaries, which are affine
+      in the index, and a zero ring.
+    """
+    ring = config["ring"]
+    interior = tuple(config["shape"]) if "shape" in config else (
+        config["ny"], config["nx"])
+    shape = workcount.ringed_shape(interior, r)
+    if "shape" not in config:
+        def ring_2d(dtype):
+            import jax.numpy as jnp
+            u = jnp.zeros(shape, dtype)
+            u = u.at[:, :r].set(ring["left"]).at[:, -r:].set(ring["right"])
+            return u.at[:r, :].set(ring["top"]).at[-r:, :].set(
+                ring["bottom"])
+        return interior, shape, ring_2d
+
+    if len(ring["coef"]) != len(shape):
+        raise ValueError(f"ring coef {ring['coef']} does not give one "
+                         f"coefficient per axis of shape {interior}")
+
+    def ring_affine(dtype):
+        import jax.numpy as jnp
+        from jax import lax
+        v = jnp.full(shape, ring["const"], jnp.float32)
+        for k, c in enumerate(ring["coef"]):
+            v = v + c * lax.broadcasted_iota(jnp.float32, shape, k)
+        return v.astype(dtype)
+    return interior, shape, ring_affine
+
+
 class System:
     """The program's entry points for one configuration."""
 
@@ -51,9 +94,8 @@ class System:
             weights=tuple(float(w) for w in config["weights"]))
         self.r = self.spec.radius
         self.dtype = jnp.dtype(config["dtype"])
-        self.shape = workcount.ringed_shape(config["ny"], config["nx"],
-                                            self.r)
-        self.points = workcount.interior_points(config["ny"], config["nx"])
+        self.interior, self.shape, self._ring = grid_form(config, self.r)
+        self.points = workcount.interior_points(self.interior)
         self.taps = self.spec.taps
         self.devices = devices[:config["chips"]]
         self.mesh = None
@@ -63,25 +105,21 @@ class System:
                                       devices=self.devices)
 
     def pool(self, seed: int, n: int) -> tuple:
-        """``n`` ringed grids: the configuration's fixed ring around an
-        interior uniform in [0, 1), made on the default device in one call
-        and left uncommitted to it, as a caller's grid is: run_distributed
-        refuses a grid committed to one device or replicated over its
-        mesh."""
-        jax, jnp = self.jax, self.jnp
-        ring = self.config["ring"]
-        ny, nx, r, dtype = self.config["ny"], self.config["nx"], self.r, \
-            self.dtype
+        """``n`` ringed grids: the configuration's fixed ring (see
+        :func:`grid_form`) around an interior uniform in [0, 1), made on
+        the default device in one call and left uncommitted to it, as a
+        caller's grid is: run_distributed refuses a grid committed to one
+        device or replicated over its mesh."""
+        jax, jnp, dtype = self.jax, self.jnp, self.dtype
+        inner = (slice(self.r, -self.r),) * len(self.shape)
         words = np.random.SeedSequence(seed).generate_state(2)
 
         def gen(k0, k1):
             key = jax.random.fold_in(jax.random.PRNGKey(k0), k1)
-            u = jnp.zeros(self.shape, dtype)
-            u = u.at[:, :r].set(ring["left"]).at[:, -r:].set(ring["right"])
-            u = u.at[:r, :].set(ring["top"]).at[-r:, :].set(ring["bottom"])
+            u = self._ring(dtype)
             return tuple(
-                u.at[r:-r, r:-r].set(jax.random.uniform(
-                    k, (ny, nx), jnp.float32).astype(dtype))
+                u.at[inner].set(jax.random.uniform(
+                    k, self.interior, jnp.float32).astype(dtype))
                 for k in jax.random.split(key, n))
 
         return jax.jit(gen)(

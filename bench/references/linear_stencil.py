@@ -1,7 +1,8 @@
 """Plain reference for a linear stencil on a ringed grid.
 
 ``out[p] = sum_k w[k] * u[p + off[k]]`` over the interior, the ring of
-width ``radius`` held fixed (Dirichlet). Written in straightforward
+width ``radius`` held fixed (Dirichlet), on a grid of any number of axes
+(one offset component per axis). Written in straightforward
 ``jax.numpy`` from the configuration's own offsets and weights: it imports
 nothing of the program under test and takes nothing the program made.
 Taps accumulate in ``compute`` precision in the configuration's tap order.
@@ -16,7 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-Taps = tuple[tuple[tuple[int, int], ...], tuple[float, ...]]
+Taps = tuple[tuple[tuple[int, ...], ...], tuple[float, ...]]
 
 
 def taps_of(config: dict) -> Taps:
@@ -28,23 +29,26 @@ def radius(taps: Taps) -> int:
     return max(abs(c) for off in taps[0] for c in off)
 
 
+def _interior(shape: tuple[int, ...], r: int, off=None) -> tuple:
+    """One slice per axis: the interior, shifted by ``off``."""
+    off = off or (0,) * len(shape)
+    return tuple(slice(r + d, n - r + d) for n, d in zip(shape, off))
+
+
 def sweep(u: jax.Array, taps: Taps) -> jax.Array:
     """One sweep in ``u.dtype`` arithmetic."""
     r = radius(taps)
-    h, w = u.shape
     acc = None
-    for (dy, dx), wt in zip(*taps):
-        term = u[r + dy:h - r + dy, r + dx:w - r + dx] * jnp.asarray(
-            wt, u.dtype)
+    for off, wt in zip(*taps):
+        term = u[_interior(u.shape, r, off)] * jnp.asarray(wt, u.dtype)
         acc = term if acc is None else acc + term
-    return u.at[r:h - r, r:w - r].set(acc)
+    return u.at[_interior(u.shape, r)].set(acc)
 
 
 def _residual(u: jax.Array, taps: Taps) -> jax.Array:
-    r = radius(taps)
-    h, w = u.shape
-    d = sweep(u, taps)[r:h - r, r:w - r].astype(jnp.float32) - u[
-        r:h - r, r:w - r].astype(jnp.float32)
+    inner = _interior(u.shape, radius(taps))
+    d = sweep(u, taps)[inner].astype(jnp.float32) - u[inner].astype(
+        jnp.float32)
     return jnp.max(jnp.abs(d))
 
 

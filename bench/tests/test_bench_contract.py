@@ -5,17 +5,19 @@ import os
 import re
 import sys
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from bench import harness  # noqa: E402
+from bench import harness, loads, workcount  # noqa: E402
 
 BENCH = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 CELLS = [w["name"] for w in BENCH["workloads"]]
+CELLS_2D = [c for c in CELLS if "shape" not in harness.load_cell(c).config]
 
 
 def test_names_are_unique_and_well_formed():
@@ -25,20 +27,50 @@ def test_names_are_unique_and_well_formed():
         assert all(NAME.match(n) for n in names), names
 
 
+def _has_a_grid_form(config):
+    """Either the 2-D form (``ny``, ``nx``, a ring of four sides) or the
+    n-D one (``shape``, an affine ring with one coefficient per axis)."""
+    ring = config["ring"]
+    if "shape" in config:
+        ndim = len(config["shape"])
+        return (set(ring) == {"const", "coef"} and len(ring["coef"]) == ndim
+                and all(len(o) == ndim for o in config["offsets"]))
+    return ("ny" in config and "nx" in config
+            and set(ring) == {"left", "right", "top", "bottom"}
+            and all(len(o) == 2 for o in config["offsets"]))
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_files_and_metrics(cell):
     c = harness.load_cell(cell)
     assert c.traffic["kind"] in ("closed_fixed", "closed_tol", "open_serve")
     assert c.config["chips"] == c.chips
-    for key in ("ny", "nx", "offsets", "weights", "dtype", "limits",
+    for key in ("offsets", "weights", "ring", "dtype", "limits",
                 "reference", "control_dtype"):
         assert key in c.config, key
+    assert _has_a_grid_form(c.config)
     e2e = {m["name"] for m in c.end_to_end}
     assert "setup_s" in e2e and len(e2e) >= 2
     assert c.per_layer
     for m in c.per_layer:
         assert m["moves"] in e2e, (m["name"], m["moves"])
         assert callable(harness.metric_reader(m["name"]))
+
+
+@pytest.mark.parametrize("cell", CELLS_2D)
+def test_existing_configs_keep_their_2d_work_counts(cell):
+    """The n-D form of the work counts gives every 2-D configuration the
+    integers the 2-D formulas gave, so each roofline share keeps its
+    yardstick."""
+    cfg = harness.load_cell(cell).config
+    ny, nx = cfg["ny"], cfg["nx"]
+    r = max(abs(c) for o in cfg["offsets"] for c in o)
+    interior, ringed, _ = loads.grid_form(cfg, r)
+    assert ringed == (ny + 2 * r, nx + 2 * r)
+    assert workcount.interior_points(interior) == ny * nx
+    size = np.dtype(cfg["dtype"]).itemsize
+    assert workcount.compulsory_bytes(ringed, size, 3) == (
+        2 * (ny + 2 * r) * (nx + 2 * r) * size * 3)
 
 
 def test_at_most_half_of_the_cells_take_four_chips():

@@ -74,12 +74,18 @@ def test_idle_gaps_go_to_the_innermost_covering_annotation():
 
 def test_work_counts_hand_worked():
     # The paper's grid: 1024 x 9216 interior, radius 1, float32.
-    pts = wc.interior_points(1024, 9216)
+    pts = wc.interior_points((1024, 9216))
     assert pts == 9_437_184
-    assert wc.ringed_shape(1024, 9216, 1) == (1026, 9218)
+    assert wc.ringed_shape((1024, 9216), 1) == (1026, 9218)
     assert wc.sweep_ops(pts, 5000, 4) == 188_743_680_000
     assert wc.compulsory_bytes((1026, 9218), 4, 1) == 2 * 1026 * 9218 * 4
     assert wc.compulsory_bytes((1026, 9218), 4, 3) == 3 * 75_661_344
+    # PolyBench heat-3d at EXTRALARGE: a 198^3 interior, 7 taps, float32.
+    pts = wc.interior_points((198, 198, 198))
+    assert pts == 7_762_392
+    assert wc.ringed_shape((198, 198, 198), 1) == (200, 200, 200)
+    assert wc.sweep_ops(pts, 2000, 7) == 108_673_488_000
+    assert wc.compulsory_bytes((200, 200, 200), 4, 1) == 64_000_000
 
 
 def test_least_time_takes_the_larger_bound_over_all_chips():

@@ -17,13 +17,17 @@ fused deeper or replaced is measured against the same floor.
 """
 from __future__ import annotations
 
-
-def interior_points(ny: int, nx: int) -> int:
-    return ny * nx
+import math
 
 
-def ringed_shape(ny: int, nx: int, radius: int) -> tuple[int, int]:
-    return ny + 2 * radius, nx + 2 * radius
+def interior_points(interior: tuple[int, ...]) -> int:
+    return math.prod(interior)
+
+
+def ringed_shape(interior: tuple[int, ...], radius: int) -> tuple[int, ...]:
+    """The interior with a ring of ``radius`` points on both sides of every
+    axis."""
+    return tuple(n + 2 * radius for n in interior)
 
 
 def sweep_ops(points: int, sweeps: int, taps: int) -> int:
@@ -31,10 +35,10 @@ def sweep_ops(points: int, sweeps: int, taps: int) -> int:
     return points * sweeps * taps
 
 
-def compulsory_bytes(ringed: tuple[int, int], dtype_bytes: int,
+def compulsory_bytes(ringed: tuple[int, ...], dtype_bytes: int,
                      solves: int) -> int:
     """Read the ringed grid once and write it once, per solve."""
-    return 2 * ringed[0] * ringed[1] * dtype_bytes * solves
+    return 2 * math.prod(ringed) * dtype_bytes * solves
 
 
 def least_time_s(ops: float, nbytes: float, *, vector_ops_per_s: float,
