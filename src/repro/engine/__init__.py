@@ -7,6 +7,9 @@ under any of the paper's execution policies —
     ``shifted``  (§IV initial)  ·  ``rowchunk`` (§VI optimized)
     ``dbuf``     (Table I double buffering)  ·  ``temporal`` (beyond paper)
 
+A 3-D spec on a (planes, rows, lanes) grid runs the temporal kernel's
+3-D form (PolyBench heat-3d's shape) on one device; ``auto`` picks it.
+
 Typical use::
 
     from repro import engine

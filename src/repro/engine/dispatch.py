@@ -5,7 +5,8 @@ benchmark tables to enumerate variants (name, paper provenance, modeled
 bytes/point) — no caller keeps a hand-written kernel list. ``run`` is the
 public entry point: pick a policy (``"auto"`` consults the device-aware
 heuristic, ``"tuned"`` the measured cache in :mod:`repro.engine.tune`),
-advance any 2-D ``StencilSpec`` any number of sweeps on any registered
+advance any 2-D ``StencilSpec`` (or a 3-D one, under the temporal kernel)
+any number of sweeps on any registered
 :class:`~repro.engine.device.DeviceModel`.
 """
 from __future__ import annotations
@@ -20,7 +21,7 @@ import jax.numpy as jnp
 from repro.core.stencil import StencilSpec, jacobi_2d_5pt
 from repro.engine import policies as P
 from repro.engine.device import DeviceModel, get_device
-from repro.engine.plan import DEFAULT_T, PlanError, plan_for
+from repro.engine.plan import DEFAULT_T, PlanError, default_depth, plan_for
 from repro.engine.schedule import DEFAULT_REMAINDER_POLICY  # noqa: F401
 from repro.engine.schedule import build_schedule
 from repro.obs import metrics as _metrics
@@ -131,8 +132,15 @@ def resolve_auto(shape, dtype, spec: StencilSpec, *, iters: int = 1,
     memory — the form the distributed executor will actually launch.
     Every candidate is planned before it is returned, so the answer is
     always a policy whose plan validates; when none does, ``PlanError``
-    lists why each was refused.
+    lists why each was refused. A 3-D grid has one candidate, the
+    temporal kernel (even at ``t=1``): the other policies are 2-D.
     """
+    if spec.ndim == 3:
+        t_eff = t if t is not None else default_depth(shape, dtype, spec,
+                                                      device)
+        plan_for(shape, dtype, spec, "temporal", t=min(t_eff, max(iters, 1)),
+                 device=device, masked=masked)
+        return "temporal"
     t_eff = t if t is not None else min(DEFAULT_T, max(iters, 1))
     if iters >= 2 and t_eff >= 2:
         try:
@@ -180,10 +188,11 @@ def step(u: jax.Array, spec: StencilSpec | None = None, *,
         interpret = not _on_tpu()
     device = _resolve_device_name(device)
     if policy in ("auto", "tuned"):
-        # A single step must advance exactly one sweep, so auto/tuned never
-        # pick a fused policy here (run() with iters does).
-        policy = resolve_auto(u.shape, u.dtype, spec, iters=1, t=1,
-                              device=device)
+        # A single step must advance exactly one sweep, so auto/tuned
+        # pick a fused policy here only on a 3-D grid, at t=1 (run() with
+        # iters fuses).
+        policy, t = resolve_auto(u.shape, u.dtype, spec, iters=1, t=1,
+                                 device=device), 1
     p = get_policy(policy)
     if p.fused:
         return p.fn(u, spec, bm=bm, t=t, interpret=interpret, device=device)
@@ -221,14 +230,18 @@ def residual_for(spec: StencilSpec | None = None) -> Callable:
 def kernel_attrs(sched, shape, dtype, spec: StencilSpec, bm,
                  device, masked: bool = False) -> dict:
     """The launched kernel's form as span attributes: its strip height
-    and the rows it sweeps per row it keeps; none for the pure-jnp
-    reference, which has no plan."""
+    and the rows it sweeps per row it keeps, and on a 3-D grid its
+    planes per block and halo planes (``recompute`` there counts planes
+    and rows); none for the pure-jnp reference, which has no plan."""
     if sched.policy == "reference":
         return {}
     plan = plan_for(shape, dtype, spec, sched.policy, bm=bm, t=sched.t,
                     device=device, masked=masked)
-    return {"strip_rows": plan.strip_rows,
-            "recompute": round(plan.recompute, 4)}
+    attrs = {"strip_rows": plan.strip_rows,
+             "recompute": round(plan.recompute, 4)}
+    if plan.ndim == 3:
+        attrs.update(block_planes=plan.bm, halo_planes=plan.halo_planes)
+    return attrs
 
 
 def _is_traced(u) -> bool:
@@ -271,6 +284,9 @@ def _execute_schedule(u: jax.Array, sched, spec: StencilSpec, bm,
                         sched.fused_blocks)
         if sched.remainder:
             rp = get_policy(sched.remainder_policy)
+            if rp.fused:  # a 3-D grid: the kernel at the remainder's depth
+                return rp.fn(u, spec=spec, bm=bm, t=sched.remainder,
+                             interpret=interpret, device=device)
             u = _scan_steps(u, functools.partial(
                 rp.fn, spec=spec, bm=bm, interpret=interpret,
                 device=device), sched.remainder)
@@ -396,7 +412,8 @@ def run_converged(u: jax.Array, spec: StencilSpec | None = None, *,
     import jax.numpy as jnp
     with _obs_span("engine.run_converged", max_iters=max_iters, tol=tol,
                    shape=tuple(u.shape), requested_policy=policy) as sp:
-        cadence = effective_depth(max_iters, t)
+        cadence = effective_depth(max_iters, t, default_depth(
+            u.shape, u.dtype, spec, device))
         sched = build_schedule(cadence, spec=spec, shape=u.shape,
                                dtype=u.dtype, policy=policy, t=cadence,
                                bm=bm, interpret=interpret, device=device,

@@ -37,7 +37,7 @@ from repro.core.stencil import StencilSpec, apply_stencil, jacobi_2d_5pt
 from repro.engine.device import DeviceModel
 from repro.engine.dispatch import (_on_tpu, _resolve_device_name, get_policy,
                                    kernel_attrs, resolve_auto)
-from repro.engine.plan import plan_for
+from repro.engine.plan import PlanError, plan_for
 from repro.engine.schedule import (DEFAULT_REMAINDER_POLICY, SweepSchedule,
                                    build_schedule, effective_depth,
                                    overlap_feasible, price_exchange)
@@ -142,6 +142,10 @@ def plan_distributed(shape, dtype, spec: StencilSpec | None = None, *,
     see the serial-vs-overlapped exchange bill the choice was made from.
     """
     spec = spec if spec is not None else jacobi_2d_5pt()
+    if spec.ndim != 2 or len(shape) != 2:
+        raise PlanError(f"the mesh path is 2-D; got a {len(shape)}-D grid "
+                        f"{tuple(shape)} and a {spec.ndim}-D spec (run a 3-D "
+                        f"grid on one device with engine.run)")
     if interpret is None:
         interpret = not _on_tpu()
     from repro.dist import stencil as dstencil

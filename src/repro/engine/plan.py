@@ -101,10 +101,15 @@ class ExecutionPlan:
     (bm + halo); ``t`` is the number of sweeps fused per HBM round-trip
     (1 unless the policy is temporal); ``device`` is the model whose budget
     validated the plan.
+
+    A 3-D plan (ringed shape (planes, rows, lanes), temporal only) blocks
+    the plane axis alone: ``bm`` is the interior planes a grid step
+    produces, ``window_rows`` the planes its window holds (``bm`` plus
+    :attr:`halo_planes`), and every plane is streamed whole.
     """
 
     policy: str
-    shape: tuple[int, int]
+    shape: tuple[int, ...]
     dtype: str
     spec: StencilSpec
     bm: int
@@ -123,9 +128,13 @@ class ExecutionPlan:
         return self.spec.radius
 
     @property
-    def interior_shape(self) -> tuple[int, int]:
+    def ndim(self) -> int:
+        return len(self.shape)
+
+    @property
+    def interior_shape(self) -> tuple[int, ...]:
         r = self.spec.radius
-        return (self.shape[0] - 2 * r, self.shape[1] - 2 * r)
+        return tuple(s - 2 * r for s in self.shape)
 
     @property
     def nblocks(self) -> int:
@@ -136,9 +145,20 @@ class ExecutionPlan:
     @property
     def halo_rows(self) -> tuple[int, int]:
         """Tile-aligned rows the kernel streams above and below each
-        ``bm``-row main block (``(0, 0)`` for one whole-grid block)."""
+        ``bm``-row main block (``(0, 0)`` for one whole-grid block, and
+        for a 3-D plan, which streams every plane whole)."""
+        if self.ndim == 3:
+            return (0, 0)
         return _halo_rows(self.policy, self.radius, self.t, self.dtype,
                           self.nblocks == 1)
+
+    @property
+    def halo_planes(self) -> tuple[int, int]:
+        """3-D only: planes the kernel streams before and after each
+        ``bm``-plane main block; ``(0, 0)`` for a 2-D plan."""
+        if self.ndim != 3:
+            return (0, 0)
+        return _halo_planes(self.radius, self.t)
 
     @property
     def dtype_bytes(self) -> int:
@@ -147,26 +167,47 @@ class ExecutionPlan:
     @property
     def kernel_rows(self) -> int:
         """Window rows the kernel holds per block (see
-        :func:`_kernel_rows`)."""
+        :func:`_kernel_rows`); a 3-D plan's rows per plane."""
+        if self.ndim == 3:
+            return _round_up(self.shape[1], sublane_tile(jnp.float32))
         return _kernel_rows(self.policy, self.shape[0], self.dtype, self.bm,
                             self.halo_rows, self.nblocks == 1)
 
     @property
     def strip_rows(self) -> int:
         """Rows one strip of a temporal sweep computes at a time (see
-        :func:`_strip_rows`); 0 where the kernel has no strip loop."""
+        :func:`_strip_rows`); 0 where the kernel has no strip loop. A 3-D
+        plan always sweeps in strips."""
+        if self.ndim == 3:
+            return sublane_tile(jnp.float32)
         return _strip_rows(self.policy, self.kernel_rows, self.shape[1])
 
     @property
     def recompute(self) -> float:
         """Rows a sweep computes per interior row it keeps: the temporal
         kernel sweeps its whole window, halo included; the others compute
-        only their block."""
+        only their block. A 3-D sweep ``s`` of ``t`` skips the ``s * r``
+        planes at each end of the window that are already stale, so on
+        average ``bm + (t - 1) * r`` planes are swept per ``bm`` kept, each
+        over ``kernel_rows`` rows per interior row kept."""
         if self.policy != "temporal":
             return 1.0
+        if self.ndim == 3:
+            planes = (self.bm + (self.t - 1) * self.radius) / self.bm
+            return planes * self.kernel_rows / self.interior_shape[1]
         return self.kernel_rows / self.bm
 
     def describe(self) -> str:
+        if self.ndim == 3:
+            return (f"{self.policy}: grid={self.shape} dtype={self.dtype} "
+                    f"taps={self.spec.taps} r={self.radius} "
+                    f"planes={self.bm} halo_planes={self.halo_planes} "
+                    f"t={self.t} window={self.window_rows}x"
+                    f"{self.kernel_rows}x{self.shape[2]} "
+                    f"vmem={self.vmem_bytes / 1024:.0f}KiB "
+                    f"blocks={self.nblocks} strip={self.strip_rows} "
+                    f"recompute={self.recompute:.3f} "
+                    f"device={self.device.name}")
         strip = (f"strip={self.strip_rows} recompute={self.recompute:.3f} "
                  if self.policy == "temporal" else "")
         return (f"{self.policy}: grid={self.shape} dtype={self.dtype} "
@@ -267,22 +308,81 @@ def _window_and_vmem(policy: str, shape, dtype, spec: StencilSpec,
             2 * streams * kwin * lw * db + 2 * bm * lwi * db + f32)
 
 
+def _halo_planes(r: int, t: int) -> tuple[int, int]:
+    """Planes a 3-D temporal window holds before and after its main block:
+    the output planes sit ``r`` past the main block's first, and ``t``
+    sweeps need ``t * r`` valid planes past them on each side."""
+    return ((t - 1) * r, (t + 1) * r)
+
+
+def _vmem_3d(shape, dtype, bp: int, t: int, r: int) -> int:
+    """Fast-memory footprint of the 3-D temporal kernel for ``bp``-plane
+    blocks: the window's planes streamed in (double-buffered), the output
+    block (double-buffered), and two f32 ping-pong scratch windows of
+    whole lane-tile pairs. Each plane takes whole sublane and lane tiles."""
+    _, h, w = shape
+    hi, wi = h - 2 * r, w - 2 * r
+    tile = sublane_tile(dtype)
+    db = jnp.dtype(dtype).itemsize
+    kp = bp + sum(_halo_planes(r, t))
+    plane_in = _round_up(h, tile) * _round_up(w, 128) * db
+    plane_out = _round_up(hi, tile) * _round_up(wi, 128) * db
+    plane_f32 = _round_up(h, sublane_tile(jnp.float32)) * _round_up(w, 256) * 4
+    return 2 * kp * plane_in + 2 * bp * plane_out + 2 * kp * plane_f32
+
+
+def _plan_3d(shape, dtype: str, spec: StencilSpec, policy: str,
+             bm_req: int | None, t: int, device: DeviceModel,
+             masked: bool) -> ExecutionPlan:
+    """A 3-D temporal plan: the deepest plane block up to the interior's
+    (or the ``bm`` request) whose footprint fits; the last may be
+    ragged."""
+    r = spec.radius
+    if policy != "temporal":
+        raise PlanError(f"policy {policy!r} is 2-D only; a 3-D grid runs "
+                        f"the temporal kernel")
+    if masked:
+        raise PlanError("a 3-D plan takes no pin mask: the mesh path is "
+                        "2-D")
+    pi = shape[0] - 2 * r
+    for bp in range(min(bm_req or pi, pi), 0, -1):
+        vmem = _vmem_3d(shape, dtype, bp, t, r)
+        if vmem <= device.fast_memory_bytes:
+            break
+    else:
+        from repro.analysis.diagnostics import budget_message
+        raise PlanError(
+            budget_message(f"policy {policy!r} for 3-D grid {shape} "
+                           f"(one plane a block, t={t})", vmem, device)
+            + " — lower t, or plan for a device with more fast memory")
+    return ExecutionPlan(policy=policy, shape=shape, dtype=dtype, spec=spec,
+                         bm=bp, t=t, window_rows=bp + sum(_halo_planes(r, t)),
+                         vmem_bytes=vmem, device=device, masked=False)
+
+
 @functools.lru_cache(maxsize=1024)
-def _plan_cached(shape: tuple[int, int], dtype: str, spec: StencilSpec,
+def _plan_cached(shape: tuple[int, ...], dtype: str, spec: StencilSpec,
                  policy: str, bm_req: int | None, t: int,
                  device: DeviceModel, masked: bool) -> ExecutionPlan:
     # Executed only on a cache miss (lru_cache body), so this counter plus
     # the request counter in plan_for gives the hit/miss split.
     _metrics.counter("engine.plan.miss").inc()
-    h, w = shape
     r = spec.radius
-    if spec.ndim != 2:
-        raise PlanError(f"engine policies are 2-D; spec has ndim={spec.ndim} "
-                        "(embed 1-D stencils as 2-D row stencils)")
-    if h <= 2 * r or w <= 2 * r:
+    if spec.ndim not in (2, 3):
+        raise PlanError(f"engine policies are 2-D or 3-D; spec has "
+                        f"ndim={spec.ndim} (embed 1-D stencils as 2-D row "
+                        f"stencils)")
+    if len(shape) != spec.ndim:
+        raise PlanError(f"grid {shape} has {len(shape)} axes; the spec has "
+                        f"{spec.ndim}")
+    if any(s <= 2 * r for s in shape):
         raise PlanError(f"grid {shape} too small for stencil radius {r}")
     if t < 1:
         raise PlanError(f"temporal depth t={t} must be >= 1")
+    if spec.ndim == 3:
+        return _plan_3d(shape, dtype, spec, policy, bm_req, t, device,
+                        masked)
+    h, w = shape
     if masked and policy != "temporal":
         raise PlanError(f"policy {policy!r} takes no pin mask; only the "
                         f"temporal kernel streams one")
@@ -341,6 +441,34 @@ def plan_for(shape, dtype, spec: StencilSpec, policy: str, *,
     if _metrics.counter("engine.plan.miss").value == misses0:
         _metrics.counter("engine.plan.hit").inc()
     return plan
+
+
+def default_depth(shape, dtype, spec: StencilSpec,
+                  device: str | DeviceModel | None = None) -> int:
+    """The temporal depth a schedule fuses when none is asked for.
+
+    :data:`DEFAULT_T` on a 2-D grid. A 3-D window carries its halo as
+    whole planes, ``2 * t * r`` of them around each block, which the
+    fast-memory budget has to hold beside the block: a deeper ``t`` buys
+    fewer write-backs with a thinner block and more planes swept per
+    plane kept. So a 3-D grid takes the deepest ``t <= DEFAULT_T`` at
+    which its sweeps cover at most two planes per plane kept (``bm + (t -
+    1) * r <= 2 * bm``, the rows per row kept of the paper's 2-D plan), or
+    whose block is the whole interior; 1 where none is. At PolyBench's
+    200^3 on a v5e that is ``t=6``, the fastest of ``t`` in 4-8 on the
+    chip.
+    """
+    if spec.ndim != 3:
+        return DEFAULT_T
+    for t in range(DEFAULT_T, 1, -1):
+        try:
+            plan = plan_for(shape, dtype, spec, "temporal", t=t,
+                            device=device)
+        except PlanError:
+            continue
+        if plan.bm >= min((t - 1) * spec.radius, plan.interior_shape[0]):
+            return t
+    return 1
 
 
 def plan_cache_info():

@@ -1,4 +1,4 @@
-"""Pallas execution policies for arbitrary 2-D stencils.
+"""Pallas execution policies for arbitrary 2-D stencils (and 3-D, temporal).
 
 The four kernel generations of the paper's §IV → §VI → Table I → future-work
 arc, each generalized from the hard-coded 5-point Jacobi (0.25 x 4 taps) to
@@ -37,7 +37,10 @@ any 2-D :class:`~repro.core.stencil.StencilSpec` (any radius, any tap set):
       neighbours are in the other parity's tiles: one lane rotation
       serves a pair of horizontal taps. A window small enough for the
       register file (``plan.WHOLE_WINDOW_VREGS``; a narrow rind strip of
-      a distributed shard) sweeps as one value instead.
+      a distributed shard) sweeps as one value instead. A 3-D grid
+      (planes, rows, lanes) blocks its planes, with ``t*r``-deep halos of
+      whole planes, and sweeps each plane's strips the same way; its taps
+      one plane over are aligned loads of the neighbouring plane.
 
 Windows are built from Pallas blocks whose heights are multiples of the
 dtype's sublane tile (8 rows for f32, 16 for bf16): the TPU's compiler
@@ -57,7 +60,8 @@ for, not a constant.
 
 Names on the device trace: every kernel's ``pallas_call`` is named
 :data:`KERNEL_PREFIX` + its policy (``stencil_shifted``,
-``stencil_rowchunk``, ``stencil_dbuf``, ``stencil_temporal``), which XLA
+``stencil_rowchunk``, ``stencil_dbuf``, ``stencil_temporal``, and
+``stencil_temporal3d`` for the temporal policy's 3-D form), which XLA
 keeps as the device operation's name whatever Python function wraps the
 call; the scatter of the kernel's interior back into the ringed grid runs
 under the ``jax.named_scope`` ``stencil.writeback``.
@@ -363,6 +367,154 @@ def _temporal_window_kernel(*refs, nin: int, bm: int, t: int, r: int,
     o_ref[...] = c[top + r:top + r + bm, r:w - r].astype(o_ref.dtype)
 
 
+def _strip_ds(m, ts: int):
+    """Rows of strip ``m`` in an interleaved scratch window."""
+    return pl.ds(pl.multiple_of(m * ts, ts), ts)
+
+
+def _tiles(x):
+    """A strip's run of lane tiles (of every plane loaded) as one
+    (tiles * rows, 128) value."""
+    return x.reshape(-1, _LANE)
+
+
+def _stage(refs, stage, w: int, at: tuple = ()):
+    """The window rows of ``refs`` (each at leading index ``at``), in
+    order, into ``stage`` (lane tiles, rows, 128) in column order, one
+    lane tile at a time."""
+    row = 0
+    for ref in refs:
+        rows = ref.shape[len(at)]
+        for q in range(-(-w // _LANE)):
+            a, b = q * _LANE, min((q + 1) * _LANE, w)
+            stage[q, row:row + rows, :b - a] = ref[
+                at + (slice(None), slice(a, b))].astype(jnp.float32)
+        row += rows
+
+
+def _gather(stage, dst, ix, lead: tuple = ()):
+    """Each band's ``m``-th row of the staged window (a sublane-strided
+    load) into strip ``m`` of ``dst``, split by column parity; every tile
+    pair at once, as (tiles * rows, 128). ``lead`` indexes the leading
+    axes of both: a 3-D window moves all its planes at once, so each step
+    of the loop has many independent vregs in flight (``ix``: the
+    :func:`_parity_lanes` of that many rows)."""
+    nt, win, _ = dst.shape[-3:]
+    nh, ts = nt // 2, sublane_tile(jnp.float32)
+    nm = win // ts
+
+    def gather(m, carry):
+        rows = pl.ds(m, ts, stride=nm)
+        a = stage[lead + (pl.ds(0, nh, stride=2), rows, slice(None))]
+        e, o = _unzip(_tiles(a), _tiles(
+            stage[lead + (pl.ds(1, nh, stride=2), rows, slice(None))]), ix)
+        dst[lead + (slice(None, nh), _strip_ds(m, ts), slice(None))] = \
+            e.reshape(a.shape)
+        dst[lead + (slice(nh, None), _strip_ds(m, ts), slice(None))] = \
+            o.reshape(a.shape)
+        return carry
+    jax.lax.fori_loop(0, nm, gather, 0)
+
+
+def _scatter(src, lin, ix, lead: tuple = ()):
+    """Inverse of :func:`_gather`: the strips of ``src`` back to window
+    rows and columns in order, in ``lin``."""
+    nt, win, _ = src.shape[-3:]
+    nh, ts = nt // 2, sublane_tile(jnp.float32)
+    nm = win // ts
+
+    def scatter(m, carry):
+        rows = pl.ds(m, ts, stride=nm)
+        e = src[lead + (slice(None, nh), _strip_ds(m, ts), slice(None))]
+        lo, hi = _zip(_tiles(e), _tiles(
+            src[lead + (slice(nh, None), _strip_ds(m, ts), slice(None))]), ix)
+        lin[lead + (pl.ds(0, nh, stride=2), rows, slice(None))] = \
+            lo.reshape(e.shape)
+        lin[lead + (pl.ds(1, nh, stride=2), rows, slice(None))] = \
+            hi.reshape(e.shape)
+        return carry
+    jax.lax.fori_loop(0, nm, scatter, 0)
+
+
+def _sweep_strip(src_at, dst, m: int, *, offsets, weights, lane, band,
+                 row0, h: int, w: int, r: int, pin=None):
+    """Strip ``m`` of one sweep into ``dst``, an interleaved scratch window
+    (see :func:`_temporal_kernel`).
+
+    ``offsets`` are ``(di, dy, dx)``; ``src_at(di)`` is the scratch window
+    ``di`` planes over (a 2-D kernel's only window, ``di`` always 0).
+    ``row0`` is the grid row of window row 0, for the ring's rows; ``pin``,
+    where given, is the interleaved pin mask that replaces the ring. Every
+    index is static, so the compiler can schedule loads of one strip's
+    rows for the next."""
+    nt, win, _ = dst.shape
+    nh, ts = nt // 2, sublane_tile(jnp.float32)
+    nm = win // ts
+    f32 = jnp.float32
+
+    def rows_at(di, dy, qa, qb):
+        # Tiles [qa, qb) of the strip whose sublane j holds the row dy
+        # below this strip's: strip m+dy, or the next band's.
+        k, p = divmod(m + dy, nm)
+        x = src_at(di)[qa:qb, p * ts:(p + 1) * ts, :]
+        return pltpu.roll(x, (-k) % ts, 1) if k else x
+
+    row_fixed = None
+    if pin is None and not (isinstance(row0, int) and all(
+            r <= row0 + b * nm + m < h - r for b in range(ts))):
+        # The r-deep ring of the grid, and any window rows that stand
+        # for cells above or below it (none in this strip of a window
+        # whose grid rows are known while tracing).
+        grow = row0 + band * nm + m
+        row_fixed = (grow < r) | (grow >= h - r)
+    for par in (0, 1):
+        acc = c = None
+        for (di, dy, dx), wt in zip(offsets, weights):
+            # value at p + (di, dy, dx), weighted in the spec's order:
+            # column parity sp, sh lanes over
+            sp, sh = (par + dx) % 2, (par + dx) // 2
+            x = _lane_shift(rows_at(di, dy, sp * nh, (sp + 1) * nh), sh,
+                            lane)
+            if (di, dy, dx) == (0, 0, 0):
+                c = x
+            term = x * f32(wt)
+            acc = term if acc is None else acc + term
+        qa, qb = par * nh, (par + 1) * nh
+        if c is None:
+            c = rows_at(0, 0, qa, qb)
+        if pin is not None:
+            fixed = pin[qa:qb, m * ts:(m + 1) * ts, :] != 0
+        else:
+            fixed = (jnp.zeros(acc.shape, bool) if row_fixed is None
+                     else jnp.broadcast_to(row_fixed, acc.shape))
+            for k in range(nh):
+                # the ring's columns: only in the end tiles
+                lo = 2 * k * _LANE + par
+                if lo < r or lo + 2 * (_LANE - 1) >= w - r:
+                    col = lo + 2 * lane[0]
+                    fixed = _set_tile(fixed, k, (
+                        (col < r) | (col >= w - r) if row_fixed is None
+                        else row_fixed[0] | (col < r) | (col >= w - r)))
+        # Pinned cells never change, so the centre is their original
+        # value.
+        dst[qa:qb, m * ts:(m + 1) * ts, :] = jnp.where(fixed, c, acc)
+
+
+def _emit(lin, o_ref, top: int, r: int, lane, at: tuple = ()):
+    """The interior rows of the in-order window ``lin`` that sit ``r``
+    below its row ``top``, from column ``r`` on, into ``o_ref`` at leading
+    index ``at``."""
+    bm, wi = o_ref.shape[len(at):]
+    nt = lin.shape[0]
+    rows = _round_up(r + bm, sublane_tile(jnp.float32))
+    nq = -(-wi // _LANE)
+    y = _lane_shift(lin[:min(nq + 1, nt), top:top + rows, :], r, lane)
+    for q in range(nq):
+        a, b = q * _LANE, min((q + 1) * _LANE, wi)
+        o_ref[at + (slice(None), slice(a, b))] = y[
+            q, r:r + bm, :b - a].astype(o_ref.dtype)
+
+
 def _temporal_kernel(*refs, nin: int, bm: int, t: int, r: int, h: int,
                      w: int, top: int, offsets, weights, masked: bool):
     """``t`` sweeps of one block's window, strip by strip in VMEM.
@@ -381,10 +533,11 @@ def _temporal_kernel(*refs, nin: int, bm: int, t: int, r: int, h: int,
     one lane rotation a tap pair where column order would take two.
 
     A sweep reads each strip's taps, one column parity at a time, and
-    stores the pinned sum once. The strips are unrolled and no loop
-    carries more than its index, so nothing the size of the window is
-    spilled. The split into parities and its inverse move every tile pair
-    of a strip at once (tile-strided loads and stores).
+    stores the pinned sum once (:func:`_sweep_strip`). The strips are
+    unrolled and no loop carries more than its index, so nothing the size
+    of the window is spilled. The split into parities and its inverse
+    move every tile pair of a strip at once (tile-strided loads and
+    stores).
     """
     in_refs = refs[:nin]
     mask_refs = refs[nin:2 * nin] if masked else ()
@@ -393,95 +546,28 @@ def _temporal_kernel(*refs, nin: int, bm: int, t: int, r: int, h: int,
     nh = nt // 2                          # tiles of one column parity
     ts = sublane_tile(buf_a.dtype)        # bands: rows per strip
     nm = win // ts                        # strips
-    f32 = jnp.float32
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _LANE), 2)
     ix = _parity_lanes((nh * ts, _LANE))
-
-    def flat(x):
-        return x.reshape(nh * ts, _LANE)
-
-    def strip_rows(m):
-        return pl.ds(pl.multiple_of(m * ts, ts), ts)
-
-    def interleave(refs_, dst):
-        # Window rows in column order into buf_b, then each band's m-th
-        # row (a sublane-strided load) into strip m of dst, by parity.
-        row = 0
-        for ref in refs_:
-            rows = ref.shape[0]
-            for q in range(-(-w // _LANE)):
-                a, b = q * _LANE, min((q + 1) * _LANE, w)
-                buf_b[q, row:row + rows, :b - a] = ref[:, a:b].astype(f32)
-            row += rows
-
-        def gather(m, carry):
-            # every tile pair at once, as (tiles * rows, 128)
-            rows = pl.ds(m, ts, stride=nm)
-            e, o = _unzip(flat(buf_b[pl.ds(0, nh, stride=2), rows, :]),
-                          flat(buf_b[pl.ds(1, nh, stride=2), rows, :]), ix)
-            dst[:nh, strip_rows(m), :] = e.reshape(nh, ts, _LANE)
-            dst[nh:, strip_rows(m), :] = o.reshape(nh, ts, _LANE)
-            return carry
-        jax.lax.fori_loop(0, nm, gather, 0)
 
     if masked:
         # Explicit pin mask (nonzero = Dirichlet): on a distributed shard
         # only the *global* ring is pinned; exchanged halo cells evolve
         # with the fused sweeps.
-        interleave(mask_refs, buf_m[0])
-    interleave(in_refs, buf_a)
+        _stage(mask_refs, buf_b, w)
+        _gather(buf_b, buf_m[0], ix)
+    # Window rows in column order into buf_b, then by strip into buf_a.
+    _stage(in_refs, buf_b, w)
+    _gather(buf_b, buf_a, ix)
 
     band = jax.lax.broadcasted_iota(jnp.int32, (1, ts, 1), 1)
     ws = pl.program_id(0) * bm - top  # grid row of window row 0
-
-    def strip(src, dst, m: int):
-        """Strip ``m`` of one sweep. Every index is static, so the
-        compiler can schedule loads of one strip's rows for the next."""
-        def rows_at(dy, qa, qb):
-            # Tiles [qa, qb) of the strip whose sublane j holds the row dy
-            # below this strip's: strip m+dy, or the next band's.
-            k, p = divmod(m + dy, nm)
-            x = src[qa:qb, p * ts:(p + 1) * ts, :]
-            return pltpu.roll(x, (-k) % ts, 1) if k else x
-
-        if not masked:
-            # The r-deep ring of the grid, and any window rows that stand
-            # for cells above or below it.
-            grow = ws + band * nm + m
-            row_fixed = (grow < r) | (grow >= h - r)
-        for par in (0, 1):
-            acc = c = None
-            for (dy, dx), wt in zip(offsets, weights):
-                # value at p + (dy, dx), weighted in the spec's order:
-                # column parity sp, sh lanes over
-                sp, sh = (par + dx) % 2, (par + dx) // 2
-                x = _lane_shift(rows_at(dy, sp * nh, (sp + 1) * nh), sh,
-                                lane)
-                if (dy, dx) == (0, 0):
-                    c = x
-                term = x * f32(wt)
-                acc = term if acc is None else acc + term
-            qa, qb = par * nh, (par + 1) * nh
-            if c is None:
-                c = rows_at(0, qa, qb)
-            if masked:
-                fixed = buf_m[0][qa:qb, m * ts:(m + 1) * ts, :] != 0
-            else:
-                fixed = jnp.broadcast_to(row_fixed, acc.shape)
-                for k in range(nh):
-                    # the ring's columns: only in the end tiles
-                    lo = 2 * k * _LANE + par
-                    if lo < r or lo + 2 * (_LANE - 1) >= w - r:
-                        col = lo + 2 * lane[0]
-                        fixed = _set_tile(fixed, k, row_fixed[0]
-                                          | (col < r) | (col >= w - r))
-            # Pinned cells never change, so the centre is their original
-            # value.
-            dst[qa:qb, m * ts:(m + 1) * ts, :] = jnp.where(fixed, c, acc)
+    taps = tuple((0,) + tuple(o) for o in offsets)
 
     def sweep(src, dst):
         for m in range(nm):
-            strip(src, dst, m)
+            _sweep_strip(lambda di: src, dst, m, offsets=taps,
+                         weights=weights, lane=lane, band=band, row0=ws,
+                         h=h, w=w, r=r, pin=buf_m[0] if masked else None)
 
     def two_sweeps(_, carry):
         sweep(buf_a, buf_b)
@@ -492,26 +578,97 @@ def _temporal_kernel(*refs, nin: int, bm: int, t: int, r: int, h: int,
     if t % 2:
         sweep(buf_a, buf_b)
     out, lin = (buf_b, buf_a) if t % 2 else (buf_a, buf_b)
-
-    def scatter(m, carry):
-        # Back to window rows and columns in order.
-        rows = pl.ds(m, ts, stride=nm)
-        lo, hi = _zip(flat(out[:nh, strip_rows(m), :]),
-                      flat(out[nh:, strip_rows(m), :]), ix)
-        lin[pl.ds(0, nh, stride=2), rows, :] = lo.reshape(nh, ts, _LANE)
-        lin[pl.ds(1, nh, stride=2), rows, :] = hi.reshape(nh, ts, _LANE)
-        return carry
-    jax.lax.fori_loop(0, nm, scatter, 0)
+    _scatter(out, lin, ix)
     # The bm interior rows of this block sit r below the main block's
     # first row, and its columns r to the right of the window's; they are
     # exact after t sweeps.
-    rows = _round_up(r + bm, ts)
-    wi = o_ref.shape[1]
-    nq = -(-wi // _LANE)
-    y = _lane_shift(lin[:min(nq + 1, nt), top:top + rows, :], r, lane)
-    for q in range(nq):
-        a, b = q * _LANE, min((q + 1) * _LANE, wi)
-        o_ref[:, a:b] = y[q, r:r + bm, :b - a].astype(o_ref.dtype)
+    _emit(lin, o_ref, top, r, lane)
+
+
+def _temporal3d_kernel(*refs, nin: int, bm: int, t: int, r: int, shape,
+                       offsets, weights):
+    """``t`` sweeps of one block's window of whole planes, plane by plane
+    and strip by strip in VMEM.
+
+    The streamed planes (the halo planes before the main block, the
+    ``bm``-plane main block, the halo planes after it) are staged plane
+    by plane, then interleaved into two f32 scratch windows shaped
+    (planes, lane tiles, rows, 128), each plane laid out as the 2-D
+    kernel's window (:func:`_temporal_kernel`): rows interleaved into
+    strips, columns split by parity. The split and its inverse move
+    every plane at once: plane by plane, each loop step would hold one
+    vreg and wait out its chain of load, lane gathers and store (on a
+    v5e that took most of a launch). A plane's strip sweeps as a 2-D
+    strip does (:func:`_sweep_strip`); its taps one plane over are
+    aligned loads of the same strip in the neighbouring plane. Sweep
+    ``s`` skips the ``s * r`` planes at each end of the window, which are
+    stale by then; planes of the ring, and planes standing for cells
+    beyond it, pass through unchanged.
+    """
+    in_refs = refs[:nin]
+    o_ref, buf_a, buf_b = refs[nin:]
+    kp, nt, kh, _ = buf_a.shape
+    nh = nt // 2
+    ts = sublane_tile(buf_a.dtype)
+    nm = kh // ts
+    pd, h, w = shape
+    top = (t - 1) * r                     # halo planes before the block
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _LANE), 2)
+
+    for p, ref in enumerate(in_refs[:top]):
+        _stage([ref], buf_b.at[p], w)
+    main = in_refs[top]
+
+    def stage_main(j, carry):
+        _stage([main], buf_b.at[top + j], w, at=(j,))
+        return carry
+    jax.lax.fori_loop(0, bm, stage_main, 0)
+    for p, ref in enumerate(in_refs[top + 1:]):
+        _stage([ref], buf_b.at[top + bm + p], w)
+
+    _gather(buf_b, buf_a, _parity_lanes((kp * nh * ts, _LANE)),
+            (slice(None),))
+
+    band = jax.lax.broadcasted_iota(jnp.int32, (1, ts, 1), 1)
+    ws = pl.program_id(0) * bm - top      # grid plane of window plane 0
+
+    def sweep(src, dst, s):
+        def plane(p, carry):
+            gp = ws + p
+            ring = (gp < r) | (gp >= pd - r)
+
+            @pl.when(ring)
+            def _():
+                dst[p] = src[p]
+
+            @pl.when(jnp.logical_not(ring))
+            def _():
+                for m in range(nm):
+                    _sweep_strip(lambda di: src.at[p + di], dst.at[p], m,
+                                 offsets=offsets, weights=weights,
+                                 lane=lane, band=band, row0=0, h=h, w=w,
+                                 r=r)
+            return carry
+        jax.lax.fori_loop(s * r, kp - s * r, plane, 0)
+
+    def two_sweeps(k, carry):
+        sweep(buf_a, buf_b, 2 * k + 1)
+        sweep(buf_b, buf_a, 2 * k + 2)
+        return carry
+
+    jax.lax.fori_loop(0, t // 2, two_sweeps, 0)
+    if t % 2:
+        sweep(buf_a, buf_b, t)
+    out, lin = (buf_b, buf_a) if t % 2 else (buf_a, buf_b)
+
+    # Output plane j is window plane t*r + j, exact after t sweeps.
+    _scatter(out, lin, _parity_lanes((bm * nh * ts, _LANE)),
+             (pl.ds(top + r, bm),))
+
+    def emit(j, carry):
+        _emit(lin.at[top + r + j], o_ref, 0, r, lane, at=(j,))
+        return carry
+    jax.lax.fori_loop(0, bm, emit, 0)
 
 
 @functools.partial(jax.jit,
@@ -521,6 +678,9 @@ def stencil_temporal(u: jax.Array, spec: StencilSpec, *, t: int | None = None,
                      device: "str | DeviceModel | None" = None,
                      mask: jax.Array | None = None) -> jax.Array:
     """Advance the grid by exactly ``t`` sweeps in one HBM round-trip.
+
+    A 3-D grid (planes, rows, lanes) runs :func:`_temporal3d_kernel`,
+    named ``stencil_temporal3d`` on the device trace; it takes no mask.
 
     ``mask`` (optional, same shape as ``u``, nonzero = pinned) overrides
     the default Dirichlet set: without it the grid's own radius-``r`` ring
@@ -534,6 +694,8 @@ def stencil_temporal(u: jax.Array, spec: StencilSpec, *, t: int | None = None,
     masked = mask is not None
     plan = plan_for(u.shape, u.dtype, spec, "temporal", bm=bm, t=t,
                     device=device, masked=masked)
+    if plan.ndim == 3:
+        return _temporal3d(u, spec, plan, interpret)
     r = plan.radius
     h, w = u.shape
     hi, wi = plan.interior_shape
@@ -565,3 +727,51 @@ def stencil_temporal(u: jax.Array, spec: StencilSpec, *, t: int | None = None,
         name=KERNEL_PREFIX + "temporal",
     )(*operands)
     return _writeback(u, out, r)
+
+
+def _plane_specs(plan) -> list:
+    """BlockSpecs streaming one window of whole planes per step: the
+    plan's halo planes before the ``bm``-plane main block, one plane each
+    and clamped onto the grid's first plane, the main block (planes
+    ``[i*bm, (i+1)*bm)``, the last one possibly ragged), and the halo
+    planes after it, clamped onto the last. A clamped plane stands for
+    planes beyond the ring, which no kept cell reads."""
+    pd, h, w = plan.shape
+    bm = plan.bm
+    top, bot = plan.halo_planes
+
+    def plane(k):
+        return pl.BlockSpec(
+            (None, h, w),
+            lambda i: (jnp.clip(i * bm + k, 0, pd - 1), 0, 0))
+
+    return ([plane(k - top) for k in range(top)]
+            + [pl.BlockSpec((bm, h, w), lambda i: (i, 0, 0))]
+            + [plane(bm + k) for k in range(bot)])
+
+
+def _temporal3d(u, spec: StencilSpec, plan, interpret: bool):
+    """``plan.t`` sweeps of a 3-D grid through :func:`_temporal3d_kernel`,
+    one ``plan.bm``-plane block per grid step."""
+    w = u.shape[2]
+    specs = _plane_specs(plan)
+    kernel = functools.partial(
+        _temporal3d_kernel, nin=len(specs), bm=plan.bm, t=plan.t,
+        r=plan.radius, shape=u.shape, offsets=spec.offsets,
+        weights=spec.weights)
+    buf = pltpu.VMEM((plan.window_rows, 2 * -(-w // (2 * _LANE)),
+                      plan.kernel_rows, _LANE), jnp.float32)
+    interior = plan.interior_shape
+    out = pl.pallas_call(
+        kernel,
+        grid=(plan.nblocks,),
+        in_specs=specs,
+        out_specs=pl.BlockSpec((plan.bm,) + interior[1:],
+                               lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(interior, u.dtype),
+        scratch_shapes=[buf, buf],
+        compiler_params=_compiler_params(plan, interpret),
+        interpret=interpret,
+        name=KERNEL_PREFIX + "temporal3d",
+    )(*[u] * len(specs))
+    return _writeback(u, out, plan.radius)

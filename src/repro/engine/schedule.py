@@ -37,11 +37,12 @@ import jax.numpy as jnp
 
 from repro.core.stencil import StencilSpec
 from repro.engine.device import DeviceModel, get_device
-from repro.engine.plan import DEFAULT_T, PlanError
+from repro.engine.plan import DEFAULT_T, PlanError, default_depth
 from repro.obs.trace import span as _obs_span
 
 #: Non-fused policy used for the leftover sweeps when ``iters`` is not a
-#: multiple of the temporal depth.
+#: multiple of the temporal depth (on a 2-D grid; a 3-D schedule runs its
+#: kernel once more at the remainder's depth).
 DEFAULT_REMAINDER_POLICY = "rowchunk"
 
 
@@ -81,8 +82,9 @@ class SweepSchedule:
     round-trip each when the policy is fused; one halo exchange each at
     mesh scale), then ``remainder`` sweeps run under ``remainder_policy``
     (a non-fused registry policy; equal to ``policy`` when the main policy
-    is itself non-fused). Frozen and hashable, so a schedule can key
-    caches and ride through jit closures like a plan does.
+    is itself non-fused, or fused on a 3-D grid, where the kernel runs
+    once more at depth ``remainder``). Frozen and hashable, so a schedule
+    can key caches and ride through jit closures like a plan does.
     """
 
     policy: str
@@ -325,7 +327,9 @@ def _build_schedule(iters: int, *, spec: StencilSpec, shape, dtype,
     ``iters`` raises a ``UserWarning`` — silently degrading the requested
     fusion depth is the same class of bug ``pick_bm`` warns about. A
     fused ``remainder_policy`` is rejected exactly like ``engine.run``
-    always has.
+    always has. A 3-D grid ignores ``remainder_policy`` (every other
+    policy is 2-D): its remainder runs the fused kernel at that depth, and
+    its default depth is :func:`~repro.engine.plan.default_depth`'s.
 
     ``overlap`` (distributed executors only, i.e. under
     ``exchange_cadence``) selects the interior/rind split that hides each
@@ -373,7 +377,8 @@ def _build_schedule(iters: int, *, spec: StencilSpec, shape, dtype,
         fused = get_policy(policy).fused
 
     if fused or exchange_cadence:
-        t_eff = effective_depth(iters, t)
+        t_eff = effective_depth(iters, t, default_depth(shape, dtype, spec,
+                                                        device))
         if t is not None and iters > 0 and t_eff < t:
             warnings.warn(
                 f"requested fusion depth t={t} exceeds iters={iters}; "
@@ -385,7 +390,9 @@ def _build_schedule(iters: int, *, spec: StencilSpec, shape, dtype,
         t_eff = 1
     nfull, rem = divmod(iters, t_eff)
 
-    if fused:
+    if fused and spec.ndim == 3:
+        rp = policy  # the kernel again, at the remainder's depth
+    elif fused:
         if rem:
             from repro.engine.dispatch import get_policy
             if get_policy(remainder_policy).fused:

@@ -19,8 +19,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.stencil import jacobi_2d_5pt
+from repro.core.stencil import StencilSpec, jacobi_2d_5pt
 from repro.engine import get_device, plan_for
+from repro.engine.plan import default_depth
 from repro.engine import policies as P
 
 SPEC = jacobi_2d_5pt()
@@ -101,3 +102,24 @@ def test_temporal_compiles_as_one_block_of_untiled_height(one_chip, masked):
                               masked)
     assert plan.nblocks == 1 and plan.kernel_rows == 32
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_temporal3d_compiles_at_polybench_size(one_chip):
+    """PolyBench heat-3d at EXTRALARGE (a 198^3 interior, ringed 200^3,
+    f32): the 3-D temporal kernel at the planner's default depth and
+    plane block, whose lane axis (200) fills two lane tiles in part."""
+    spec = StencilSpec(
+        offsets=((0, 0, 0), (-1, 0, 0), (1, 0, 0), (0, -1, 0), (0, 1, 0),
+                 (0, 0, -1), (0, 0, 1)),
+        weights=(0.25,) + (0.125,) * 6)
+    shape = (200, 200, 200)
+    t = default_depth(shape, jnp.float32, spec, "tpu_v5e")
+    plan = plan_for(shape, jnp.float32, spec, "temporal", t=t,
+                    device="tpu_v5e")
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    fn = jax.jit(lambda u: P.stencil_temporal(
+        u, spec, t=t, interpret=False, device="tpu_v5e"))
+    hlo = fn.lower(x).compile().as_text()
+    assert f"%{P.KERNEL_PREFIX}temporal3d." in hlo
+    assert plan.vmem_bytes <= get_device("tpu_v5e").fast_memory_bytes
+    assert plan.nblocks > 1 and plan.nblocks * plan.bm > 198  # ragged
