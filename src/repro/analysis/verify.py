@@ -21,8 +21,10 @@ falsify by hanging):
   ``k``.
 * **Address bounds** — every :class:`ReadBlock`/:class:`WriteBlock`
   block-relative window is checked against the grid/mask stream extents
-  for *all* block indices ``i`` (``row0 = r + i*bm``), so ragged-edge and
-  ``t*r``-halo window arithmetic is proven in-range, not spot-checked.
+  for *all* block indices ``i`` (``row0 = first_row + i*bm``), so
+  ragged-edge and ``t*r``-halo window arithmetic is proven in-range, not
+  spot-checked; a clipping write may run past the stream's end only
+  within its last block.
 * **Device budgets** — the summed CB footprint vs per-core SRAM
   (``BUD-SRAM``) and the CB count vs the device's CB file
   (``BUD-CBFILE``), formatted like every other budget error.
@@ -233,6 +235,7 @@ def _bounds_pass(prog: TensixProgram, diags: list[Diagnostic]) -> None:
     plan = prog.plan
     h, w = plan.shape
     r = plan.spec.radius
+    row0 = plan.first_row
     bm, nblocks = plan.bm, plan.nblocks
     for kernel, ops in _kernels(prog):
         for idx, op in enumerate(ops):
@@ -259,10 +262,11 @@ def _bounds_pass(prog: TensixProgram, diags: list[Diagnostic]) -> None:
                         f"{stream} stream's {h} total rows",
                         hint="shrink the window (lower bm or t)"))
                 continue
-            # row0 = r + i*bm; start monotonically increases with i, so
-            # the extremes certify every block index.
-            start0 = r + op.dy
-            end_last = r + (nblocks - 1) * bm + op.dy + op.rows
+            # Block i starts at row0 + i*bm (row0: the plan's first output
+            # row); start monotonically increases with i, so the extremes
+            # certify every block index.
+            start0 = row0 + op.dy
+            end_last = row0 + (nblocks - 1) * bm + op.dy + op.rows
             if start0 < 0:
                 diags.append(error(
                     "AB-ROW", span,
@@ -271,15 +275,20 @@ def _bounds_pass(prog: TensixProgram, diags: list[Diagnostic]) -> None:
                     f"past the radius-{r} ring)",
                     hint="set clamp=True for boundary blocks or shrink "
                          "|dy| to <= the ring depth"))
+            if getattr(op, "clip", False) and end_last - op.rows < h:
+                # Rows past the stream's end are dropped; the last block
+                # must still start inside it, or it writes nothing.
+                end_last = min(end_last, h)
             if end_last > h:
                 # The smallest violating block index is the counterexample.
                 i_bad = 0
                 if bm > 0:
-                    i_bad = max(0, -(-(h - r - op.dy - op.rows + 1) // bm))
+                    i_bad = max(0,
+                                -(-(h - row0 - op.dy - op.rows + 1) // bm))
                 diags.append(error(
                     "AB-ROW", span,
-                    f"rows [{r + i_bad * bm + op.dy},"
-                    f"{r + i_bad * bm + op.dy + op.rows}) at block "
+                    f"rows [{row0 + i_bad * bm + op.dy},"
+                    f"{row0 + i_bad * bm + op.dy + op.rows}) at block "
                     f"{i_bad}/{nblocks} run past the {stream} stream's "
                     f"{h} rows",
                     hint="set clamp=True for boundary blocks, or fix the "

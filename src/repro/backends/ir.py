@@ -186,8 +186,10 @@ class LocalSweeps:
 
 @dataclasses.dataclass(frozen=True)
 class WriteBlock:
-    """CB -> DRAM: the mirror of :class:`ReadBlock` (no clamp; writers
-    always target the block's exact output rows)."""
+    """CB -> DRAM: the mirror of :class:`ReadBlock`. Writers target the
+    block's exact output rows, never clamped; ``clip`` drops the rows a
+    ragged last block holds past the array's end (the temporal policy,
+    whose blocks tile the ringed height)."""
 
     cb: str
     dy: int
@@ -197,6 +199,7 @@ class WriteBlock:
     contiguous: bool = True
     seg_cols: int | None = None
     sync: bool = False
+    clip: bool = False
 
     def txns(self) -> int:
         if self.seg_cols:
@@ -339,8 +342,9 @@ def _op_str(op) -> str:
                 f"cols=[{op.col0},{op.col0 + op.cols}) {mode}{extra}")
     if isinstance(op, WriteBlock):
         mode = "contig" if op.contiguous else "strided"
+        extra = " clip" if op.clip else ""
         return (f"write_block <- {op.cb:8s} rows={op.rows} dy={op.dy:+d} "
-                f"cols=[{op.col0},{op.col0 + op.cols}) {mode}")
+                f"cols=[{op.col0},{op.col0 + op.cols}) {mode}{extra}")
     if isinstance(op, Tilize):
         return f"tilize      {op.src} -> {op.dst}"
     if isinstance(op, Untilize):

@@ -143,9 +143,12 @@ def _lower_temporal(spec, plan, dev, dtype, cdtype, tilized):
     if tilized:
         cbs.append(_cb("out_raw", bm, w, dev, dtype))
         writer.append(Untilize(src="out", dst="out_raw"))
-    # t sweeps' central rows go back in one contiguous full-width write.
+    # The block's own rows of the ringed grid (the ring pinned by the
+    # sweeps) go back in one contiguous full-width write; a ragged last
+    # block's rows past the grid are dropped.
     writer.append(WriteBlock(cb="out_raw" if tilized else "out", dy=0,
-                             rows=bm, col0=0, cols=w, contiguous=True))
+                             rows=bm, col0=0, cols=w, contiguous=True,
+                             clip=True))
     return cbs, tuple(reader), tuple(compute), tuple(writer)
 
 

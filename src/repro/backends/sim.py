@@ -209,7 +209,7 @@ def _run_block(prog: TensixProgram, u: np.ndarray, out: np.ndarray,
     plan = prog.plan
     r = plan.spec.radius
     h, w = plan.shape
-    row0 = r + block * plan.bm
+    row0 = plan.first_row + block * plan.bm
     gdtype = np_dtype(plan.dtype)
     db = gdtype.itemsize
     cbs = _CBState(prog)
@@ -306,9 +306,10 @@ def _run_block(prog: TensixProgram, u: np.ndarray, out: np.ndarray,
         elif isinstance(op, WriteBlock):
             e = cbs.pop(op.cb)
             data = _entry_2d(e).astype(gdtype)
-            out[row0 + op.dy:row0 + op.dy + op.rows,
-                op.col0:op.col0 + op.cols] = data
-            nbytes = op.rows * op.cols * db
+            start = row0 + op.dy
+            rows = min(op.rows, h - start) if op.clip else op.rows
+            out[start:start + rows, op.col0:op.col0 + op.cols] = data[:rows]
+            nbytes = rows * op.cols * db
             txns = op.txns()
             counters.writer.bytes += nbytes
             counters.writer.txns += txns

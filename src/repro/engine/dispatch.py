@@ -230,9 +230,10 @@ def residual_for(spec: StencilSpec | None = None) -> Callable:
 def kernel_attrs(sched, shape, dtype, spec: StencilSpec, bm,
                  device, masked: bool = False) -> dict:
     """The launched kernel's form as span attributes: its strip height
-    and the rows it sweeps per row it keeps, and on a 3-D grid its
-    planes per block and halo planes (``recompute`` there counts planes
-    and rows); none for the pure-jnp reference, which has no plan."""
+    and the rows it sweeps per row it keeps, its halo rows on a 2-D grid,
+    and on a 3-D grid its planes per block and halo planes
+    (``recompute`` there counts planes and rows); none for the pure-jnp
+    reference, which has no plan."""
     if sched.policy == "reference":
         return {}
     plan = plan_for(shape, dtype, spec, sched.policy, bm=bm, t=sched.t,
@@ -241,6 +242,8 @@ def kernel_attrs(sched, shape, dtype, spec: StencilSpec, bm,
              "recompute": round(plan.recompute, 4)}
     if plan.ndim == 3:
         attrs.update(block_planes=plan.bm, halo_planes=plan.halo_planes)
+    else:
+        attrs.update(halo_rows=plan.halo_rows)
     return attrs
 
 

@@ -28,7 +28,7 @@ from repro.engine.device import DeviceModel, get_device
 from repro.obs import metrics as _metrics
 
 # Knob defaults shared by every policy.
-DEFAULT_BM = 256   # largest interior-row block bm=None plans
+DEFAULT_BM = 256   # largest row block bm=None plans
 DEFAULT_T = 8      # temporal fusion depth (sweeps per HBM round-trip)
 
 #: A temporal window of at most this many f32 vregs (8x128 tiles) sweeps
@@ -60,9 +60,9 @@ def _round_up(n: int, m: int) -> int:
 
 
 def bm_candidates(h_int: int, align: int) -> list[int]:
-    """Exact row-block heights for an ``h_int``-row interior, largest
-    first: every divisor that is a multiple of ``align``, plus the full
-    interior height (a single block, always legal)."""
+    """Exact row-block heights for ``h_int`` rows to tile, largest first:
+    every divisor that is a multiple of ``align``, plus ``h_int`` itself
+    (a single block, always legal)."""
     out = {h_int}
     out.update(d for d in range(align, h_int, align) if h_int % d == 0)
     return sorted(out, reverse=True)
@@ -96,14 +96,16 @@ class ExecutionPlan:
     """Fully-resolved launch parameters for one policy on one problem.
 
     shape/dtype describe the ringed grid (boundary included); ``bm`` is the
-    number of interior rows each grid step produces; ``window_rows`` is the
-    height of the fast-memory-resident input window that block needs
-    (bm + halo); ``t`` is the number of sweeps fused per HBM round-trip
-    (1 unless the policy is temporal); ``device`` is the model whose budget
-    validated the plan.
+    number of rows each grid step produces: rows of the ringed grid for
+    the temporal kernel, which writes its main block's rows ring and all,
+    interior rows for the other policies (:attr:`tiled_rows`);
+    ``window_rows`` is the height of the fast-memory-resident input window
+    that block needs (bm + halo); ``t`` is the number of sweeps fused per
+    HBM round-trip (1 unless the policy is temporal); ``device`` is the
+    model whose budget validated the plan.
 
     A 3-D plan (ringed shape (planes, rows, lanes), temporal only) blocks
-    the plane axis alone: ``bm`` is the interior planes a grid step
+    the plane axis alone: ``bm`` is the ringed planes a grid step
     produces, ``window_rows`` the planes its window holds (``bm`` plus
     :attr:`halo_planes`), and every plane is streamed whole.
     """
@@ -137,10 +139,23 @@ class ExecutionPlan:
         return tuple(s - 2 * r for s in self.shape)
 
     @property
+    def tiled_rows(self) -> int:
+        """Rows (3-D: planes) the grid steps tile: the ringed height for
+        the temporal kernel, the interior's for the other policies."""
+        return _tiled_rows(self.policy, self.shape[0], self.radius)
+
+    @property
+    def first_row(self) -> int:
+        """Grid row (3-D: plane) where block 0's output starts: 0 where
+        the blocks tile the ringed grid, the radius where they tile the
+        interior."""
+        return (self.shape[0] - self.tiled_rows) // 2
+
+    @property
     def nblocks(self) -> int:
         """Grid steps; the last block is ragged when ``bm`` does not
-        divide the interior height."""
-        return -(-self.interior_shape[0] // self.bm)
+        divide :attr:`tiled_rows`."""
+        return -(-self.tiled_rows // self.bm)
 
     @property
     def halo_rows(self) -> tuple[int, int]:
@@ -184,17 +199,17 @@ class ExecutionPlan:
 
     @property
     def recompute(self) -> float:
-        """Rows a sweep computes per interior row it keeps: the temporal
-        kernel sweeps its whole window, halo included; the others compute
-        only their block. A 3-D sweep ``s`` of ``t`` skips the ``s * r``
-        planes at each end of the window that are already stale, so on
-        average ``bm + (t - 1) * r`` planes are swept per ``bm`` kept, each
-        over ``kernel_rows`` rows per interior row kept."""
+        """Rows a sweep computes per row it keeps: the temporal kernel
+        sweeps its whole window, halo included; the others compute only
+        their block. A 3-D sweep ``s`` of ``t`` skips the ``s * r`` planes
+        at each end of the window that are already stale, so on average
+        ``bm + (t - 1) * r`` planes are swept per ``bm`` kept, each over
+        ``kernel_rows`` rows per row of the plane kept."""
         if self.policy != "temporal":
             return 1.0
         if self.ndim == 3:
             planes = (self.bm + (self.t - 1) * self.radius) / self.bm
-            return planes * self.kernel_rows / self.interior_shape[1]
+            return planes * self.kernel_rows / self.shape[1]
         return self.kernel_rows / self.bm
 
     def describe(self) -> str:
@@ -217,15 +232,21 @@ class ExecutionPlan:
                 f"{strip}device={self.device.name}")
 
 
+def _tiled_rows(policy: str, h: int, r: int) -> int:
+    """Rows a policy's grid steps tile: the temporal kernel writes rows of
+    the ringed grid, ``h`` of them; the others write the interior's."""
+    return h if policy == "temporal" else h - 2 * r
+
+
 def _row_align(policy: str, r: int, t: int, dtype) -> int:
     """Rows a block height must be a multiple of: the sublane tile, or
     the policy's halo block when that is taller (the halo rides as a
     block of its own, indexed in units of its height)."""
     tile = sublane_tile(dtype)
     if policy == "temporal":
-        # The output rows sit r below the main block's first row; t sweeps
-        # need t*r valid rows past them on each side.
-        return _round_up((t + 1) * r, tile)
+        # The kernel writes its main block's own rows; t sweeps need t*r
+        # valid rows past them on each side.
+        return _round_up(t * r, tile)
     if policy in ("rowchunk", "dbuf"):
         return _round_up(2 * r, tile)
     return tile
@@ -274,12 +295,13 @@ def _window_and_vmem(policy: str, shape, dtype, spec: StencilSpec,
     """
     h, w = shape
     r = spec.radius
-    hi, wi = h - 2 * r, w - 2 * r
+    wi = w - 2 * r
     db = jnp.dtype(dtype).itemsize
     if policy not in ("shifted", "rowchunk", "dbuf", "temporal"):
         raise PlanError(f"unknown policy {policy!r}")
-    halo = _halo_rows(policy, r, t, dtype, bm == hi)
-    kwin = _kernel_rows(policy, h, dtype, bm, halo, bm == hi)
+    single = bm == _tiled_rows(policy, h, r)
+    halo = _halo_rows(policy, r, t, dtype, single)
+    kwin = _kernel_rows(policy, h, dtype, bm, halo, single)
     out = 2 * bm * wi * db                       # double-buffered output
     if policy == "shifted":
         # One streamed (bm, wi) block per tap plus the output block; the
@@ -293,49 +315,47 @@ def _window_and_vmem(policy: str, shape, dtype, spec: StencilSpec,
         f32 = kwin * w * 4 + 2 * bm * wi * 4     # window + accumulator
         return min(bm + 2 * r, h), bufs * kwin * w * db + out + f32
     # temporal: grid (and pin-mask) windows double-buffered, the output
-    # block, and the f32 copies the t sweeps run on: in strips, a scratch
-    # of two ping-pong windows, plus the pin mask's with a mask (an even
-    # number of lane tiles: a tile of each column parity); whole, the
-    # carried window, the pinned originals, a rolled tap and the sum.
-    # Every VMEM row holds whole lane tiles.
-    lw, lwi = _round_up(w, 128), _round_up(wi, 128)
+    # block of ringed rows, and the f32 copies the t sweeps run on: in
+    # strips, a scratch of two ping-pong windows, plus the pin mask's with
+    # a mask (an even number of lane tiles: a tile of each column parity);
+    # whole, the carried window, the pinned originals, a rolled tap and
+    # the sum. Every VMEM row holds whole lane tiles.
+    lw = _round_up(w, 128)
     streams = 2 if masked else 1
     if _strip_rows(policy, kwin, w):
         f32 = (streams + 1) * kwin * _round_up(w, 256) * 4
     else:
         f32 = 4 * kwin * lw * 4
     return (min(bm + 2 * t * r, h),
-            2 * streams * kwin * lw * db + 2 * bm * lwi * db + f32)
+            2 * streams * kwin * lw * db + 2 * bm * lw * db + f32)
 
 
 def _halo_planes(r: int, t: int) -> tuple[int, int]:
     """Planes a 3-D temporal window holds before and after its main block:
-    the output planes sit ``r`` past the main block's first, and ``t``
-    sweeps need ``t * r`` valid planes past them on each side."""
-    return ((t - 1) * r, (t + 1) * r)
+    the kernel writes the main block's own planes, and ``t`` sweeps need
+    ``t * r`` valid planes past them on each side."""
+    return (t * r, t * r)
 
 
 def _vmem_3d(shape, dtype, bp: int, t: int, r: int) -> int:
     """Fast-memory footprint of the 3-D temporal kernel for ``bp``-plane
     blocks: the window's planes streamed in (double-buffered), the output
-    block (double-buffered), and two f32 ping-pong scratch windows of
-    whole lane-tile pairs. Each plane takes whole sublane and lane tiles."""
+    block of ringed planes (double-buffered), and two f32 ping-pong
+    scratch windows of whole lane-tile pairs. Each plane takes whole
+    sublane and lane tiles."""
     _, h, w = shape
-    hi, wi = h - 2 * r, w - 2 * r
-    tile = sublane_tile(dtype)
     db = jnp.dtype(dtype).itemsize
     kp = bp + sum(_halo_planes(r, t))
-    plane_in = _round_up(h, tile) * _round_up(w, 128) * db
-    plane_out = _round_up(hi, tile) * _round_up(wi, 128) * db
+    plane = _round_up(h, sublane_tile(dtype)) * _round_up(w, 128) * db
     plane_f32 = _round_up(h, sublane_tile(jnp.float32)) * _round_up(w, 256) * 4
-    return 2 * kp * plane_in + 2 * bp * plane_out + 2 * kp * plane_f32
+    return 2 * (kp + bp) * plane + 2 * kp * plane_f32
 
 
 def _plan_3d(shape, dtype: str, spec: StencilSpec, policy: str,
              bm_req: int | None, t: int, device: DeviceModel,
              masked: bool) -> ExecutionPlan:
-    """A 3-D temporal plan: the deepest plane block up to the interior's
-    (or the ``bm`` request) whose footprint fits; the last may be
+    """A 3-D temporal plan: the deepest plane block up to the grid's
+    planes (or the ``bm`` request) whose footprint fits; the last may be
     ragged."""
     r = spec.radius
     if policy != "temporal":
@@ -344,8 +364,8 @@ def _plan_3d(shape, dtype: str, spec: StencilSpec, policy: str,
     if masked:
         raise PlanError("a 3-D plan takes no pin mask: the mesh path is "
                         "2-D")
-    pi = shape[0] - 2 * r
-    for bp in range(min(bm_req or pi, pi), 0, -1):
+    pd = shape[0]
+    for bp in range(min(bm_req or pd, pd), 0, -1):
         vmem = _vmem_3d(shape, dtype, bp, t, r)
         if vmem <= device.fast_memory_bytes:
             break
@@ -386,20 +406,21 @@ def _plan_cached(shape: tuple[int, ...], dtype: str, spec: StencilSpec,
     if masked and policy != "temporal":
         raise PlanError(f"policy {policy!r} takes no pin mask; only the "
                         f"temporal kernel streams one")
-    hi = h - 2 * r
+    rows = _tiled_rows(policy, h, r)
     align = _row_align(policy, r, t, dtype)
-    if bm_req is None:
-        # The largest block up to DEFAULT_BM (so the grid keeps several
-        # steps for the pipeline to overlap) that fits the budget: exact
-        # tilings first, then tile multiples with a ragged last block
-        # (the kernels drop its rows past the interior).
-        cap = min(DEFAULT_BM, hi)
-        cands = [c for c in bm_candidates(hi, align) if c <= cap]
-        cands += [c for c in range(cap // align * align, 0, -align)
-                  if c not in cands]
-        cands = cands or [pick_bm(hi, cap, align)]
-    else:
-        cands = [pick_bm(hi, bm_req, align)]
+    # The largest block up to the request, or up to DEFAULT_BM (so the
+    # grid keeps several steps for the pipeline to overlap): exact tilings
+    # first, then tile multiples with a ragged last block (the kernels
+    # drop its rows past the grid); a request below the alignment gets
+    # the smallest block. Left to choose, the planner takes the first that
+    # fits the budget; a request takes the first or fails.
+    cap = min(DEFAULT_BM if bm_req is None else bm_req, rows)
+    cands = [c for c in bm_candidates(rows, align) if c <= cap]
+    cands += [c for c in range(cap // align * align, 0, -align)
+              if c not in cands]
+    cands = cands or [min(align, rows)]
+    if bm_req is not None:
+        cands = cands[:1]
     for bm in cands:
         win, vmem = _window_and_vmem(policy, shape, dtype, spec, bm, t,
                                      masked)
@@ -425,10 +446,11 @@ def plan_for(shape, dtype, spec: StencilSpec, policy: str, *,
     """Resolve (and cache) an :class:`ExecutionPlan` for static arguments.
 
     ``bm``/``t`` are requests; the plan holds the realized values (``bm``
-    is snapped by :func:`pick_bm` to the policy's row alignment, ``t`` is
-    forced to 1 for non-temporal policies). ``bm=None`` takes the largest
-    height up to ``DEFAULT_BM`` whose footprint fits the device, exact
-    tilings first, then a ragged last block. ``device`` is a registry name or model; None
+    is the largest height up to the request that the policy's row
+    alignment allows, an exact tiling first, then a ragged last block;
+    ``t`` is forced to 1 for non-temporal policies). ``bm=None`` takes the
+    largest such height up to ``DEFAULT_BM`` whose footprint fits the
+    device. ``device`` is a registry name or model; None
     plans against the detected host backend (``device.detect()``).
     ``masked`` plans the temporal kernel's explicit pin-mask stream (the
     distributed shard form).
@@ -450,11 +472,11 @@ def default_depth(shape, dtype, spec: StencilSpec,
     :data:`DEFAULT_T` on a 2-D grid. A 3-D window carries its halo as
     whole planes, ``2 * t * r`` of them around each block, which the
     fast-memory budget has to hold beside the block: a deeper ``t`` buys
-    fewer write-backs with a thinner block and more planes swept per
-    plane kept. So a 3-D grid takes the deepest ``t <= DEFAULT_T`` at
+    fewer launches with a thinner block and more planes swept per plane
+    kept. So a 3-D grid takes the deepest ``t <= DEFAULT_T`` at
     which its sweeps cover at most two planes per plane kept (``bm + (t -
     1) * r <= 2 * bm``, the rows per row kept of the paper's 2-D plan), or
-    whose block is the whole interior; 1 where none is. At PolyBench's
+    whose block is the whole grid; 1 where none is. At PolyBench's
     200^3 on a v5e that is ``t=6``, the fastest of ``t`` in 4-8 on the
     chip.
     """
@@ -466,7 +488,7 @@ def default_depth(shape, dtype, spec: StencilSpec,
                             device=device)
         except PlanError:
             continue
-        if plan.bm >= min((t - 1) * spec.radius, plan.interior_shape[0]):
+        if plan.bm >= min((t - 1) * spec.radius, plan.shape[0]):
             return t
     return 1
 

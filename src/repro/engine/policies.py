@@ -22,9 +22,11 @@ any 2-D :class:`~repro.core.stencil.StencilSpec` (any radius, any tap set):
       buffering" row, done by the TPU's block pipeline).
 
   ``temporal``  — beyond-paper: T sweeps fused per HBM round-trip. Each
-      block streams a window with tile-rounded halos of at least
-      (T+1)*r rows per side, advances it T sweeps locally (valid region
-      shrinking by r rows per sweep) and writes back the central rows.
+      block streams a window with tile-rounded halos of at least T*r
+      rows per side, advances it T sweeps locally (valid region
+      shrinking by r rows per sweep) and writes its main block's rows,
+      every lane, into a fresh ringed grid (the ring is pinned, so it
+      comes out as it went in): no pass follows the kernel.
       HBM traffic per sweep drops ~Tx at the cost of O(T²r²) redundant
       halo compute — the right trade when the compute:bandwidth ratio
       dwarfs the stencil's arithmetic intensity. The sweeps run in two
@@ -48,7 +50,8 @@ refuses a DMA row window of any other height, and refuses any manual row
 slice of an HBM grid whose width is not a multiple of 128 lanes (the
 paper's ringed width is 9218). So the grid operand stays in HBM and only
 blocks enter VMEM; the planner (``engine.plan``) picks block heights that
-tile the interior, or lets the last block run ragged.
+tile the rows each kernel writes (the ringed grid's for ``temporal``, the
+interior's for the others), or lets the last block run ragged.
 
 All grids are "ringed": shape (H, W) with a fixed Dirichlet boundary ring of
 width ``spec.radius``; only the interior is updated. Kernels accumulate in
@@ -63,8 +66,9 @@ Names on the device trace: every kernel's ``pallas_call`` is named
 ``stencil_rowchunk``, ``stencil_dbuf``, ``stencil_temporal``, and
 ``stencil_temporal3d`` for the temporal policy's 3-D form), which XLA
 keeps as the device operation's name whatever Python function wraps the
-call; the scatter of the kernel's interior back into the ringed grid runs
-under the ``jax.named_scope`` ``stencil.writeback``.
+call. The single-sweep policies write the interior, which is scattered
+back into the ringed grid under the ``jax.named_scope``
+``stencil.writeback``; the temporal kernel writes the ringed grid itself.
 """
 from __future__ import annotations
 
@@ -77,7 +81,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.stencil import StencilSpec
 from repro.engine.device import DeviceModel  # noqa: F401  (annotations)
-from repro.engine.plan import _round_up, plan_for, sublane_tile
+from repro.engine.plan import plan_for, sublane_tile
 from repro.obs import metrics as _metrics
 
 #: Prefix of every kernel's name on the device trace.
@@ -96,7 +100,9 @@ def _tap_sum(c, bm: int, r: int, w: int, offsets, weights):
 
 
 def _writeback(u, out, r: int):
-    """The kernel's interior written back into the ringed grid."""
+    """The kernel's interior written back into the ringed grid (counted
+    once a trace as ``engine.kernel.writeback``)."""
+    _metrics.counter("engine.kernel.writeback").inc()
     with jax.named_scope("stencil.writeback"):
         return u.at[tuple(slice(r, s - r) for s in u.shape)].set(out)
 
@@ -362,9 +368,8 @@ def _temporal_window_kernel(*refs, nin: int, bm: int, t: int, r: int,
         return jnp.where(fixed, c0, acc)
 
     c = jax.lax.fori_loop(0, t, sweep, c0)
-    # The bm interior rows of this block sit r below the main block's
-    # first row; they are exact after t sweeps.
-    o_ref[...] = c[top + r:top + r + bm, r:w - r].astype(o_ref.dtype)
+    # The main block's rows, ring included, are exact after t sweeps.
+    o_ref[...] = c[top:top + o_ref.shape[0]].astype(o_ref.dtype)
 
 
 def _strip_ds(m, ts: int):
@@ -500,19 +505,14 @@ def _sweep_strip(src_at, dst, m: int, *, offsets, weights, lane, band,
         dst[qa:qb, m * ts:(m + 1) * ts, :] = jnp.where(fixed, c, acc)
 
 
-def _emit(lin, o_ref, top: int, r: int, lane, at: tuple = ()):
-    """The interior rows of the in-order window ``lin`` that sit ``r``
-    below its row ``top``, from column ``r`` on, into ``o_ref`` at leading
-    index ``at``."""
-    bm, wi = o_ref.shape[len(at):]
-    nt = lin.shape[0]
-    rows = _round_up(r + bm, sublane_tile(jnp.float32))
-    nq = -(-wi // _LANE)
-    y = _lane_shift(lin[:min(nq + 1, nt), top:top + rows, :], r, lane)
-    for q in range(nq):
-        a, b = q * _LANE, min((q + 1) * _LANE, wi)
-        o_ref[at + (slice(None), slice(a, b))] = y[
-            q, r:r + bm, :b - a].astype(o_ref.dtype)
+def _emit(lin, o_ref, top: int, at: tuple = ()):
+    """The rows of the in-order window ``lin`` from its row ``top`` on,
+    every lane, into ``o_ref`` at leading index ``at``."""
+    rows, w = o_ref.shape[len(at):]
+    for q in range(-(-w // _LANE)):
+        a, b = q * _LANE, min((q + 1) * _LANE, w)
+        o_ref[at + (slice(None), slice(a, b))] = lin[
+            q, top:top + rows, :b - a].astype(o_ref.dtype)
 
 
 def _temporal_kernel(*refs, nin: int, bm: int, t: int, r: int, h: int,
@@ -579,10 +579,8 @@ def _temporal_kernel(*refs, nin: int, bm: int, t: int, r: int, h: int,
         sweep(buf_a, buf_b)
     out, lin = (buf_b, buf_a) if t % 2 else (buf_a, buf_b)
     _scatter(out, lin, ix)
-    # The bm interior rows of this block sit r below the main block's
-    # first row, and its columns r to the right of the window's; they are
-    # exact after t sweeps.
-    _emit(lin, o_ref, top, r, lane)
+    # The main block's rows, ring included, are exact after t sweeps.
+    _emit(lin, o_ref, top)
 
 
 def _temporal3d_kernel(*refs, nin: int, bm: int, t: int, r: int, shape,
@@ -603,7 +601,8 @@ def _temporal3d_kernel(*refs, nin: int, bm: int, t: int, r: int, shape,
     aligned loads of the same strip in the neighbouring plane. Sweep
     ``s`` skips the ``s * r`` planes at each end of the window, which are
     stale by then; planes of the ring, and planes standing for cells
-    beyond it, pass through unchanged.
+    beyond it, pass through unchanged. The main block's planes go out
+    whole, ring rows and lanes included.
     """
     in_refs = refs[:nin]
     o_ref, buf_a, buf_b = refs[nin:]
@@ -612,7 +611,7 @@ def _temporal3d_kernel(*refs, nin: int, bm: int, t: int, r: int, shape,
     ts = sublane_tile(buf_a.dtype)
     nm = kh // ts
     pd, h, w = shape
-    top = (t - 1) * r                     # halo planes before the block
+    top = t * r                           # halo planes before the block
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _LANE), 2)
 
     for p, ref in enumerate(in_refs[:top]):
@@ -663,10 +662,10 @@ def _temporal3d_kernel(*refs, nin: int, bm: int, t: int, r: int, shape,
 
     # Output plane j is window plane t*r + j, exact after t sweeps.
     _scatter(out, lin, _parity_lanes((bm * nh * ts, _LANE)),
-             (pl.ds(top + r, bm),))
+             (pl.ds(top, bm),))
 
     def emit(j, carry):
-        _emit(lin.at[top + r + j], o_ref, 0, r, lane, at=(j,))
+        _emit(lin.at[top + j], o_ref, 0, at=(j,))
         return carry
     jax.lax.fori_loop(0, bm, emit, 0)
 
@@ -679,8 +678,10 @@ def stencil_temporal(u: jax.Array, spec: StencilSpec, *, t: int | None = None,
                      mask: jax.Array | None = None) -> jax.Array:
     """Advance the grid by exactly ``t`` sweeps in one HBM round-trip.
 
-    A 3-D grid (planes, rows, lanes) runs :func:`_temporal3d_kernel`,
-    named ``stencil_temporal3d`` on the device trace; it takes no mask.
+    The kernel writes a fresh ringed grid, each grid step the rows of its
+    own main block: no write-back follows it. A 3-D grid (planes, rows,
+    lanes) runs :func:`_temporal3d_kernel`, named ``stencil_temporal3d``
+    on the device trace; it takes no mask.
 
     ``mask`` (optional, same shape as ``u``, nonzero = pinned) overrides
     the default Dirichlet set: without it the grid's own radius-``r`` ring
@@ -696,16 +697,14 @@ def stencil_temporal(u: jax.Array, spec: StencilSpec, *, t: int | None = None,
                     device=device, masked=masked)
     if plan.ndim == 3:
         return _temporal3d(u, spec, plan, interpret)
-    r = plan.radius
     h, w = u.shape
-    hi, wi = plan.interior_shape
     specs = _window_specs(plan)
     operands = [u] * len(specs)
     if masked:
         # The mask rides the same block pipeline as the grid, cast to the
         # grid dtype so 0/1 survive any registry dtype exactly.
         operands += [mask.astype(u.dtype)] * len(specs)
-    kw = dict(nin=len(specs), bm=plan.bm, t=plan.t, r=r, h=h,
+    kw = dict(nin=len(specs), bm=plan.bm, t=plan.t, r=plan.radius, h=h,
               top=plan.halo_rows[0], offsets=spec.offsets,
               weights=spec.weights, masked=masked)
     if plan.strip_rows:
@@ -715,18 +714,18 @@ def stencil_temporal(u: jax.Array, spec: StencilSpec, *, t: int | None = None,
         scratch = [buf] * (3 if masked else 2)
     else:
         kernel, scratch = functools.partial(_temporal_window_kernel, **kw), []
-    out = pl.pallas_call(
+    # No input_output_aliases: block i+1's halo reads rows block i writes.
+    return pl.pallas_call(
         kernel,
         grid=(plan.nblocks,),
         in_specs=specs * (2 if masked else 1),
-        out_specs=pl.BlockSpec((plan.bm, wi), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((hi, wi), u.dtype),
+        out_specs=pl.BlockSpec((plan.bm, w), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
         scratch_shapes=scratch,
         compiler_params=_compiler_params(plan, interpret),
         interpret=interpret,
         name=KERNEL_PREFIX + "temporal",
     )(*operands)
-    return _writeback(u, out, r)
 
 
 def _plane_specs(plan) -> list:
@@ -752,8 +751,8 @@ def _plane_specs(plan) -> list:
 
 def _temporal3d(u, spec: StencilSpec, plan, interpret: bool):
     """``plan.t`` sweeps of a 3-D grid through :func:`_temporal3d_kernel`,
-    one ``plan.bm``-plane block per grid step."""
-    w = u.shape[2]
+    one ``plan.bm``-plane block of the ringed grid per grid step."""
+    _, h, w = u.shape
     specs = _plane_specs(plan)
     kernel = functools.partial(
         _temporal3d_kernel, nin=len(specs), bm=plan.bm, t=plan.t,
@@ -761,17 +760,14 @@ def _temporal3d(u, spec: StencilSpec, plan, interpret: bool):
         weights=spec.weights)
     buf = pltpu.VMEM((plan.window_rows, 2 * -(-w // (2 * _LANE)),
                       plan.kernel_rows, _LANE), jnp.float32)
-    interior = plan.interior_shape
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=(plan.nblocks,),
         in_specs=specs,
-        out_specs=pl.BlockSpec((plan.bm,) + interior[1:],
-                               lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(interior, u.dtype),
+        out_specs=pl.BlockSpec((plan.bm, h, w), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
         scratch_shapes=[buf, buf],
         compiler_params=_compiler_params(plan, interpret),
         interpret=interpret,
         name=KERNEL_PREFIX + "temporal3d",
     )(*[u] * len(specs))
-    return _writeback(u, out, plan.radius)

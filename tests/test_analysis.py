@@ -209,6 +209,32 @@ def test_unmutated_lowerings_accepted(policy, spec):
             assert 0 <= b.min_tiles <= b.max_tiles <= cb.capacity_tiles
 
 
+@pytest.mark.parametrize("case", ["clipped", "unclipped", "past-end"])
+def test_ragged_temporal_write_bounds(case):
+    """At 258x258 the temporal blocks tile the ringed rows raggedly: the
+    clipped write may run past the grid in its last block only. Without
+    the clip, or with the last block starting past the grid, the bounds
+    pass refuses it."""
+    prog = _prog("temporal", t=4, shape=(258, 258))
+    h, bm = prog.plan.shape[0], prog.plan.bm
+    assert prog.plan.nblocks * bm > h
+    wb = next(op for op in prog.writer if isinstance(op, ir.WriteBlock))
+    assert wb.clip
+    if case == "unclipped":
+        wb2 = dataclasses.replace(wb, clip=False)
+    elif case == "past-end":
+        wb2 = dataclasses.replace(wb, dy=h - (prog.plan.nblocks - 1) * bm)
+    else:
+        wb2 = wb
+    bad = dataclasses.replace(prog, writer=tuple(
+        wb2 if op is wb else op for op in prog.writer))
+    report = analysis.verify_program(bad)
+    if case == "clipped":
+        assert report.ok, report.describe()
+    else:
+        assert "AB-ROW" in _codes(report), report.describe()
+
+
 def test_masked_temporal_accepted_and_described():
     prog = _prog("temporal", masked=True)
     assert analysis.verify_program(prog).ok

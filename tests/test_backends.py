@@ -189,6 +189,23 @@ def test_sim_matches_engine_run_bf16_tilized_exact(policy):
                                   want)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sim_temporal_ragged_blocks_match_engine_run(dtype):
+    """At 256x256 on the e150 budget the temporal blocks tile the 258
+    ringed rows raggedly over several blocks; the last block's write is
+    clipped at the grid's end and the result stays bit-exact."""
+    u = _problem(256, 256, dtype=dtype)
+    spec = jacobi_2d_5pt()
+    res = backends.simulate(u, spec, policy="temporal", iters=8, t=4,
+                            device="grayskull_e150")
+    plan = res.programs[0].plan
+    assert plan.nblocks > 1 and u.shape[0] % plan.bm
+    assert "clip" in res.programs[0].describe()
+    want = np.asarray(engine.run(u, spec, policy="temporal", iters=8, t=4))
+    np.testing.assert_array_equal(np.asarray(res.grid).astype(np.float32),
+                                  want.astype(np.float32))
+
+
 def test_masked_temporal_lowering_streams_the_pin_mask():
     """The distributed-shard temporal program carries an explicit mask
     stream: a mask CB fed by a second DRAM source, consumed by the fused

@@ -103,25 +103,26 @@ RADIUS2 = StencilSpec(offsets=((-2, 0), (-1, 0), (0, 0), (0, -2), (0, 1)),
                      weights=(0.1, 0.3, 0.2, 0.15, 0.25))
 # (interior rows, cols, dtype, spec, t, bm, masked): the temporal
 # kernel's edge cases, at widths whose windows exceed WHOLE_WINDOW_VREGS
-# and so sweep in strips. A single block whose 26 ringed rows are not a
-# whole number of sublane tiles; a ragged last block (``("fit", 16)``:
-# bm=None on a device whose budget holds 16-row blocks and no more, over
-# 46 rows); the masked form a distributed shard runs, ragged too; bf16;
-# a radius-2 spec; one sweep. The ``whole`` cases' windows are small
-# enough to sweep as one value.
+# and so sweep in strips. The kernel's blocks tile the ringed rows. A
+# single block whose 26 ringed rows are not a whole number of sublane
+# tiles; a ragged last block (``("fit", 16)``: bm=None on a device whose
+# budget holds 16-row blocks and no more, over 46 ringed rows, which no
+# multiple of 8 divides); the masked form a distributed shard runs,
+# ragged too; bf16; a radius-2 spec; one sweep. The ``whole`` cases'
+# windows are small enough to sweep as one value.
 STRIP_CASES = {
-    "deep": (32, 2046, jnp.float32, jacobi_2d_5pt(), 8, 16, False),
+    "deep": (32, 2046, jnp.float32, jacobi_2d_5pt(), 8, 24, False),
     "single_ragged": (24, 2302, jnp.float32, jacobi_2d_5pt(), 3, None,
                       False),
-    "ragged_blocks": (46, 2302, jnp.float32, laplace_2d_9pt(), 4,
+    "ragged_blocks": (44, 2302, jnp.float32, laplace_2d_9pt(), 4,
                       ("fit", 16), False),
-    "masked_shard": (46, 2046, jnp.float32, jacobi_2d_5pt(), 8, ("fit", 16),
+    "masked_shard": (44, 2046, jnp.float32, jacobi_2d_5pt(), 8, ("fit", 24),
                      True),
     "bf16": (48, 2046, jnp.bfloat16, jacobi_2d_5pt(), 8, 16, False),
     "radius2": (34, 2814, jnp.float32, RADIUS2, 2, 8, False),
     "t1": (32, 2814, jnp.float32, jacobi_2d_5pt(), 1, 8, False),
     "whole": (32, 128, jnp.float32, jacobi_2d_5pt(), 8, 16, False),
-    "whole_masked": (46, 140, jnp.float32, jacobi_2d_5pt(), 8, ("fit", 16),
+    "whole_masked": (44, 140, jnp.float32, jacobi_2d_5pt(), 8, ("fit", 16),
                      True),
 }
 
@@ -246,6 +247,58 @@ def test_temporal_mask_pins_only_global_ring_cells():
                                   np.asarray(want)[:h - d, :w - d])
 
 
+# (interior rows, cols, masked): each form of the temporal kernel over
+# 42 ringed rows in 16-row blocks, the last one ragged (10 rows): strips,
+# the whole window as one value, and the masked form pinned at the grid's
+# own ring.
+RINGED_FORMS = {
+    "strip": (40, 2302, False),
+    "whole": (40, 258, False),
+    "masked": (40, 2302, True),
+}
+
+
+@pytest.mark.parametrize("form", list(RINGED_FORMS))
+def test_temporal_writes_the_ringed_grid(form):
+    """The kernel's output is the ringed grid itself: the reference
+    policy's grid bit for bit, ring rows and columns included, with no
+    write-back after the launch."""
+    ny, nx, masked = RINGED_FORMS[form]
+    spec, t, bm = jacobi_2d_5pt(), 4, 16
+    u = make_laplace_problem(ny, nx, left=1.0, right=-2.0, top=0.5,
+                             bottom=3.0)
+    u = u.at[1:-1, 1:-1].set(jax.random.uniform(
+        jax.random.PRNGKey(len(form)), (ny, nx)))
+    plan = engine.plan_for(u.shape, u.dtype, spec, "temporal", t=t, bm=bm,
+                           masked=masked)
+    assert (plan.bm, plan.nblocks, plan.halo_rows) == (16, 3, (8, 8))
+    assert plan.strip_rows == (0 if form == "whole" else 8)
+    mask = None
+    if masked:
+        mask = jnp.ones(u.shape, bool).at[1:-1, 1:-1].set(False)
+    got = engine.stencil_temporal(u, spec, t=t, bm=bm, interpret=True,
+                                  mask=mask)
+    want = engine.run(u, spec, policy="reference", iters=t)
+    assert got.shape == u.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("policy,writebacks",
+                         [("temporal", 0), ("rowchunk", 1)])
+def test_writeback_is_traced_only_for_single_sweep_policies(policy,
+                                                             writebacks):
+    """``engine.kernel.writeback`` counts each trace of the interior's
+    write-back: a temporal program has none, a single-sweep policy's one
+    (the grid's shape is traced by no other test)."""
+    from repro.obs import metrics
+    u = _problem(19, 150, jnp.float32)
+    count = metrics.counter("engine.kernel.writeback")
+    before = count.value
+    jax.make_jaxpr(lambda v: engine.run(v, jacobi_2d_5pt(), policy=policy,
+                                        iters=4, t=4, interpret=True))(u)
+    assert count.value - before == writebacks
+
+
 def test_auto_policy_matches_oracle():
     u = _problem(24, 128, jnp.float32)
     got = engine.run(u, laplace_2d_9pt(), policy="auto", iters=6, bm=8,
@@ -278,7 +331,7 @@ def test_plan_values():
     plan = engine.plan_for((34, 130), jnp.bfloat16, laplace_2d_9pt(),
                            "temporal", bm=16, t=4)
     assert plan.bm == 16 and plan.t == 4 and plan.radius == 1
-    assert plan.nblocks == 2
+    assert plan.nblocks == 3  # the 34 ringed rows: 16 + 16 + a ragged 2
     assert plan.window_rows == 16 + 2 * 4  # bm + 2*t*r
     assert plan.dtype_bytes == 2
     assert "temporal" in plan.describe()
@@ -290,28 +343,38 @@ def test_plan_values():
 
 def test_temporal_footprint_is_the_strip_kernels_working_set():
     """The planner prices what the strip-mined kernel holds: the streamed
-    window (and pin mask) double-buffered, the output block, and two f32
-    ping-pong copies of the window (three with a mask), in whole lane
-    tiles, an even number of them in the scratch. At the paper's grid on
-    a v5e that keeps f32 at bm=32 (bm=64 would need about 19 MB) and the
-    masked form at bm=16."""
+    window (and pin mask) double-buffered, the output block of ringed
+    rows, and two f32 ping-pong copies of the window (three with a mask),
+    in whole lane tiles, an even number of them in the scratch. The halo
+    is the t*r = 8 rows the sweeps need past the rows a block writes. At
+    the paper's grid on a v5e that keeps f32 at bm=56 (15.02 MB; bm=64
+    would need 16.83 MB), 19 blocks of 72 rows over the 1026 ringed rows,
+    and the masked form at bm=32."""
     from repro.engine.plan import _window_and_vmem
     spec, shape = jacobi_2d_5pt(), (1026, 9218)
-    win = 16 + 32 + 16                    # halo, main, halo
-    stream, out = 2 * win * 9344 * 4, 2 * 32 * 9216 * 4
-    scratch = win * 9472 * 4              # 74 lane tiles
-    assert _window_and_vmem("temporal", shape, jnp.float32, spec, 32, 8) \
-        == (48, stream + out + 2 * scratch)
+    budget = engine.get_device("tpu_v5e").fast_memory_bytes
+
+    def vmem(bm, streams):
+        win = 8 + bm + 8                  # halo, main, halo
+        stream, out = 2 * win * 9344 * 4, 2 * bm * 9344 * 4
+        scratch = win * 9472 * 4          # 74 lane tiles
+        return streams * stream + out + (streams + 1) * scratch
+
+    assert _window_and_vmem("temporal", shape, jnp.float32, spec, 56, 8) \
+        == (56 + 16, vmem(56, 1)) == (72, 15_024_128)
+    assert vmem(56, 1) <= budget < vmem(64, 1) == 16_826_368
     assert _window_and_vmem("temporal", shape, jnp.float32, spec, 32, 8,
-                            masked=True) == (48, 2 * stream + out + 3 * scratch)
+                            masked=True) == (48, vmem(32, 2))
+    assert vmem(32, 2) <= budget < vmem(40, 2)
     plan = engine.plan_for(shape, jnp.float32, spec, "temporal", t=8,
                            device="tpu_v5e")
-    assert (plan.bm, plan.kernel_rows, plan.strip_rows) == (32, 64, 8)
-    assert plan.recompute == 2.0
-    assert "strip=8 recompute=2.000" in plan.describe()
+    assert (plan.bm, plan.kernel_rows, plan.strip_rows) == (56, 72, 8)
+    assert plan.halo_rows == (8, 8) and plan.nblocks == 19
+    assert plan.recompute == 72 / 56
+    assert "strip=8 recompute=1.286" in plan.describe()
     masked = engine.plan_for(shape, jnp.float32, spec, "temporal", t=8,
                              device="tpu_v5e", masked=True)
-    assert (masked.bm, masked.recompute) == (16, 3.0)
+    assert (masked.bm, masked.recompute) == (32, 1.5)
 
 
 def _loop_carries(jaxpr):

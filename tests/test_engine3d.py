@@ -82,6 +82,23 @@ def test_3d_kernel_ignores_uninitialized_memory():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(_oracle(u, 3)))
 
 
+def test_3d_kernel_writes_the_ringed_grid():
+    """The 3-D kernel's output is the ringed grid itself, its affine ring
+    included: the reference policy's grid bit for bit, over 4-plane blocks
+    of 9 planes (the last one ragged), with no write-back traced."""
+    from repro.obs import metrics
+    u = _grid((9, 11, 140), seed=8)
+    plan = engine.plan_for(u.shape, u.dtype, HEAT3D, "temporal", t=2, bm=4)
+    assert (plan.bm, plan.nblocks, plan.halo_planes) == (4, 3, (2, 2))
+    count = metrics.counter("engine.kernel.writeback")
+    before = count.value
+    got = engine.stencil_temporal(u, HEAT3D, t=2, bm=4, interpret=True)
+    assert count.value == before
+    want = engine.run(u, HEAT3D, policy="reference", iters=2)
+    assert got.shape == u.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_3d_run_converged_stops_on_the_residual():
     """``run_converged`` on a 3-D grid: whole blocks until the n-D
     residual is under tol, and the residual it reports is that of the
@@ -116,7 +133,8 @@ def test_resolve_auto_picks_the_3d_kernel():
 def test_3d_plan_at_polybench_size():
     """At 200^3 f32 on a v5e: every plane takes 200 x 256 f32 words in
     fast memory, and a block of ``bp`` planes needs its window (``bp + 2t``
-    planes) streamed in twice, twice in scratch, and its output twice. The
+    planes, ``t`` a side of the planes it writes) streamed in twice, twice
+    in scratch, and its output of ringed planes twice. The
     planner takes the deepest block that fits 16 MiB, and the default
     depth is the deepest whose sweeps cover at most two planes per plane
     kept: at t=6 five-plane blocks sweep 5 + 5 planes, at t=7 four-plane
@@ -126,13 +144,13 @@ def test_3d_plan_at_polybench_size():
     plan = engine.plan_for(shape, jnp.float32, HEAT3D, "temporal", t=4,
                            device="tpu_v5e")
     assert plan.ndim == 3 and plan.interior_shape == (198, 198, 198)
-    assert (plan.bm, plan.halo_planes, plan.window_rows) == (8, (3, 5), 16)
+    assert (plan.bm, plan.halo_planes, plan.window_rows) == (8, (4, 4), 16)
     assert plan.halo_rows == (0, 0) and plan.strip_rows == 8
     assert plan.vmem_bytes == (4 * 16 + 2 * 8) * plane <= budget
     assert (4 * 17 + 2 * 9) * plane > budget   # a ninth plane does not fit
-    assert plan.nblocks == 25                  # 24 x 8 + a ragged 6
-    assert plan.recompute == pytest.approx((8 + 3) / 8 * 200 / 198)
-    assert "planes=8 halo_planes=(3, 5)" in plan.describe()
+    assert plan.nblocks == 25                  # 25 x 8 ringed planes
+    assert plan.recompute == pytest.approx((8 + 3) / 8)
+    assert "planes=8 halo_planes=(4, 4)" in plan.describe()
     blocks = {t: engine.plan_for(shape, jnp.float32, HEAT3D, "temporal",
                                  t=t, device="tpu_v5e").bm
               for t in (5, 6, 7, 8)}
@@ -151,7 +169,7 @@ def test_3d_run_span_carries_the_plan():
         attrs = next(e.attrs for e in tr.events if e.name == name)
         assert attrs["policy"] == "temporal"
         assert attrs["block_planes"] == 2
-        assert tuple(attrs["halo_planes"]) == plan.halo_planes == (2, 4)
+        assert tuple(attrs["halo_planes"]) == plan.halo_planes == (3, 3)
         assert attrs["strip_rows"] == 8
         assert attrs["recompute"] == round(plan.recompute, 4)
 

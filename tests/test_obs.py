@@ -72,9 +72,11 @@ def test_engine_run_bit_identical_obs_on_vs_off():
     assert run_ev.attrs["policy"] == "temporal"
     assert run_ev.attrs["t"] == 4
     # The kernel's form: a window this small sweeps as one value (no
-    # strips), one block of 24 tile-rounded rows for the 18 it keeps.
+    # strips), one block of 24 tile-rounded rows for the 20 ringed rows
+    # it writes, so no halo rows.
     assert run_ev.attrs["strip_rows"] == 0
-    assert run_ev.attrs["recompute"] == round(24 / 18, 4)
+    assert run_ev.attrs["recompute"] == round(24 / 20, 4)
+    assert tuple(run_ev.attrs["halo_rows"]) == (0, 0)
     # build_schedule nests under engine.run in the span tree.
     (sched_ev,) = [e for e in tracer.events
                    if e.name == "engine.build_schedule"]
@@ -165,8 +167,9 @@ def _op_names(hlo: str) -> set:
 
 def test_converged_launch_names_residual_and_writeback():
     """The compiled ``run_converged`` program carries its phases in every
-    operation's ``op_name``: the residual and exit test, and the kernel's
-    write-back."""
+    operation's ``op_name``: the residual and exit test. The temporal
+    kernel writes the ringed grid itself, so no operation runs under
+    ``stencil.writeback``."""
     from repro.engine import dispatch
     from repro.engine.schedule import build_schedule
     spec = jacobi_2d_5pt()
@@ -180,7 +183,7 @@ def test_converged_launch_names_residual_and_writeback():
     assert any("/solver.residual/reduce_max" in n for n in names), names
     assert any("/solver.residual/" in n and "while/cond" in n
                for n in names), names               # the exit test
-    assert any("/stencil.writeback/" in n for n in names), names
+    assert not any("/stencil.writeback/" in n for n in names), names
 
 
 # ---------------------------------------------------------------------------

@@ -171,7 +171,8 @@ def test_server_warm_never_remeasures():
 def test_run_batched_strip_kernel_matches_reference():
     """The vmapped launch the served path runs, at a width whose windows
     sweep in strips (the batch becomes a grid axis of the kernel and its
-    scratch is reused lane after lane), over two 16-row blocks: each lane
+    scratch is reused lane after lane), over 16-row blocks of the 34
+    ringed rows, the last one ragged: each lane
     equals the oracle bit for bit, with uninitialized memory read as NaN
     (Pallas's TPU interpret mode)."""
     from jax.experimental.pallas import tpu as pltpu
@@ -184,7 +185,7 @@ def test_run_batched_strip_kernel_matches_reference():
         jax.random.PRNGKey(3), (2, 32, 2300)))
     plan = engine.plan_for(us.shape[1:], us.dtype, spec, "temporal", t=8,
                            bm=16)
-    assert plan.strip_rows == 8 and plan.nblocks == 2, plan.describe()
+    assert plan.strip_rows == 8 and plan.nblocks == 3, plan.describe()
     got = engine.run_batched(us, spec, policy="temporal", iters=16, t=8,
                              bm=16, interpret=pltpu.InterpretParams(
                                  out_of_bounds_reads="uninitialized"))
